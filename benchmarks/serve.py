@@ -87,6 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import _cache_len, _prompts
 from repro.models.lm import init_params
 from repro.serve import (Request, RequestQueue, ServeEngine, auto_page_size,
@@ -998,7 +999,7 @@ def bench_tp_serve(cfg, params, *, slots, cache_len, page_size,
               "XLA_FLAGS=--xla_force_host_platform_device_count=4 to "
               "exercise the sharded engine off-accelerator)", flush=True)
         return []
-    mesh = jax.make_mesh((1, ndev), ("data", "model"))
+    mesh = make_mesh((1, ndev), ("data", "model"))
     prompts, patches = _prompts(cfg, n_req, prompt_len, seed=41)
     prompts = np.asarray(prompts)
     patches = None if patches is None else np.asarray(patches)

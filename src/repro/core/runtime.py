@@ -317,6 +317,15 @@ class UMTRuntime:
             return
         self.wait_all()
         self.running = False
+        try:
+            os.write(self._wake_w, b"\x01" * 8)
+        except BlockingIOError:
+            pass
+        if self.umt:
+            # the leader is the only spawner after __init__: once it has
+            # stopped, no worker is appended to _workers unstarted (a
+            # join of such a worker raises)
+            self.leader.join(timeout=5)
         with self._pool_lock:
             pool = list(self._pool)
             self._pool.clear()
@@ -324,14 +333,8 @@ class UMTRuntime:
             w.sem.release()
         for w in list(self._workers):
             w.sem.release()
-        try:
-            os.write(self._wake_w, b"\x01" * 8)
-        except BlockingIOError:
-            pass
         for w in self._workers:
             w.join(timeout=5)
-        if self.umt:
-            self.leader.join(timeout=5)
         for ch in self.channels:
             ch.close()
         os.close(self._wake_r)

@@ -1,19 +1,31 @@
 """Production meshes.  A function (not a module-level constant) so importing
-this module never touches jax device state."""
+this module never touches jax device state.
+
+Every mesh is built through :func:`make_mesh`, with Auto axis types: the
+model annotates tensors with ``with_sharding_constraint``, which only
+accepts Auto axes (``jax.make_mesh`` defaults to Explicit)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis Auto (see the module doc)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (axes present, sizes 1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_mesh_from_devices(spec: str | None = None):
@@ -41,4 +53,4 @@ def make_mesh_from_devices(spec: str | None = None):
             raise ValueError(
                 f"mesh spec {spec!r}: {shape[0]}x{shape[1]} != "
                 f"{n} visible devices")
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))

@@ -8,10 +8,15 @@ flow through the monitored queue, prefill/insert/decode/respond run as
 UMT tasks, finished slots free immediately.  ``--mode oneshot`` keeps the
 pre-engine behaviour — prefill one static batch, decode it to completion —
 as the comparison baseline (same greedy tokens, tested).
+
+Weights are random (seeded) and held in the compute dtype; the JSON line
+names the device the run used.  A request that fails inside the engine
+fails the command.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
@@ -21,8 +26,47 @@ import numpy as np
 
 from ..configs import get
 from ..models.lm import init_params
+from ..serve import param_shardings
 from ..steps import make_prefill_step, make_serve_step
+from .compile_cache import use_compile_cache
 from .mesh import make_mesh_from_devices
+
+
+def serve_config(arch: str, tiny: bool = False):
+    """The served config: weights in the compute dtype (float32 master
+    weights are a training concern; ``.tiny()`` configs compute in
+    float32, so they keep float32 weights)."""
+    cfg = get(arch)
+    if tiny:
+        cfg = cfg.tiny()
+    return cfg.replace(param_dtype=cfg.dtype)
+
+
+def init_serve_params(cfg, mesh, seed: int = 0):
+    """Random weights from ``seed``, created where they will live: on a
+    mesh with a model axis each device initialises only its own shard
+    (``param_shardings``), so no device ever holds the whole model."""
+    tp = mesh is not None and mesh.shape.get("model", 1) > 1
+    init = jax.jit(functools.partial(init_params, cfg),
+                   out_shardings=param_shardings(cfg, mesh) if tp else None)
+    return init(jax.random.PRNGKey(seed))
+
+
+def device_info() -> dict:
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": jax.device_count()}
+
+
+def check_requests(reqs) -> None:
+    """Raise if any request failed inside the engine (``Request.error``)
+    — a failed prefill or decode must never end in a result line."""
+    failed = [r for r in reqs if r.error is not None]
+    if failed:
+        r = failed[0]
+        raise RuntimeError(
+            f"{len(failed)} of {len(reqs)} requests failed; request "
+            f"{r.rid}: {r.error!r}") from r.error
 
 
 def _prompts(cfg, batch, prompt_len, seed=1):
@@ -65,6 +109,7 @@ def serve_oneshot(cfg, params, mesh, args):
     print(json.dumps({
         "mode": "oneshot",
         "arch": cfg.name,
+        "device": device_info(),
         "prefill_s": round(t_prefill, 3),
         "decode_s_per_tok": round(t_decode / max(args.gen - 1, 1), 4),
         "generated_shape": list(gen.shape),
@@ -109,12 +154,14 @@ def serve_engine(cfg, params, mesh, args):
         eng.join()
         stats = eng.stats()
     wall = time.time() - t0
+    check_requests(reqs)
 
     gen = jnp.asarray(np.stack(
         [np.asarray(r.out_tokens, np.int32) for r in reqs]))
     print(json.dumps({
         "mode": "engine",
         "arch": cfg.name,
+        "device": device_info(),
         "umt": not args.no_umt,
         "page_size": stats["page_size"],
         "tp": stats["tp"],
@@ -221,16 +268,14 @@ def serve(argv=None):
     if args.requests <= 0:
         args.requests = args.batch
 
-    cfg = get(args.arch)
-    if args.tiny:
-        cfg = cfg.tiny()
+    cfg = serve_config(args.arch, args.tiny)
     # always mesh over whatever is visible: one device gives the (1, 1)
     # host mesh (annotations present, no sharding), several give (1, n)
     # — the engine auto-enables tensor-parallel serving on the model
     # axis (the old `device_count == 1` special case left multi-device
     # runs with no mesh at all, so they never sharded anything)
     mesh = make_mesh_from_devices(args.mesh)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = init_serve_params(cfg, mesh)
 
     if args.mode == "oneshot":
         return serve_oneshot(cfg, params, mesh, args)
@@ -238,4 +283,5 @@ def serve(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     serve()

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels import paged_decode_attention, paged_mla_decode_attention
@@ -361,13 +360,13 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                     # local and per-head outputs are bit-identical to
                     # the single-shard call.
                     hspec = P(None, None, axes, None)
-                    out = shard_map(
+                    out = jax.shard_map(
                         functools.partial(paged_decode_attention,
                                           page_size=ps, window=w),
                         mesh=current_mesh(),
                         in_specs=(hspec, hspec, hspec, P(None, None),
                                   P(None)),
-                        out_specs=hspec, check_rep=False,
+                        out_specs=hspec, check_vma=False,
                     )(q, kc, vc, table, pv)
                 else:
                     out = paged_decode_attention(q, kc, vc, table, pv,
@@ -559,13 +558,13 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                 # per model shard over its local query heads
                 hspec = P(None, None, axes, None)
                 rep3 = P(None, None, None)
-                lat = shard_map(
+                lat = jax.shard_map(
                     functools.partial(paged_mla_decode_attention,
                                       page_size=ps, scale=scale),
                     mesh=current_mesh(),
                     in_specs=(hspec, hspec, rep3, rep3, P(None, None),
                               P(None)),
-                    out_specs=hspec, check_rep=False,
+                    out_specs=hspec, check_vma=False,
                 )(q_lat, q_rope, cc, kr, table, pv)
             else:
                 lat = paged_mla_decode_attention(q_lat, q_rope, cc, kr,

@@ -123,7 +123,8 @@ The CLI front-end is ``python -m repro.launch.serve --mode engine``
 (``--mode oneshot`` keeps the pre-engine one-shot batch path for
 comparison); the load benchmark is ``python -m benchmarks.serve``.
 """
-from .engine import ServeEngine, auto_page_size, make_jit_steps
+from .engine import (ServeEngine, auto_page_size, make_jit_steps,
+                     param_shardings)
 from .kvstate import KVState, alias_safe
 from .pager import GARBAGE_PAGE, PagePool
 from .policy import (POLICIES, OnDemandPolicy, SchedulerPolicy, SlotView,
@@ -135,4 +136,4 @@ __all__ = ["ServeEngine", "Request", "RequestQueue", "make_jit_steps",
            "KVState", "alias_safe", "PagePool", "GARBAGE_PAGE",
            "auto_page_size", "SchedulerPolicy", "OnDemandPolicy",
            "SlotView", "make_policy", "POLICIES", "PrefixCache",
-           "PrefixMatch"]
+           "PrefixMatch", "param_shardings"]

@@ -143,6 +143,18 @@ def _tp_shardings(cfg, mesh, cache_len: int, page_size: int | None):
             "row": cache_tree_shardings(rows, mesh)}
 
 
+def param_shardings(cfg, mesh):
+    """Per-leaf ``NamedSharding`` tree of the weights under
+    tensor-parallel serving: heads / ff fan-out / vocab on the model
+    axis, strict — a dim the axis does not divide replicates."""
+    from ..models.lm import LeafMeta, param_meta
+
+    return jax.tree_util.tree_map(
+        lambda m: logical_sharding(m.shape, m.axes, mesh, TP_SERVE_RULES,
+                                   strict=True),
+        param_meta(cfg), is_leaf=lambda x: isinstance(x, LeafMeta))
+
+
 def make_jit_steps(cfg, mesh=None, cache_len: int = 64, *,
                    page_size: int | None = None, chunk: bool | None = None,
                    donate: bool = True, paged_kernel: bool = False,
@@ -689,18 +701,12 @@ class ServeEngine:
             alias_safe(rows, out_c, "chunk")
 
     def _shard_params(self, params):
-        """Commit the weights to their logical-axis shardings (heads /
-        ff fan-out / vocab on the model axis, strict — non-dividing dims
-        replicate); identity when not tensor-parallel."""
+        """Commit the weights to :func:`param_shardings`; identity when
+        not tensor-parallel (and a no-op for weights created with those
+        shardings already)."""
         if not self.tp or params is None:
             return params
-        from ..models.lm import param_logical_axes
-
-        sh = jax.tree_util.tree_map(
-            lambda p, a: logical_sharding(p.shape, a, self.mesh,
-                                          TP_SERVE_RULES, strict=True),
-            params, param_logical_axes(self.cfg))
-        return jax.device_put(params, sh)
+        return jax.device_put(params, param_shardings(self.cfg, self.mesh))
 
     def _dev_rows(self, rows):
         """Commit a fresh host-built row cache to its per-leaf TP
@@ -834,6 +840,16 @@ class ServeEngine:
         if req.prefix_hold is not None and self.prefix is not None:
             self.prefix.release(req.prefix_hold)
             req.prefix_hold = None
+
+    def _drop_queued_holds(self) -> bool:
+        """Drop the prefix holds of every row queued for insertion;
+        True if any was held."""
+        with self._lock:
+            held = [e[0] for e in self._inserts
+                    if e[0].prefix_hold is not None]
+        for req in held:
+            self._drop_prefix_hold(req)
+        return bool(held)
 
     def _finish_failed(self, req: Request, exc: BaseException):
         self._drop_prefix_hold(req)
@@ -1301,7 +1317,8 @@ class ServeEngine:
             with self._lock:
                 if not self._inserts:
                     return
-                req, rows_cache, row, t0 = self._inserts[0]
+                entry = self._inserts[0]
+            req, rows_cache, row, t0 = entry
             ids = None
             shared: list[int] = []
             if self.paged:
@@ -1329,6 +1346,13 @@ class ServeEngine:
                     need = self.pager.pages_for(
                         self.policy.admission_tokens(self, req))
                     ids = self._alloc_pages(need)
+                if ids is None and not self._active.any() \
+                        and self._drop_queued_holds():
+                    # nothing live will ever free a page, so what the
+                    # head lacks was held by rows queued behind it, which
+                    # only their own admission would release: they fall
+                    # back to cold-shaped too (sound as above) — retry
+                    continue
                 if ids is None:         # admission blocked on free pages
                     if self._blocked_head != req.rid:
                         self._blocked_head = req.rid
@@ -1336,7 +1360,11 @@ class ServeEngine:
                     return
             self._blocked_head = None
             with self._lock:
-                self._inserts.popleft()
+                # dequeue this row, not the head: a prefill worker may
+                # have pushed a restore there (appendleft) since the peek
+                i = next(i for i, e in enumerate(self._inserts)
+                         if e is entry)
+                del self._inserts[i]
             s = int(self.policy.select_slot(self, free))
             assert not self._active[s], \
                 f"policy picked a live slot {s} for admission"

@@ -123,8 +123,10 @@ def make_prefill_step(cfg, mesh=None, cache_len=None, *, tp=False):
     return prefill_step
 
 
-def make_serve_step(cfg, mesh=None, *, tp=False):
-    """One decode step: (params, cache, tokens) -> (next_tokens, cache)."""
+def make_serve_step(cfg, mesh=None, *, tp=False, return_logits=False):
+    """One decode step: (params, cache, tokens) -> (next_tokens, cache),
+    or (logits, cache) with ``return_logits`` (teacher-forced
+    comparisons read the logits the greedy choice was made from)."""
     rules = TP_SERVE_RULES if tp else DECODE_RULES
 
     def serve_step(params, cache, tokens):
@@ -132,6 +134,8 @@ def make_serve_step(cfg, mesh=None, *, tp=False):
             pc = cast_tree(params, cfg.dtype)
             out = forward(pc, cfg, tokens, mode="decode", pos=cache["pos"],
                           cache=cache)
+            if return_logits:
+                return out["logits"], out["cache"]
             nxt = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
             return nxt, out["cache"]
 

@@ -75,7 +75,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_cost import HloCost
-mesh = jax.make_mesh((4,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("model",))
 def f(x, w):
     y = jnp.einsum("bd,df->bf", x, w)
     return (y.astype(jnp.float32) ** 2).sum()

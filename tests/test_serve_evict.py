@@ -25,6 +25,7 @@ from repro.configs import get
 from repro.models.lm import init_params
 from repro.serve import (OnDemandPolicy, Request, SchedulerPolicy,
                          ServeEngine, make_jit_steps, make_policy)
+from repro.serve.spec import NgramDrafter
 from repro.steps import greedy_oneshot, make_serve_step
 
 # plain GQA, SWA+MoE, MLA, vision frontend, audio frontend, SSM hybrid
@@ -245,6 +246,104 @@ def test_ondemand_never_deadlocks_under_severe_pressure(seed, built):
     assert stats["admission_blocks"] + stats["evictions"] > 0
 
 
+class RestoreLandsMidAdmission(SchedulerPolicy):
+    """Pushes a stand-in restore row to the head of the insert queue from
+    inside the admission window — after the engine read the head, before
+    it dequeued it — which is what a prefill worker finishing a restore
+    does (``appendleft``) at any moment.  ``select_slot`` (called after
+    the dequeue) records whether the engine dequeued the row it admitted
+    and left the restore at the head."""
+
+    def __init__(self):
+        self.marker = self.admitting = self.head_kept = None
+
+    def admission_tokens(self, eng, req):
+        if self.marker is None:
+            self.marker, self.admitting = ("restore",), req
+            with eng._lock:
+                eng._inserts.appendleft(self.marker)
+        return super().admission_tokens(eng, req)
+
+    def select_slot(self, eng, free):
+        if self.head_kept is None:
+            with eng._lock:
+                head = eng._inserts[0] if eng._inserts else None
+                self.head_kept = head is self.marker
+                # take the stand-in (or, dequeued in its place, the
+                # admitted row's stale twin) back out before the loop
+                # tries to admit it
+                if head is self.marker or (
+                        head is not None and head[0] is self.admitting):
+                    eng._inserts.popleft()
+        return super().select_slot(eng, free)
+
+
+@pytest.mark.slow
+def test_admission_dequeues_the_row_it_admitted(built):
+    """A restore pushed to the head of the insert queue while an
+    admission is allocating pages must stay queued: the admission
+    dequeues its own row, not whatever is at the head by then.
+    Dequeuing the head instead loses the restore and leaves the admitted
+    row queued twice — one request in two slots, its stream running
+    ahead of its pages."""
+    b = _build("qwen2.5-14b", built)
+    policy = RestoreLandsMidAdmission()
+    _run(b, policy)
+    assert policy.head_kept is True
+
+
+@pytest.mark.slow
+def test_blocked_head_with_nothing_live_takes_queued_holds(built):
+    """With no slot live, no page will ever be freed by a completion or
+    an eviction: if the admission head cannot fit, the pages it lacks
+    are prefix-cache holds of rows queued *behind* it, which only their
+    own admission would release.  The engine must drop those holds (the
+    rows fall back to cold-shaped admission, as a blocked head's own
+    hold does) and admit the head — not block with nothing live, which
+    the decode loop would spin on forever."""
+    b = _build("qwen2.5-14b", built)
+    cfg, ps, prefill = b["cfg"], b["ps"], b["steps"]["prefill"]
+    w = -(-(PLEN + GEN - 1) // ps)          # one request's worst case
+    eng = ServeEngine(cfg, b["params"], slots=2, cache_len=b["cache_len"],
+                      umt=True, n_cores=2, jit_steps=b["steps"],
+                      page_size=ps, num_pages=w + 1, policy="ondemand")
+    try:
+        # head: a restore of request 0 after 4 generated tokens (prompt +
+        # generated[:-1] replayed); behind it: request 1, fresh
+        gen0 = b["ref"][0, :5]
+        replay = np.concatenate([b["prompts"][0], gen0[:-1]])
+        rows = []
+        for i, toks in ((0, replay), (1, b["prompts"][1])):
+            req = Request(i, b["prompts"][i], max_new_tokens=GEN)
+            cache, logits = prefill(b["params"], jnp.asarray(toks[None]),
+                                    None)
+            t0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            req.total_len = len(toks)
+            req.out_tokens = list(gen0) if i == 0 else [np.asarray(t0)[0, 0]]
+            rows.append((req, cache, 0, t0))
+        head, behind = rows[0][0], rows[1][0]
+        head.restore_tokens = replay
+        # the row behind the head holds cached pages of its prompt (a
+        # prefix hit); the rest of the pool cannot fit the head
+        n_hit = (PLEN - 1) // ps
+        ids = eng.pager.alloc(n_hit)
+        eng.prefix.insert(behind.tokens, ids, n_hit * ps)
+        eng.pager.release(ids)
+        behind.prefix_hold = eng.prefix.match_and_lock(behind.tokens,
+                                                       PLEN - 1)
+        assert behind.prefix_hold.pages == ids
+        assert eng.pager.capacity - n_hit < \
+            eng.pager.pages_for(head.total_len) <= eng.pager.capacity
+        eng._inserts.extend(rows)
+        eng._do_inserts()
+        assert eng._active.any(), "head blocked with nothing live"
+        assert eng._slot_req[int(np.flatnonzero(eng._active)[0])] is head
+        assert behind.prefix_hold is None
+        assert [e[0] for e in eng._inserts] == [behind]
+    finally:
+        eng.shutdown()
+
+
 @pytest.mark.slow
 def test_restore_retraces_bounded(built):
     """Eviction restores at many distinct depths must not pay one XLA
@@ -279,21 +378,51 @@ def test_restore_retraces_bounded(built):
 SPEC_ARCHS = ["qwen2.5-14b", "minicpm3-4b", "internvl2-2b"]
 
 
+def _ref_rows(b, prompts):
+    """One-shot greedy rows for ``prompts`` (the harness's reference)."""
+    serve_step = jax.jit(make_serve_step(b["cfg"]))
+    patches = None if b["patches"] is None else jnp.asarray(b["patches"])
+    return np.asarray(greedy_oneshot(
+        b["steps"]["prefill"], serve_step, b["params"], jnp.asarray(prompts),
+        patches, GEN))
+
+
+def _bait_drafter(b, prompts):
+    """Rewrite each prompt's last token so that the n-gram drafter must
+    fire on the request's first decode tick, whatever the random weights
+    emit: every vocabulary entry is tried in that position in one batched
+    prefill, and the first whose greedy token already occurs in the
+    prompt is kept — the drafter's 1-gram lookup then matches the emitted
+    token against that occurrence.  Returns the prompts and their
+    one-shot rows; asserts the opportunity holds on those rows."""
+    n, vocab = len(prompts), b["cfg"].vocab
+    cand = np.repeat(prompts, vocab, axis=0)
+    cand[:, -1] = np.tile(np.arange(vocab), n)
+    patches = (None if b["patches"] is None else
+               jnp.asarray(np.repeat(b["patches"], vocab, axis=0)))
+    _, logits = b["steps"]["prefill"](b["params"], jnp.asarray(cand),
+                                      patches)
+    first = np.asarray(jnp.argmax(logits, axis=-1))[:, 0].reshape(n, vocab)
+    prompts = np.array(prompts, copy=True)
+    for i in range(n):
+        hits = [c for c in range(vocab)
+                if first[i, c] == c or first[i, c] in prompts[i, :-1]]
+        assert hits, f"no last token baits the drafter for prompt {i}"
+        prompts[i, -1] = hits[0]
+    ref = _ref_rows(b, prompts)
+    drafter = NgramDrafter()
+    for i in range(n):
+        ctx = [int(t) for t in prompts[i]] + [int(ref[i, 0])]
+        assert drafter.draft(ctx, 1), f"prompt {i}: bait missed"
+    return prompts, ref
+
+
 def _spec_data(b):
-    """The standard request set rewritten repetitive (each prompt a
-    2-token motif tiled) plus matching one-shot rows — the n-gram
-    drafter's home turf, so speculation actually fires on every arch
-    regardless of vocab size (random prompts only draft by chance
-    collision on small vocabularies)."""
+    """The standard request set with drafter-baited prompts
+    (:func:`_bait_drafter`) plus matching one-shot rows, so speculation
+    fires on every arch however the random weights decode."""
     if "spec_data" not in b:
-        prompts = np.array(b["prompts"], copy=True)
-        prompts[:] = np.tile(prompts[:, :2], (1, PLEN // 2))
-        serve_step = jax.jit(make_serve_step(b["cfg"]))
-        patches = (None if b["patches"] is None
-                   else jnp.asarray(b["patches"]))
-        ref = np.asarray(greedy_oneshot(
-            b["steps"]["prefill"], serve_step, b["params"],
-            jnp.asarray(prompts), patches, GEN))
+        prompts, ref = _bait_drafter(b, b["prompts"])
         b["spec_data"] = dict(b, prompts=prompts, ref=ref)
     return b["spec_data"]
 
@@ -449,21 +578,19 @@ def test_stop_fires_after_restore_on_both_replay_paths(arch, kind, built):
 N_SHARED = 6          # shared system-prompt tokens (3 full pages, ps=2)
 
 
-def _shared_prefix_data(b):
+def _shared_prefix_data(b, bait=False):
     """The standard prompt set rewritten so every request shares its
     first ``N_SHARED`` tokens with request 0 (a common system prompt),
-    plus the matching one-shot reference rows."""
-    if "shared" not in b:
+    plus the matching one-shot reference rows.  ``bait`` also rewrites
+    each (unshared) last token so the n-gram drafter must fire
+    (:func:`_bait_drafter`)."""
+    key = "shared_bait" if bait else "shared"
+    if key not in b:
         prompts = np.array(b["prompts"], copy=True)
         prompts[1:, :N_SHARED] = prompts[0, :N_SHARED]
-        serve_step = jax.jit(make_serve_step(b["cfg"]))
-        patches = (None if b["patches"] is None
-                   else jnp.asarray(b["patches"]))
-        ref = np.asarray(greedy_oneshot(
-            b["steps"]["prefill"], serve_step, b["params"],
-            jnp.asarray(prompts), patches, GEN))
-        b["shared"] = (prompts, ref)
-    return b["shared"]
+        b[key] = (_bait_drafter(b, prompts) if bait
+                  else (prompts, _ref_rows(b, prompts)))
+    return b[key]
 
 
 def _run_prefix(b, *, policy=None, num_pages=None, jit_steps=None,
@@ -473,7 +600,7 @@ def _run_prefix(b, *, policy=None, num_pages=None, jit_steps=None,
     serialized to completion first so its pages warm the trie before
     the rest arrive.  Asserts every stream equals its one-shot row and
     the drained pool holds nothing but trie capital."""
-    prompts, ref = _shared_prefix_data(b)
+    prompts, ref = _shared_prefix_data(b, bait=spec is not None)
     steps = b["steps"] if jit_steps is None else jit_steps
     ps = b["ps"] if page_size == "use" else page_size
     reqs = [Request(i, prompts[i],

@@ -26,6 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from repro.configs import get
+from repro.launch.mesh import make_mesh
 from repro.models.lm import init_params
 from repro.serve import Request, ServeEngine, make_jit_steps
 from repro.steps import greedy_oneshot, make_serve_step
@@ -86,7 +87,7 @@ def test_tp_engine_bit_identical_to_single_device(arch):
         body = r"""
 cfg, params, prompts = build("mamba2-780m")
 ref = reference(cfg, params, prompts, None)
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 for seed, donate in ((0, True), (1, False)):
     steps = make_jit_steps(cfg, mesh, cache_len=CACHE_LEN,
                            donate=donate, tp=True)
@@ -107,7 +108,7 @@ run_leg(cfg, params, prompts, ref, mesh, steps, 7, page_size=PAGE,
         body = (r"""
 cfg, params, prompts = build("%s")
 ref = reference(cfg, params, prompts, PAGE)
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 for donate in (True, False):
     steps = make_jit_steps(cfg, mesh, cache_len=CACHE_LEN,
                            page_size=PAGE, chunk=True, donate=donate,
@@ -132,7 +133,7 @@ def test_tp_engine_collectives_footprint_and_donation():
     the pool in place (per-shard buffer pointers survive)."""
     body = r"""
 cfg, params, prompts = build("qwen2.5-14b")
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 with ServeEngine(cfg, params, slots=3, cache_len=CACHE_LEN, mesh=mesh,
                  umt=False, n_cores=4, page_size=PAGE) as eng:
     assert eng.tp and eng.stats()["tp"]

@@ -144,14 +144,15 @@ import sys, jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_checkpoint, load_checkpoint
+from repro.launch.mesh import make_mesh
 
 path = sys.argv[1]
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
 x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 xs = jax.device_put(x, NamedSharding(mesh_a, P("data", "model")))
 save_checkpoint({"x": xs}, 3, path)
 
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))
 sh = {"x": NamedSharding(mesh_b, P("data", "model"))}
 loaded, step = load_checkpoint(path, {"x": x}, shardings=sh)
 assert step == 3

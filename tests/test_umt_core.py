@@ -452,6 +452,31 @@ def test_runtime_runs_tasks_and_results():
         assert [h.wait() for h in hs] == [i * i for i in range(20)]
 
 
+def test_shutdown_while_leader_spawns_a_worker():
+    """The Leader grows the worker set (append, then start) while a
+    shutdown may be joining it: shutdown must stop the Leader first, so
+    it never joins a worker that was appended but not yet started."""
+    from repro.core.runtime import Worker
+
+    rt = UMTRuntime(n_cores=1, umt=True, trace=False)
+    appended = threading.Event()
+
+    def slow_spawn_scan():              # runs on the Leader thread
+        if appended.is_set():
+            return
+        w = Worker(rt, 0)
+        rt._workers.append(w)
+        appended.set()
+        time.sleep(0.3)                 # shutdown starts joining here
+        w.start()
+
+    rt.leader_scan = slow_spawn_scan
+    assert appended.wait(5)
+    rt.shutdown()
+    assert not rt.leader.is_alive()
+    assert not any(w.is_alive() for w in rt._workers)
+
+
 def test_runtime_dependency_order():
     order = []
     lock = threading.Lock()
