@@ -194,6 +194,7 @@ def serve_engine(cfg, params, mesh, args):
         "occupancy": round(stats["occupancy"], 3),
         "p50_latency_s": round(stats["p50_latency_s"], 4),
         "p99_latency_s": round(stats["p99_latency_s"], 4),
+        "admit_wait_mean_s": stats["admit_wait_mean_s"],
         "generated_shape": list(gen.shape),
         "sample": [int(x) for x in jnp.ravel(gen)[:8]],
     }))

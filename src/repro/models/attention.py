@@ -1,5 +1,11 @@
 """Attention family: GQA (+QKV bias, sliding window) and MLA (latent KV).
 
+Attention's ops carry one of four named scopes in their HLO metadata, so
+a device profile splits attention time: ``attn.proj`` (norm, projections, rope,
+MLA absorption, output projection), ``attn.kv_write`` (cache writes),
+``attn.kv_gather`` (the paged leg's page gather) and ``attn.core``
+(scores, softmax and the weighted sum, or the paged kernel).
+
 Three execution paths, chosen by the step being lowered:
   * ``full``    — materialised scores, train-time (seq <= ~4k).
   * ``qchunk``  — lax.scan over query chunks, forward-only prefill (32k).
@@ -22,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 from ..kernels import paged_decode_attention, paged_mla_decode_attention
 from ..sharding import axis_size, current_mesh, resolved_axes, shard
 from .layers import (apply_rope, page_gather, page_scatter,
-                     page_scatter_window, rms_norm)
+                     page_scatter_window, rms_norm, scope)
 
 NEG_INF = -1e30
 
@@ -133,6 +139,7 @@ def _sdpa(q, k, v, bias, scale=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+@scope("attn.core")
 def full_attention(q, k, v, *, window=None, q_off=0, scale=None):
     k = _expand_kv(k, q.shape[2])
     v = _expand_kv(v, q.shape[2])
@@ -141,6 +148,7 @@ def full_attention(q, k, v, *, window=None, q_off=0, scale=None):
     return shard(out, "batch", "seq", "heads", "head_dim")
 
 
+@scope("attn.core")
 def qchunk_attention(q, k, v, *, window=None, chunk=512, scale=None):
     """Forward-only prefill: scan over query chunks vs full K/V."""
     b, s, h, dh = q.shape
@@ -161,6 +169,7 @@ def qchunk_attention(q, k, v, *, window=None, chunk=512, scale=None):
     return shard(out, "batch", "seq", "heads", "head_dim")
 
 
+@scope("attn.core")
 def decode_attention(q, k_cache, v_cache, pos, *, window=None, scale=None):
     """q: (B,1,H,Dh); caches: (B,Sc,Hkv,Dh) sequence-sharded; pos is a
     scalar (all rows at the same position) or (B,) per-slot positions
@@ -198,6 +207,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None, scale=None):
     return out / jnp.swapaxes(l, 1, 2).astype(q.dtype)   # (B,1,H,1)
 
 
+@scope("attn.core")
 def verify_attention(q, k_cache, v_cache, pos, *, scale=None):
     """Speculative-decode verify: q: (B,S,H,Dh) — S window lanes per slot,
     lane j at position ``pos_b + j``; caches: (B,Sc,Hkv,Dh) already holding
@@ -264,6 +274,7 @@ def gqa_cache_shapes(cfg, spec, batch, seq):
     return {"k": (kv, ax), "v": (kv, ax)}
 
 
+@scope("attn.kv_write")
 def _cache_update(c, u, idx):
     """Write the decode-step update ``u`` (B,1,...) into cache ``c``
     (B,Sc,...) at sequence index ``idx`` — scalar (one shared position)
@@ -282,6 +293,7 @@ def _cache_update(c, u, idx):
     return c.at[jnp.arange(c.shape[0]), idx].set(u[:, 0].astype(c.dtype))
 
 
+@scope("attn.kv_write")
 def _cache_update_window(c, u, pos, n_tok):
     """Dense-cache counterpart of :func:`page_scatter_window`: write the
     verify window ``u`` (B,S,...) into cache ``c`` (B,Sc,...) at per-slot
@@ -294,6 +306,7 @@ def _cache_update_window(c, u, pos, n_tok):
     return c.at[jnp.arange(b)[:, None], idx].set(u.astype(c.dtype))
 
 
+@scope("attn.kv_write")
 def _pad_seq(t, target):
     """Right-pad dim 1 (sequence) with zeros up to `target` slots."""
     if target is None or t.shape[1] >= target:
@@ -319,19 +332,19 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
     b, s, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = x.dtype
-    xn = rms_norm(x, p["ln"], cfg.norm_eps)
-
     d = x.shape[-1]
-    q = jnp.einsum("bsd,dhk->bshk", xn,
-                   p["wq"].astype(dt).reshape(d, h, dh))
-    k = jnp.einsum("bsd,dhk->bshk", xn,
-                   p["wk"].astype(dt).reshape(d, hkv, dh))
-    v = jnp.einsum("bsd,dhk->bshk", xn,
-                   p["wv"].astype(dt).reshape(d, hkv, dh))
-    if cfg.qkv_bias:
-        q = q + p["bq"].astype(dt).reshape(h, dh)
-        k = k + p["bk"].astype(dt).reshape(hkv, dh)
-        v = v + p["bv"].astype(dt).reshape(hkv, dh)
+    with jax.named_scope("attn.proj"):
+        xn = rms_norm(x, p["ln"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", xn,
+                       p["wq"].astype(dt).reshape(d, h, dh))
+        k = jnp.einsum("bsd,dhk->bshk", xn,
+                       p["wk"].astype(dt).reshape(d, hkv, dh))
+        v = jnp.einsum("bsd,dhk->bshk", xn,
+                       p["wv"].astype(dt).reshape(d, hkv, dh))
+        if cfg.qkv_bias:
+            q = q + p["bq"].astype(dt).reshape(h, dh)
+            k = k + p["bk"].astype(dt).reshape(hkv, dh)
+            v = v + p["bv"].astype(dt).reshape(hkv, dh)
 
     if mode == "decode":
         rp = pos[:, None] if jnp.ndim(pos) == 1 else pos   # (B,1) | scalar
@@ -352,25 +365,26 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                 # dense slot-major copy never exists
                 pv = pos if jnp.ndim(pos) == 1 else jnp.full((b,), pos)
                 axes = _tp_kernel_axes(h, hkv)
-                if axes:
-                    # tensor-parallel pool: one kernel per model shard.
-                    # Heads are kv-head-major contiguous, so an even
-                    # head split keeps every query head on the shard
-                    # holding its KV group — the kernel's group math is
-                    # local and per-head outputs are bit-identical to
-                    # the single-shard call.
-                    hspec = P(None, None, axes, None)
-                    out = jax.shard_map(
-                        functools.partial(paged_decode_attention,
-                                          page_size=ps, window=w),
-                        mesh=current_mesh(),
-                        in_specs=(hspec, hspec, hspec, P(None, None),
-                                  P(None)),
-                        out_specs=hspec, check_vma=False,
-                    )(q, kc, vc, table, pv)
-                else:
-                    out = paged_decode_attention(q, kc, vc, table, pv,
-                                                 page_size=ps, window=w)
+                with jax.named_scope("attn.core"):
+                    if axes:
+                        # tensor-parallel pool: one kernel per model
+                        # shard.  Heads are kv-head-major contiguous, so
+                        # an even head split keeps every query head on
+                        # the shard holding its KV group — the kernel's
+                        # group math is local and per-head outputs are
+                        # bit-identical to the single-shard call.
+                        hspec = P(None, None, axes, None)
+                        out = jax.shard_map(
+                            functools.partial(paged_decode_attention,
+                                              page_size=ps, window=w),
+                            mesh=current_mesh(),
+                            in_specs=(hspec, hspec, hspec, P(None, None),
+                                      P(None)),
+                            out_specs=hspec, check_vma=False,
+                        )(q, kc, vc, table, pv)
+                    else:
+                        out = paged_decode_attention(
+                            q, kc, vc, table, pv, page_size=ps, window=w)
             else:
                 kd = shard(page_gather(kc, table, ps),
                            "batch", "seq_shard", "kv_heads", None)
@@ -430,8 +444,11 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             # pattern is validated by make_prefill_chunk_step.
             kc, vc = cache["k"], cache["v"]
             start = (0, pos) + (0,) * (kc.ndim - 2)
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), start)
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), start)
+            with jax.named_scope("attn.kv_write"):
+                kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                                  start)
+                vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                                  start)
             kc = shard(kc, "batch", "seq_shard", "kv_heads", None)
             vc = shard(vc, "batch", "seq_shard", "kv_heads", None)
             # static extent bucket: attend only the prefix that can hold
@@ -450,7 +467,8 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                 # than the window before the cache itself runs out
                 ring = w if cache_len is None else min(w, cache_len)
                 if s >= ring:
-                    kc, vc = k[:, s - ring:], v[:, s - ring:]  # slot=pos%W
+                    with jax.named_scope("attn.kv_write"):  # slot=pos%W
+                        kc, vc = k[:, s - ring:], v[:, s - ring:]
                 else:
                     kc, vc = _pad_seq(k, ring), _pad_seq(v, ring)
             else:
@@ -463,8 +481,9 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             out = full_attention(q, k, v, window=spec.window)
             new_cache = None
 
-    out = jnp.einsum("bshk,hkd->bsd", out,
-                     p["wo"].astype(dt).reshape(h, dh, x.shape[-1]))
+    with jax.named_scope("attn.proj"):
+        out = jnp.einsum("bshk,hkd->bsd", out,
+                         p["wo"].astype(dt).reshape(h, dh, x.shape[-1]))
     return shard(out, "batch", "seq", "embed"), new_cache
 
 
@@ -512,12 +531,12 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                        cfg.v_head_dim)
     dt = x.dtype
     scale = (dn + dr) ** -0.5
-    xn = rms_norm(x, p["ln"], cfg.norm_eps)
-
-    q_nope, q_rope = _mla_q(xn, p, cfg, dt)
-    kva = jnp.einsum("bsd,dr->bsr", xn, p["wkv_a"].astype(dt))
-    ckv = rms_norm(kva[..., :rkv], p["kv_ln"], cfg.norm_eps)   # (B,S,rkv)
-    k_rope = kva[..., rkv:]                                    # (B,S,dr)
+    with jax.named_scope("attn.proj"):
+        xn = rms_norm(x, p["ln"], cfg.norm_eps)
+        q_nope, q_rope = _mla_q(xn, p, cfg, dt)
+        kva = jnp.einsum("bsd,dr->bsr", xn, p["wkv_a"].astype(dt))
+        ckv = rms_norm(kva[..., :rkv], p["kv_ln"], cfg.norm_eps)  # (B,S,rkv)
+        k_rope = kva[..., rkv:]                                   # (B,S,dr)
 
     if mode == "decode":
         # absorbed decode: scores live in the latent space.
@@ -543,45 +562,48 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             cc = shard(cc, "batch", "seq_shard", None)
             kr = shard(kr, "batch", "seq_shard", None)
             cd, kd = cc, kr
-        wk_b = p["wk_b"].astype(dt).reshape(rkv, h, dn)
-        # absorb q_nope through wk_b:  (B,1,H,rkv)
-        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, wk_b)
-        if fused:
-            # fused paged path: latent pages read in place (the absorbed
-            # form's V is its K, so the kernel returns the attended
-            # latent and wv_b applies outside)
-            pv = pos if per_slot else jnp.full((b,), pos)
-            axes = _tp_kernel_axes(h)
-            if axes:
-                # tensor-parallel MLA: the latent pools carry no head
-                # dim (replicated); only the query splits, one kernel
-                # per model shard over its local query heads
-                hspec = P(None, None, axes, None)
-                rep3 = P(None, None, None)
-                lat = jax.shard_map(
-                    functools.partial(paged_mla_decode_attention,
-                                      page_size=ps, scale=scale),
-                    mesh=current_mesh(),
-                    in_specs=(hspec, hspec, rep3, rep3, P(None, None),
-                              P(None)),
-                    out_specs=hspec, check_vma=False,
-                )(q_lat, q_rope, cc, kr, table, pv)
+        with jax.named_scope("attn.proj"):
+            wk_b = p["wk_b"].astype(dt).reshape(rkv, h, dn)
+            # absorb q_nope through wk_b:  (B,1,H,rkv)
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, wk_b)
+        with jax.named_scope("attn.core"):
+            if fused:
+                # fused paged path: latent pages read in place (the
+                # absorbed form's V is its K, so the kernel returns the
+                # attended latent and wv_b applies outside)
+                pv = pos if per_slot else jnp.full((b,), pos)
+                axes = _tp_kernel_axes(h)
+                if axes:
+                    # tensor-parallel MLA: the latent pools carry no head
+                    # dim (replicated); only the query splits, one kernel
+                    # per model shard over its local query heads
+                    hspec = P(None, None, axes, None)
+                    rep3 = P(None, None, None)
+                    lat = jax.shard_map(
+                        functools.partial(paged_mla_decode_attention,
+                                          page_size=ps, scale=scale),
+                        mesh=current_mesh(),
+                        in_specs=(hspec, hspec, rep3, rep3, P(None, None),
+                                  P(None)),
+                        out_specs=hspec, check_vma=False,
+                    )(q_lat, q_rope, cc, kr, table, pv)
+                else:
+                    lat = paged_mla_decode_attention(
+                        q_lat, q_rope, cc, kr, table, pv, page_size=ps,
+                        scale=scale)
             else:
-                lat = paged_mla_decode_attention(q_lat, q_rope, cc, kr,
-                                                 table, pv, page_size=ps,
-                                                 scale=scale)
-        else:
-            scores = (jnp.einsum("bshr,btr->bhst", q_lat, cd) +
-                      jnp.einsum("bshr,btr->bhst", q_rope, kd))
-            scores = scores.astype(jnp.float32) * scale
-            valid = jnp.arange(cd.shape[1]) <= rp          # (B,T) | (T,)
-            mb = jnp.where(valid, 0.0, NEG_INF)
-            scores = scores + (mb[:, None, None, :] if per_slot
-                               else mb[None, None, None, :])
-            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-            lat = jnp.einsum("bhst,btr->bshr", probs, cd)      # (B,1,H,rkv)
-        out = jnp.einsum("bshr,rhv->bshv", lat,
-                         p["wv_b"].astype(dt).reshape(rkv, h, dv))
+                scores = (jnp.einsum("bshr,btr->bhst", q_lat, cd) +
+                          jnp.einsum("bshr,btr->bhst", q_rope, kd))
+                scores = scores.astype(jnp.float32) * scale
+                valid = jnp.arange(cd.shape[1]) <= rp      # (B,T) | (T,)
+                mb = jnp.where(valid, 0.0, NEG_INF)
+                scores = scores + (mb[:, None, None, :] if per_slot
+                                   else mb[None, None, None, :])
+                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+                lat = jnp.einsum("bhst,btr->bshr", probs, cd)  # (B,1,H,rkv)
+        with jax.named_scope("attn.proj"):
+            out = jnp.einsum("bshr,rhv->bshv", lat,
+                             p["wv_b"].astype(dt).reshape(rkv, h, dv))
         new_cache = {"ckv": cc, "krope": kr}
     elif mode == "verify":
         # speculative-decode verify: the ABSORBED decode form generalised
@@ -608,18 +630,22 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             cc = shard(cc, "batch", "seq_shard", None)
             kr = shard(kr, "batch", "seq_shard", None)
             cd, kd = cc, kr
-        wk_b = p["wk_b"].astype(dt).reshape(rkv, h, dn)
-        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, wk_b)  # (B,S,H,rkv)
-        scores = (jnp.einsum("bshr,btr->bhst", q_lat, cd) +
-                  jnp.einsum("bshr,btr->bhst", q_rope, kd))
-        scores = scores.astype(jnp.float32) * scale
-        valid = jnp.arange(cd.shape[1])[None, None, :] <= qp[:, :, None]
-        mb = jnp.where(valid, 0.0, NEG_INF)                # (B,S,T)
-        scores = scores + mb[:, None]                      # (B,1,S,T)
-        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-        lat = jnp.einsum("bhst,btr->bshr", probs, cd)      # (B,S,H,rkv)
-        out = jnp.einsum("bshr,rhv->bshv", lat,
-                         p["wv_b"].astype(dt).reshape(rkv, h, dv))
+        with jax.named_scope("attn.proj"):
+            wk_b = p["wk_b"].astype(dt).reshape(rkv, h, dn)
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, wk_b)  # (B,S,H,rkv)
+        with jax.named_scope("attn.core"):
+            scores = (jnp.einsum("bshr,btr->bhst", q_lat, cd) +
+                      jnp.einsum("bshr,btr->bhst", q_rope, kd))
+            scores = scores.astype(jnp.float32) * scale
+            valid = (jnp.arange(cd.shape[1])[None, None, :]
+                     <= qp[:, :, None])
+            mb = jnp.where(valid, 0.0, NEG_INF)            # (B,S,T)
+            scores = scores + mb[:, None]                  # (B,1,S,T)
+            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+            lat = jnp.einsum("bhst,btr->bshr", probs, cd)  # (B,S,H,rkv)
+        with jax.named_scope("attn.proj"):
+            out = jnp.einsum("bshr,rhv->bshv", lat,
+                             p["wv_b"].astype(dt).reshape(rkv, h, dv))
         new_cache = {"ckv": cc, "krope": kr}
     elif mode == "prefill_chunk":
         # cache-append chunk: write this chunk's latent into the dense row
@@ -632,23 +658,26 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
         k_rope = apply_rope(k_rope[:, :, None, :], positions,
                             cfg.rope_theta)[:, :, 0, :]
         cc, kr = cache["ckv"], cache["krope"]
-        cc = jax.lax.dynamic_update_slice(cc, ckv.astype(cc.dtype),
-                                          (0, pos, 0))
-        kr = jax.lax.dynamic_update_slice(kr, k_rope.astype(kr.dtype),
-                                          (0, pos, 0))
+        with jax.named_scope("attn.kv_write"):
+            cc = jax.lax.dynamic_update_slice(cc, ckv.astype(cc.dtype),
+                                              (0, pos, 0))
+            kr = jax.lax.dynamic_update_slice(kr, k_rope.astype(kr.dtype),
+                                              (0, pos, 0))
         cc = shard(cc, "batch", "seq_shard", None)
         kr = shard(kr, "batch", "seq_shard", None)
         # static extent bucket (see gqa chunk branch): reconstruct and
         # attend only the key prefix that can be valid
         sk = cc.shape[1] if attn_extent is None else attn_extent
-        k_nope = jnp.einsum("bsr,rhk->bshk", cc[:, :sk].astype(dt),
-                            p["wk_b"].astype(dt).reshape(rkv, h, dn))
-        vfull = jnp.einsum("bsr,rhk->bshk", cc[:, :sk].astype(dt),
-                           p["wv_b"].astype(dt).reshape(rkv, h, dv))
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(kr[:, :sk].astype(dt)[:, :, None, :],
-                                      (b, sk, h, dr))], axis=-1)
+        with jax.named_scope("attn.proj"):
+            k_nope = jnp.einsum("bsr,rhk->bshk", cc[:, :sk].astype(dt),
+                                p["wk_b"].astype(dt).reshape(rkv, h, dn))
+            vfull = jnp.einsum("bsr,rhk->bshk", cc[:, :sk].astype(dt),
+                               p["wv_b"].astype(dt).reshape(rkv, h, dv))
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope,
+                 jnp.broadcast_to(kr[:, :sk].astype(dt)[:, :, None, :],
+                                  (b, sk, h, dr))], axis=-1)
         q = shard(q, "batch", "seq", "heads", "head_dim")
         k = shard(k, "batch", "seq", "heads", "head_dim")
         out = full_attention(q, k, vfull, q_off=pos, scale=scale)
@@ -658,14 +687,15 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
         q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
         k_rope = apply_rope(k_rope[:, :, None, :], positions,
                             cfg.rope_theta)[:, :, 0, :]
-        k_nope = jnp.einsum("bsr,rhk->bshk", ckv,
-                            p["wk_b"].astype(dt).reshape(rkv, h, dn))
-        vfull = jnp.einsum("bsr,rhk->bshk", ckv,
-                           p["wv_b"].astype(dt).reshape(rkv, h, dv))
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                      (b, s, h, dr))], axis=-1)
+        with jax.named_scope("attn.proj"):
+            k_nope = jnp.einsum("bsr,rhk->bshk", ckv,
+                                p["wk_b"].astype(dt).reshape(rkv, h, dn))
+            vfull = jnp.einsum("bsr,rhk->bshk", ckv,
+                               p["wv_b"].astype(dt).reshape(rkv, h, dv))
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                          (b, s, h, dr))], axis=-1)
         q = shard(q, "batch", "seq", "heads", "head_dim")
         k = shard(k, "batch", "seq", "heads", "head_dim")
         if mode == "prefill":
@@ -680,6 +710,7 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             out = full_attention(q, k, vfull, scale=scale)
             new_cache = None
 
-    out = jnp.einsum("bshk,hkd->bsd", out,
-                     p["wo"].astype(dt).reshape(h, dv, x.shape[-1]))
+    with jax.named_scope("attn.proj"):
+        out = jnp.einsum("bshk,hkd->bsd", out,
+                         p["wo"].astype(dt).reshape(h, dv, x.shape[-1]))
     return shard(out, "batch", "seq", "embed"), new_cache

@@ -1,6 +1,7 @@
 """Residual block = mixer (attention or SSD) + MLP (dense / MoE / none)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from . import attention as attn_mod
@@ -66,11 +67,12 @@ def block_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
     x = x + out
     aux = jnp.zeros((), jnp.float32)
     if spec.mlp != "none":
-        xn = rms_norm(x, p["mlp"]["ln"], cfg.norm_eps)
-        if spec.mlp == "dense":
-            y = mlp_dense(xn, p["mlp"], cfg)
-        else:
-            fn = moe_mlp_ragged if cfg.moe_impl == "ragged" else moe_mlp
-            y, aux = fn(xn, p["mlp"], cfg)
+        with jax.named_scope("mlp"):
+            xn = rms_norm(x, p["mlp"]["ln"], cfg.norm_eps)
+            if spec.mlp == "dense":
+                y = mlp_dense(xn, p["mlp"], cfg)
+            else:
+                fn = moe_mlp_ragged if cfg.moe_impl == "ragged" else moe_mlp
+                y, aux = fn(xn, p["mlp"], cfg)
         x = x + y
     return x, new_cache, aux

@@ -1,11 +1,27 @@
 """Shared layers: norms, rope, MLPs, embeddings, losses (pure JAX)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..sharding import shard
+
+
+def scope(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    each op it emits carries ``name`` in its HLO ``op_name`` metadata and
+    a device profile can attribute the op's time to it.  Metadata only:
+    the compiled program is the same with or without it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return scoped
+    return wrap
 
 
 def rms_norm(x, w, eps=1e-5):
@@ -43,6 +59,7 @@ def check_cache_invariant(old, new, where: str = "block"):
 
 
 # ------------------------------------------------------------------- paging
+@scope("attn.kv_gather")
 def page_gather(pool, table, page_size):
     """Materialise a slot-major dense view of a paged KV pool.
 
@@ -63,6 +80,7 @@ def page_gather(pool, table, page_size):
     return gathered.reshape((b, pps * page_size) + pool.shape[2:])
 
 
+@scope("attn.kv_write")
 def page_scatter(pool, table, page_size, idx, update):
     """Write one token row per slot into the paged pool.
 
@@ -77,6 +95,7 @@ def page_scatter(pool, table, page_size, idx, update):
         update[:, 0].astype(pool.dtype))
 
 
+@scope("attn.kv_write")
 def page_scatter_window(pool, table, page_size, pos, update, n_tok):
     """Write a per-slot multi-token window into the paged pool (the
     speculative-decode verify append).
@@ -106,6 +125,7 @@ def rope_freqs(dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
 
 
+@scope("attn.proj")
 def apply_rope(x, pos, theta=10_000.0):
     """x: (..., S, H, Dh) or (..., S, Dh); pos: scalar, (S,), or (B, S)
     (per-slot decode positions, continuous batching) — broadcast over x."""

@@ -174,7 +174,8 @@ def forward(params, cfg, tokens, *, mode="train", pos=0, cache=None,
     ``repro.models.layers.check_cache_invariant``).
     """
     dt = jnp.dtype(cfg.dtype)
-    x = embed_tokens(tokens, params["embed"], cfg, dt)
+    with jax.named_scope("embed"):
+        x = embed_tokens(tokens, params["embed"], cfg, dt)
     if cfg.frontend == "vision_patches" and mode in ("train", "prefill"):
         assert patches is not None
         x = jnp.concatenate([patches.astype(dt), x], axis=1)
@@ -247,8 +248,9 @@ def forward(params, cfg, tokens, *, mode="train", pos=0, cache=None,
         x = x[:, -1:]
     if not want_logits:                 # non-final chunk: cache only
         return {"logits": None, "cache": new_cache, "aux": aux}
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(x, params, cfg)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(x, params, cfg)
     return {"logits": logits, "cache": new_cache, "aux": aux}
 
 

@@ -384,6 +384,9 @@ class ServeEngine:
         self.rt = rt if rt is not None else UMTRuntime(
             n_cores=n_cores, umt=umt, trace=False)
         self._own_rt = rt is None
+        # spans of the decode and prefill tasks (a no-op unless the
+        # runtime traces): ServeEngine.stats() reports their self time
+        self._span = self.rt.tracer.span
         # the baseline runtime never backfills a blocked worker's core, so
         # intake (blocked on the queue) + the decode driver permanently
         # occupy two workers — prefill needs at least a third to make
@@ -622,6 +625,8 @@ class ServeEngine:
             maxlen=4096)
         self._tick_intervals: collections.deque = collections.deque(
             maxlen=65536)
+        self._admit_wait_sum = 0.0      # t_admit - t_submit, first admission
+        self._n_admitted = 0
         self._last_tick_t: float | None = None
         self.stats_ticks = 0
         self.stats_occupancy_sum = 0.0
@@ -972,9 +977,20 @@ class ServeEngine:
                 st["unaccounted"] = []
                 raise
             return
-        rows_cache, logits = self._dispatch(self.prefill, self._params,
-                                            tj, pj)
+        with self._span("prefill.dispatch", [r.rid for r in grp]):
+            rows_cache, logits = self._dispatch(self.prefill, self._params,
+                                                tj, pj)
+        self._stamp_prefill(grp)
         self._account_prefilled(grp, remaining, rows_cache, logits)
+
+    @staticmethod
+    def _stamp_prefill(reqs):
+        """``t_prefill``: the request's first prefill dispatch (one-shot,
+        first chunk or prefix gather); a restore keeps the first."""
+        now = time.monotonic()
+        for r in reqs:
+            if r.t_prefill is None:
+                r.t_prefill = now
 
     def _try_prefix_prefill(self, req, remaining) -> bool:
         """Prefix-cache hit path.  Match the prompt's longest cached
@@ -1011,11 +1027,14 @@ class ServeEngine:
             # the gather before the donating step recycles the buffers
             trow_dev, pos_dev = kv.to_dev(trow), kv.to_dev(
                 np.int32(m.tokens))
-            with self._pool_lock:
+            with self._span("prefill.dispatch", (req.rid,)), \
+                    self._pool_lock:
                 src = kv.cache
                 rows_cache = self._dispatch(self.gather, src, trow_dev,
                                             pos_dev)
-            jax.block_until_ready(rows_cache["pos"])
+            self._stamp_prefill((req,))
+            with self._span("prefill.sync", (req.rid,)):
+                jax.block_until_ready(rows_cache["pos"])
             del src, trow_dev, pos_dev
             # fork content copied: drop its hold (the matched full
             # pages' holds ride to admission on the request)
@@ -1071,10 +1090,13 @@ class ServeEngine:
             # _do_inserts)
             chunk_toks, off_dev = tj[:, c0:c1], self.kv.to_dev(
                 np.int32(off))
-            rows_cache, logits = self._dispatch(
-                self.chunk, self._params, old_rows, chunk_toks, off_dev,
-                pj if first else None, attn_extent=ext,
-                want_logits=c1 >= plen)
+            rids = [r.rid for r in grp]
+            with self._span("prefill.dispatch", rids):
+                rows_cache, logits = self._dispatch(
+                    self.chunk, self._params, old_rows, chunk_toks, off_dev,
+                    pj if first else None, attn_extent=ext,
+                    want_logits=c1 >= plen)
+            self._stamp_prefill(grp)
             st.update(rows_cache=rows_cache, off=covered, c0=c1,
                       first=False, chunks=st["chunks"] + 1)
             # complete the chunk before the next task dispatches it:
@@ -1084,7 +1106,8 @@ class ServeEngine:
             # decode ticks interleave.  ``old_rows`` stays referenced
             # until this sync, so the chunk chain (donated or copied)
             # never drops a version a pending dispatch still reads.
-            jax.block_until_ready(rows_cache["pos"])
+            with self._span("prefill.sync", rids):
+                jax.block_until_ready(rows_cache["pos"])
             del old_rows, chunk_toks, off_dev
             with self._lock:
                 self.stats_prefill_chunk_tasks += 1
@@ -1113,8 +1136,10 @@ class ServeEngine:
         unaccounted ones."""
         t0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         # force the first token before stamping TTFT — dispatch is
-        # async, so a monotonic() above the sync would under-report
-        t0.block_until_ready()
+        # async, so a monotonic() above the sync would under-report;
+        # the prefill runs behind every decode tick already dispatched
+        with self._span("prefill.first_token", [r.rid for r in grp]):
+            t0.block_until_ready()
         t0_host = np.asarray(t0)        # already forced: free
         now = time.monotonic()
         with self._lock:                # rounds run on concurrent workers
@@ -1207,7 +1232,8 @@ class ServeEngine:
             nxts.append(nxt)
         # one sync for the whole chain (dispatch stays pipelined), then
         # verify every replayed argmax against the recorded stream
-        jax.block_until_ready(cache["pos"])
+        with self._span("prefill.sync", (req.rid,)):
+            jax.block_until_ready(cache["pos"])
         pins.clear()
         for k, nxt in enumerate(nxts):
             assert np.array_equal(np.asarray(nxt)[0, 0], toks[k + 1]), (
@@ -1318,106 +1344,120 @@ class ServeEngine:
                 if not self._inserts:
                     return
                 entry = self._inserts[0]
-            req, rows_cache, row, t0 = entry
-            ids = None
-            shared: list[int] = []
-            if self.paged:
-                # a prefix hit already holds its matched pages — only
-                # the uncached remainder is allocated (never zero: at
-                # least the last prompt position was recomputed)
-                if req.prefix_hold is not None:
-                    shared = list(req.prefix_hold.pages)
-                need = self.pager.pages_for(
-                    self.policy.admission_tokens(self, req)) - len(shared)
-                ids = self._alloc_pages(need)
-                if ids is None and shared:
-                    # a blocked head must hold nothing — the
-                    # deadlock-freedom argument (a lone live slot can
-                    # always grow; every eviction strictly frees) breaks
-                    # if blocked admissions pin pages.  Dropping the
-                    # hold is always sound: the gathered row cache holds
-                    # the complete prefix content, so the admission
-                    # falls back to cold-shaped (all pages fresh) — the
-                    # prefill compute stayed saved, only page dedup is
-                    # lost.  The dropped pages revert to refcount-0
-                    # cached: this very retry's reclaim may harvest them.
-                    self._drop_prefix_hold(req)
-                    shared = []
-                    need = self.pager.pages_for(
-                        self.policy.admission_tokens(self, req))
-                    ids = self._alloc_pages(need)
-                if ids is None and not self._active.any() \
-                        and self._drop_queued_holds():
-                    # nothing live will ever free a page, so what the
-                    # head lacks was held by rows queued behind it, which
-                    # only their own admission would release: they fall
-                    # back to cold-shaped too (sound as above) — retry
-                    continue
-                if ids is None:         # admission blocked on free pages
-                    if self._blocked_head != req.rid:
-                        self._blocked_head = req.rid
-                        self.stats_admission_blocks += 1
+            with self._span("insert", (entry[0].rid,)):
+                if not self._admit(entry, free):
                     return
-            self._blocked_head = None
-            with self._lock:
-                # dequeue this row, not the head: a prefill worker may
-                # have pushed a restore there (appendleft) since the peek
-                i = next(i for i, e in enumerate(self._inserts)
-                         if e is entry)
-                del self._inserts[i]
-            s = int(self.policy.select_slot(self, free))
-            assert not self._active[s], \
-                f"policy picked a live slot {s} for admission"
-            kv = self.kv
-            row_dev, slot_dev = kv.to_dev(np.int32(row)), \
-                kv.to_dev(np.int32(s))
-            # dispatch temporaries the pending insert reads whose Python
-            # refs drop at the end of this iteration: pin until a sync
-            kv.pin(rows_cache, t0, row_dev, slot_dev)
-            if self.paged:
-                req.pages = shared + ids
-                req.prefix_hold = None  # holds adopted as the slot's own
-                table_row = kv.bind_slot_pages(s, req.pages,
-                                               n_shared=len(shared))
-                kv.pin(table_row)
-                if kv.debug_validate:
-                    for pid in ids:     # fresh pages must be private
-                        assert self.pager.refcount(pid) == 1 \
-                            and not self.pager.is_cached(pid), (
-                            f"freshly allocated page {pid} is shared")
-                with self._pool_lock:
-                    new_cache = self._dispatch(self.insert, kv.cache,
-                                               rows_cache, row_dev,
-                                               slot_dev, table_row)
-                    # donated: the displaced version was consumed by the
-                    # insert (never pinned); copied: commit pins it
-                    kv.commit(new_cache, donated=self.donate)
-            else:
-                with self._pool_lock:
-                    new_cache = self._dispatch(self.insert, kv.cache,
-                                               rows_cache, row_dev,
-                                               slot_dev)
-                    kv.commit(new_cache, donated=self.donate)
-            self._rebind_tokens(self._tokens.at[s].set(t0[row]))
-            self._active[s] = True
-            self._rebind_active()
-            self._slot_req[s] = req
-            req.slot = s
-            self._slot_pos[s] = req.total_len   # next cache write position
-            self._admit_seq += 1
-            self._slot_seq[s] = self._admit_seq
-            # make the admitted prompt's complete pages reusable the
-            # moment their content lands: the trie adopts every full
-            # page of the written extent (first-wins on runs an earlier
-            # admission already cached).  The insert dispatch above is
-            # ordered (pool lock + device FIFO) before any gather a
-            # concurrent matcher issues, so a hit can never read pages
-            # whose content is still in flight.
-            if self.prefix is not None and req.patches is None \
-                    and req.total_len >= self.page_size:
-                key = (req.restore_tokens
-                       if req.restore_tokens is not None else req.tokens)
-                self.prefix.insert(key, req.pages, req.total_len)
+
+    def _admit(self, entry, free) -> bool:
+        """Admit the queued row ``entry`` into one of the ``free`` slots;
+        False when its admission blocks on free pages."""
+        req, rows_cache, row, t0 = entry
+        ids = None
+        shared: list[int] = []
+        if self.paged:
+            # a prefix hit already holds its matched pages — only
+            # the uncached remainder is allocated (never zero: at
+            # least the last prompt position was recomputed)
+            if req.prefix_hold is not None:
+                shared = list(req.prefix_hold.pages)
+            need = self.pager.pages_for(
+                self.policy.admission_tokens(self, req)) - len(shared)
+            ids = self._alloc_pages(need)
+            if ids is None and shared:
+                # a blocked head must hold nothing — the
+                # deadlock-freedom argument (a lone live slot can
+                # always grow; every eviction strictly frees) breaks
+                # if blocked admissions pin pages.  Dropping the
+                # hold is always sound: the gathered row cache holds
+                # the complete prefix content, so the admission
+                # falls back to cold-shaped (all pages fresh) — the
+                # prefill compute stayed saved, only page dedup is
+                # lost.  The dropped pages revert to refcount-0
+                # cached: this very retry's reclaim may harvest them.
+                self._drop_prefix_hold(req)
+                shared = []
+                need = self.pager.pages_for(
+                    self.policy.admission_tokens(self, req))
+                ids = self._alloc_pages(need)
+            if ids is None and not self._active.any() \
+                    and self._drop_queued_holds():
+                # nothing live will ever free a page, so what the
+                # head lacks was held by rows queued behind it, which
+                # only their own admission would release: they fall
+                # back to cold-shaped too (sound as above) — retry
+                return True
+            if ids is None:         # admission blocked on free pages
+                if self._blocked_head != req.rid:
+                    self._blocked_head = req.rid
+                    self.stats_admission_blocks += 1
+                return False
+        self._blocked_head = None
+        with self._lock:
+            # dequeue this row, not the head: a prefill worker may
+            # have pushed a restore there (appendleft) since the peek
+            i = next(i for i, e in enumerate(self._inserts)
+                     if e is entry)
+            del self._inserts[i]
+        s = int(self.policy.select_slot(self, free))
+        assert not self._active[s], \
+            f"policy picked a live slot {s} for admission"
+        kv = self.kv
+        row_dev, slot_dev = kv.to_dev(np.int32(row)), \
+            kv.to_dev(np.int32(s))
+        # dispatch temporaries the pending insert reads whose Python
+        # refs drop when this admission returns: pin until a sync
+        kv.pin(rows_cache, t0, row_dev, slot_dev)
+        if self.paged:
+            req.pages = shared + ids
+            req.prefix_hold = None  # holds adopted as the slot's own
+            table_row = kv.bind_slot_pages(s, req.pages,
+                                           n_shared=len(shared))
+            kv.pin(table_row)
+            if kv.debug_validate:
+                for pid in ids:     # fresh pages must be private
+                    assert self.pager.refcount(pid) == 1 \
+                        and not self.pager.is_cached(pid), (
+                        f"freshly allocated page {pid} is shared")
+            with self._pool_lock:
+                new_cache = self._dispatch(self.insert, kv.cache,
+                                           rows_cache, row_dev,
+                                           slot_dev, table_row)
+                # donated: the displaced version was consumed by the
+                # insert (never pinned); copied: commit pins it
+                kv.commit(new_cache, donated=self.donate)
+        else:
+            with self._pool_lock:
+                new_cache = self._dispatch(self.insert, kv.cache,
+                                           rows_cache, row_dev,
+                                           slot_dev)
+                kv.commit(new_cache, donated=self.donate)
+        if req.t_admit is None:
+            req.t_admit = time.monotonic()
+            if req.t_submit is not None:    # queued through submit()
+                with self._lock:
+                    self._admit_wait_sum += req.t_admit - req.t_submit
+                    self._n_admitted += 1
+        self._rebind_tokens(self._tokens.at[s].set(t0[row]))
+        self._active[s] = True
+        self._rebind_active()
+        self._slot_req[s] = req
+        req.slot = s
+        self._slot_pos[s] = req.total_len   # next cache write position
+        self._admit_seq += 1
+        self._slot_seq[s] = self._admit_seq
+        # make the admitted prompt's complete pages reusable the
+        # moment their content lands: the trie adopts every full
+        # page of the written extent (first-wins on runs an earlier
+        # admission already cached).  The insert dispatch above is
+        # ordered (pool lock + device FIFO) before any gather a
+        # concurrent matcher issues, so a hit can never read pages
+        # whose content is still in flight.
+        if self.prefix is not None and req.patches is None \
+                and req.total_len >= self.page_size:
+            key = (req.restore_tokens
+                   if req.restore_tokens is not None else req.tokens)
+            self.prefix.insert(key, req.pages, req.total_len)
+        return True
 
     def _slot_views(self) -> list:
         """Read-only live-slot snapshots for policy decisions."""
@@ -1571,15 +1611,16 @@ class ServeEngine:
         # pre-dispatch policy window: unforced preemption, then on-demand
         # page faults (both may evict — the tick below only runs over
         # whatever is still live)
-        if self._policy_may_evict:
-            v = self.policy.maybe_evict(self, self._slot_views())
-            if v is not None:
-                self._evict_slot(int(v))
-                self._rebind_active()
-                if self.paged:
-                    kv.sync_table()
-        if self.paged:
-            self._page_faults()
+        with self._span("tick.policy"):
+            if self._policy_may_evict:
+                v = self.policy.maybe_evict(self, self._slot_views())
+                if v is not None:
+                    self._evict_slot(int(v))
+                    self._rebind_active()
+                    if self.paged:
+                        kv.sync_table()
+            if self.paged:
+                self._page_faults()
         if not self._active.any():
             return                      # everything evicted: no tick
         if kv.debug_validate and self.prefix is not None:
@@ -1595,7 +1636,7 @@ class ServeEngine:
                     not self.pager.is_cached(pid), (
                     f"slot {int(s)} would decode-write shared/cached "
                     f"page {pid}")
-        with self._pool_lock:
+        with self._span("tick.dispatch"), self._pool_lock:
             if self.paged:
                 new_tokens, new_cache = self._dispatch(
                     self.decode, self._params, kv.cache, self._tokens,
@@ -1645,9 +1686,10 @@ class ServeEngine:
                 # computation dispatched so far, so by the time the pages
                 # are freed and the block table rewritten nothing pending
                 # can still read them
-                self._finish(req)
-                self._prefix_insert_slot(req)  # pages -> reuse capital
-                self._release_slot(s)         # slot + pages freed now
+                with self._span("tick.finish", (req.rid,)):
+                    self._finish(req)
+                    self._prefix_insert_slot(req)  # pages -> reuse capital
+                    self._release_slot(s)         # slot + pages freed now
                 freed = True
         if freed:
             self._rebind_active()
@@ -1655,8 +1697,12 @@ class ServeEngine:
                 self.kv.sync_table()
         # freed: a finish forced the chain; sync_ticks / host_toks: this
         # tick's sync did.  Otherwise flush only past the depth cap.
-        self.kv.flush(synced=freed or self.sync_ticks
-                      or host_toks is not None)
+        synced = freed or self.sync_ticks or host_toks is not None
+        if synced or kv.pins <= kv.pin_max:
+            kv.flush(synced=synced)
+        else:
+            with self._span("tick.flush"):  # drains at the pin cap
+                kv.flush(synced=False)
 
     def _spec_window(self, req: Request) -> list[int]:
         """Draft a verify window for one live slot: the policy decides
@@ -1687,29 +1733,8 @@ class ServeEngine:
         pipeline, the spec leg buys fewer dispatches per committed token
         (the PASS-gated axis on this dispatch-bound container)."""
         kv = self.kv
-        if self._policy_may_evict:
-            v = self.policy.maybe_evict(self, self._slot_views())
-            if v is not None:
-                self._evict_slot(int(v))
-                self._rebind_active()
-                if self.paged:
-                    kv.sync_table()
-        # draft before the fault pass: on-demand growth must cover the
-        # whole verify window, not just the next position — a window can
-        # cross several page boundaries at once
-        drafts = {}
-        for s in np.flatnonzero(self._active):
-            s = int(s)
-            d = self._spec_window(self._slot_req[s])
-            if d:
-                drafts[s] = d
-        if self.paged:
-            ahead = np.zeros((self.slots,), np.int64)
-            for s, d in drafts.items():
-                ahead[s] = len(d)
-            self._page_faults(ahead=ahead)
-            # the fault pass may have evicted a drafted slot
-            drafts = {s: d for s, d in drafts.items() if self._active[s]}
+        with self._span("tick.policy"):
+            drafts = self._spec_policy()
         if not self._active.any():
             return                      # everything evicted: no tick
         live = [int(s) for s in np.flatnonzero(self._active)]
@@ -1741,7 +1766,7 @@ class ServeEngine:
         toks_dev = kv.to_dev(toks)
         pos_dev = kv.to_dev(self._slot_pos.astype(np.int32))
         n_dev = kv.to_dev(n_tok)
-        with self._pool_lock:
+        with self._span("tick.dispatch"), self._pool_lock:
             if self.paged:
                 nxt, new_cache = self._dispatch(
                     self.verify, self._params, kv.cache, toks_dev,
@@ -1800,9 +1825,10 @@ class ServeEngine:
                 if req.stopped:
                     with self._lock:
                         self.stats_stopped_early += 1
-                self._finish(req)
-                self._prefix_insert_slot(req)
-                self._release_slot(s)
+                with self._span("tick.finish", (req.rid,)):
+                    self._finish(req)
+                    self._prefix_insert_slot(req)
+                    self._release_slot(s)
                 freed = True
         if freed:
             self._rebind_active()
@@ -1810,6 +1836,35 @@ class ServeEngine:
                 self.kv.sync_table()
         # the host_nxt sync above proved the whole chain executed
         self.kv.flush(synced=True)
+
+    def _spec_policy(self) -> dict:
+        """A speculative tick's policy window: unforced preemption, a
+        draft per live slot, then the page faults the drafts' windows
+        need.  Returns {slot: draft} over the slots still live."""
+        if self._policy_may_evict:
+            v = self.policy.maybe_evict(self, self._slot_views())
+            if v is not None:
+                self._evict_slot(int(v))
+                self._rebind_active()
+                if self.paged:
+                    self.kv.sync_table()
+        # draft before the fault pass: on-demand growth must cover the
+        # whole verify window, not just the next position — a window can
+        # cross several page boundaries at once
+        drafts = {}
+        for s in np.flatnonzero(self._active):
+            s = int(s)
+            d = self._spec_window(self._slot_req[s])
+            if d:
+                drafts[s] = d
+        if self.paged:
+            ahead = np.zeros((self.slots,), np.int64)
+            for s, d in drafts.items():
+                ahead[s] = len(d)
+            self._page_faults(ahead=ahead)
+            # the fault pass may have evicted a drafted slot
+            drafts = {s: d for s, d in drafts.items() if self._active[s]}
+        return drafts
 
     def _drained(self) -> bool:
         with self._lock:
@@ -1820,8 +1875,9 @@ class ServeEngine:
         while True:
             self._do_inserts()
             if self._active.any():
-                self._tick_spec() if self.drafter is not None \
-                    else self._tick()
+                with self._span("tick"):
+                    self._tick_spec() if self.drafter is not None \
+                        else self._tick()
                 continue
             self._last_tick_t = None     # idle gap: not tick jitter
             if self._drained():
@@ -1848,6 +1904,7 @@ class ServeEngine:
             lats = sorted(self._lat_samples)
             ttfts = sorted(self._ttft_samples)
             ticks = sorted(self._tick_intervals)
+            admit_sum, n_admit = self._admit_wait_sum, self._n_admitted
         out = {
             "requests": n,
             "slots": self.slots,
@@ -1894,7 +1951,12 @@ class ServeEngine:
             "p50_tick_s": percentile(ticks, 0.50),
             "p99_tick_s": percentile(ticks, 0.99),
             "page_size": self.page_size,
+            # first admission's t_admit - t_submit, over admitted requests
+            "admit_wait_mean_s": admit_sum / n_admit if n_admit else None,
         }
+        if self.rt.tracer.enabled:
+            # seconds of each span's own work, children excluded
+            out["span_self_s"] = self.rt.tracer.span_self_s()
         out.update(self.kv.stats())     # versions, commits, pager pool
         if self.prefix is not None:
             out.update(self.prefix.stats())

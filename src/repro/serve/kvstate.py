@@ -151,7 +151,7 @@ class KVState:
         else:
             self.table_dev = None
         self._pins: list = []
-        self._pin_max = pin_max
+        self.pin_max = pin_max
         self.version = 0
         self.donated_commits = 0
         self.copied_commits = 0
@@ -208,7 +208,7 @@ class KVState:
         explicit drain first."""
         if synced:
             self._pins.clear()
-        elif len(self._pins) > self._pin_max:
+        elif len(self._pins) > self.pin_max:
             jax.block_until_ready(self.cache["pos"])
             self.pin_syncs += 1
             self._pins.clear()

@@ -117,10 +117,20 @@ class PrefixCache:
     def n_pages(self) -> int:
         """Pages currently owned by the trie."""
         with self._lock:
-            return self._count(self._root)
+            return self._count()
 
-    def _count(self, node) -> int:
-        return sum(1 + self._count(c) for c in node.children.values())
+    def _nodes(self):
+        """Every node below the root, depth first, siblings in insertion
+        order (a stack, not recursion: a cached 8k context at 8 tokens
+        a page is a chain 1,024 nodes deep)."""
+        stack = list(reversed(self._root.children.values()))
+        while stack:
+            node = stack.pop()
+            stack.extend(reversed(node.children.values()))
+            yield node
+
+    def _count(self) -> int:
+        return sum(1 for _ in self._nodes())
 
     def _touch(self, node) -> None:
         self._clock += 1
@@ -231,13 +241,8 @@ class PrefixCache:
                     heapq.heappush(heap, (node.last_used, seq, node))
                     seq += 1
 
-            def walk(node):
-                for c in node.children.values():
-                    walk(c)
-                if node is not self._root:
-                    push(node)
-
-            walk(self._root)
+            for node in self._nodes():
+                push(node)
             while heap and freed < n_pages:
                 _, _, node = heapq.heappop(heap)
                 if node.parent is None or node.children:
@@ -271,7 +276,7 @@ class PrefixCache:
 
     def stats(self) -> dict:
         with self._lock:
-            n = self._count(self._root)
+            n = self._count()
         return {
             "prefix_nodes": n,
             "prefix_lookups": self.lookups,

@@ -50,7 +50,8 @@ class Request:
       computation the first pass ran, so bit-exact by construction."""
 
     __slots__ = ("rid", "tokens", "patches", "max_new", "out_tokens",
-                 "t_submit", "t_first", "t_done", "done", "slot", "error",
+                 "t_submit", "t_prefill", "t_first", "t_admit", "t_done",
+                 "done", "slot", "error",
                  "eos_id", "stop", "stopped", "pages", "total_len",
                  "evictions", "resume", "restore_tokens", "prefix_hold",
                  "spec_drafted", "spec_accepted")
@@ -68,8 +69,13 @@ class Request:
             assert all(len(s) >= 1 for s in self.stop)
         self.stopped = False          # ended early on eos_id/stop
         self.out_tokens: list = []
+        # monotonic stamps: queued, first prefill dispatched, first token
+        # on the host, first inserted into a slot, completed (a restore
+        # keeps the first of each)
         self.t_submit: float | None = None
+        self.t_prefill: float | None = None
         self.t_first: float | None = None
+        self.t_admit: float | None = None
         self.t_done: float | None = None
         self.done = threading.Event()
         self.slot: int | None = None

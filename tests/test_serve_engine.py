@@ -598,3 +598,64 @@ def test_spec_decode_ab_bit_identical_fewer_dispatches(built):
     # the win: same tokens, fewer dispatches
     assert on["dispatches_per_token"] < off["dispatches_per_token"]
     assert on["decode_dispatches"] < off["decode_dispatches"]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("spec,trace", [(None, True), ("ngram", True),
+                                        (None, False)])
+def test_engine_stamps_and_spans(built, spec, trace):
+    """Every admitted request is stamped in order (submit, first prefill
+    dispatch, first token, admission, done), with tracing on or off; on
+    a traced runtime the decode task records one ``tick`` span per tick,
+    each admission an ``insert`` span with its rid, and ``stats()``
+    reports each span's self time and the mean admission wait."""
+    from repro.core import UMTRuntime
+    from repro.serve import ServeEngine
+
+    b = built
+    reqs = [Request(i, b["prompts"][i], max_new_tokens=1 + i % GEN_MAX)
+            for i in range(N_REQ)]
+    with UMTRuntime(n_cores=4, umt=True, trace=trace) as rt:
+        with ServeEngine(b["cfg"], b["params"], slots=3,
+                         cache_len=CACHE_LEN, rt=rt, jit_steps=b["steps"],
+                         page_size=PAGE_SIZE, spec=spec, spec_k=3) as eng:
+            for r in reqs:
+                eng.submit(r)
+            eng.close()
+            eng.join()
+            stats = eng.stats()
+        spans = rt.tracer.spans()
+    for r in reqs:
+        assert np.array_equal(np.asarray(r.wait(), np.int32),
+                              b["ref"][r.rid, :r.max_new])
+    admitted = [r for r in reqs if r.t_admit is not None]
+    # a one-token request is done at prefill and never takes a slot
+    assert {r.rid for r in admitted} == {r.rid for r in reqs
+                                         if r.max_new > 1}
+    for r in admitted:
+        assert r.t_submit <= r.t_prefill <= r.t_first <= r.t_admit \
+            <= r.t_done, r
+    waits = [r.t_admit - r.t_submit for r in admitted]
+    assert stats["admit_wait_mean_s"] == pytest.approx(np.mean(waits))
+    if not trace:
+        assert spans == [] and "span_self_s" not in stats
+        return
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    assert len(by["tick"]) == stats["ticks"] > 0
+    assert len(by["tick.dispatch"]) == stats["decode_dispatches"]
+    assert len(by["prefill.first_token"]) == stats["prefill_calls"]
+    # one tick.finish per completion in a tick, tagged with its rid
+    assert sorted(s.rids[0] for s in by["tick.finish"]) == sorted(
+        r.rid for r in admitted)
+    assert {r.rid for r in admitted} <= {s.rids[0] for s in by["insert"]}
+    ticks = {s.span_id for s in by["tick"]}
+    for name in ("tick.policy", "tick.dispatch", "tick.finish"):
+        assert all(s.parent in ticks for s in by[name]), name
+    assert all(s.parent is None for s in by["insert"] + by["tick"])
+    self_s = stats["span_self_s"]
+    assert set(by) == set(self_s)
+    assert {"tick", "tick.policy", "tick.dispatch", "tick.finish",
+            "insert", "prefill.dispatch", "prefill.first_token"} <= set(by)
+    assert all(v >= 0 for v in self_s.values())

@@ -324,3 +324,32 @@ def test_trie_clear_uncaches_everything():
     assert p.used_pages == 1 and not p.is_cached(held[0])
     p.release(held)
     assert p.used_pages == 0
+
+
+@pytest.mark.parametrize("walk", ["stats", "n_pages", "evict_lru"])
+def test_trie_walks_a_chain_deeper_than_the_recursion_limit(walk):
+    """An 8k context at page size 8 caches a 1,024-page chain; the trie's
+    walks must not spend a Python frame per page (the default limit is
+    1,000).  1,100 pages of size 1, under the default limit."""
+    import sys
+
+    n = 1_100
+    p, t = _trie(num_pages=n + 1, page_size=1)
+    ids = p.alloc(n)
+    t.insert(np.arange(n), ids, n)
+    p.release(ids)
+    limit = sys.getrecursionlimit()
+    sys.setrecursionlimit(1_000)
+    try:
+        if walk == "stats":
+            assert t.stats()["prefix_nodes"] == n
+        elif walk == "n_pages":
+            assert t.n_pages == n
+        else:
+            # leaf first, each freed leaf exposing its parent: the chain
+            assert t.evict_lru(n) == n
+            assert p.used_pages == 0
+    finally:
+        sys.setrecursionlimit(limit)
+    if walk == "evict_lru":
+        assert t.n_pages == 0

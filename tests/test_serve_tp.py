@@ -184,3 +184,37 @@ print("TP_SYS_OK")
     for tag in ("BYTES_OK", "COLL_OK", "ALIAS_OK", "TP_SYS_OK"):
         assert tag in out.stdout, (tag, out.stdout[-1500:],
                                    out.stderr[-3000:])
+
+
+@pytest.mark.slow
+def test_tp_decode_carries_named_scopes():
+    """The sharded decode step keeps its module name and every named
+    scope after partitioning, the paged kernel's ``shard_map`` leg
+    included, for latent (MLA) and grouped (GQA) attention."""
+    body = r"""
+import re
+want = {"attn.proj", "attn.kv_write", "attn.kv_gather", "attn.core",
+        "mlp", "embed", "head"}
+mesh = make_mesh((1, 4), ("data", "model"))
+for arch in ("minicpm3-4b", "qwen2.5-14b"):
+    cfg, params, _ = build(arch)
+    for kernel in (False, True):
+        with ServeEngine(cfg, params, slots=3, cache_len=CACHE_LEN,
+                         mesh=mesh, umt=False, n_cores=4, page_size=PAGE,
+                         paged_kernel=kernel) as eng:
+            kv = eng.kv
+            txt = eng.decode.lower(eng._params, kv.cache, eng._tokens,
+                                   eng._active_dev, kv.table_dev
+                                   ).compile().as_text()
+        assert txt.startswith("HloModule jit_decode_step,"), txt[:80]
+        got = set()
+        for name in set(re.findall(r'op_name="([^"]*)"', txt)):
+            got.update(seg for seg in name.split("/") if seg in want)
+        assert got == want - ({"attn.kv_gather"} if kernel else set()), (
+            arch, kernel, sorted(got))
+        print("SCOPES_OK", arch, kernel)
+print("TP_SCOPES_OK")
+"""
+    out = _run(_PRELUDE + body)
+    assert "TP_SCOPES_OK" in out.stdout, (out.stdout[-1500:],
+                                          out.stderr[-3000:])
