@@ -320,14 +320,16 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
               pages=None, attn_extent=None, n_tok=None):
     """x: (B,S,D) -> (out, new_cache or None). cache: {"k","v"} unexpanded.
 
-    With ``pages`` (decode only) the linear K/V leaves are paged pools
-    (P, page_size, Hkv, Dh): the new token's K/V is scattered through the
-    block table and attention runs over a gathered slot-major dense view
-    — bit-identical to the unpaged cache, since every valid (masked-in)
+    With ``pages`` (decode and verify) the linear K/V leaves are the
+    stacked paged pools (n_repeats, P, page_size, Hkv, Dh) that ride the
+    layer scan's carry, and ``pages["layer"]`` is this layer's index: the
+    new token's K/V is scattered through the block table at that layer
+    and attention runs over a gathered slot-major dense view —
+    bit-identical to the unpaged cache, since every valid (masked-in)
     position gathers the very value the dense cache would hold.  With
     ``pages["kernel"]`` the gather is replaced by the fused
     :func:`repro.kernels.paged_decode_attention` Pallas kernel, which
-    reads the same pages in place (same greedy tokens, no dense copy).
+    reads the layer's pages in place (same greedy tokens, no dense copy).
     """
     b, s, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -356,13 +358,14 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
         if paged_leaf(pages, w):
             # linear logical index (ring degenerate: no wraparound), so
             # the scatter goes straight through the block table
-            table, ps = pages["table"], pages["page_size"]
-            kc = page_scatter(kc, table, ps, pos, k)
-            vc = page_scatter(vc, table, ps, pos, v)
+            table, ps, l = pages["table"], pages["page_size"], pages["layer"]
+            kc = page_scatter(kc, l, table, ps, pos, k)
+            vc = page_scatter(vc, l, table, ps, pos, v)
             if pages.get("kernel"):
                 # fused path: the Pallas kernel walks the block table
                 # in-kernel and reads pages in place — page_gather's
-                # dense slot-major copy never exists
+                # dense slot-major copy never exists.  It takes one
+                # layer's pool, so this leg slices ``kc[l]`` out
                 pv = pos if jnp.ndim(pos) == 1 else jnp.full((b,), pos)
                 axes = _tp_kernel_axes(h, hkv)
                 with jax.named_scope("attn.core"):
@@ -381,14 +384,15 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                             in_specs=(hspec, hspec, hspec, P(None, None),
                                       P(None)),
                             out_specs=hspec, check_vma=False,
-                        )(q, kc, vc, table, pv)
+                        )(q, kc[l], vc[l], table, pv)
                     else:
                         out = paged_decode_attention(
-                            q, kc, vc, table, pv, page_size=ps, window=w)
+                            q, kc[l], vc[l], table, pv, page_size=ps,
+                            window=w)
             else:
-                kd = shard(page_gather(kc, table, ps),
+                kd = shard(page_gather(kc, l, table, ps),
                            "batch", "seq_shard", "kv_heads", None)
-                vd = shard(page_gather(vc, table, ps),
+                vd = shard(page_gather(vc, l, table, ps),
                            "batch", "seq_shard", "kv_heads", None)
                 out = decode_attention(q, kd, vd, pos, window=w)
         else:
@@ -413,12 +417,12 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             k = apply_rope(k, qp, cfg.rope_theta)
         kc, vc = cache["k"], cache["v"]
         if paged_leaf(pages, spec.window):
-            table, ps = pages["table"], pages["page_size"]
-            kc = page_scatter_window(kc, table, ps, pos, k, n_tok)
-            vc = page_scatter_window(vc, table, ps, pos, v, n_tok)
-            kd = shard(page_gather(kc, table, ps),
+            table, ps, l = pages["table"], pages["page_size"], pages["layer"]
+            kc = page_scatter_window(kc, l, table, ps, pos, k, n_tok)
+            vc = page_scatter_window(vc, l, table, ps, pos, v, n_tok)
+            kd = shard(page_gather(kc, l, table, ps),
                        "batch", "seq_shard", "kv_heads", None)
-            vd = shard(page_gather(vc, table, ps),
+            vd = shard(page_gather(vc, l, table, ps),
                        "batch", "seq_shard", "kv_heads", None)
             out = verify_attention(q, kd, vd, pos)
         else:
@@ -548,13 +552,13 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
         cc, kr = cache["ckv"], cache["krope"]
         fused = paged_leaf(pages, None) and pages.get("kernel")
         if paged_leaf(pages, None):
-            table, ps = pages["table"], pages["page_size"]
-            cc = page_scatter(cc, table, ps, pos, ckv)
-            kr = page_scatter(kr, table, ps, pos, k_rope)
+            table, ps, l = pages["table"], pages["page_size"], pages["layer"]
+            cc = page_scatter(cc, l, table, ps, pos, ckv)
+            kr = page_scatter(kr, l, table, ps, pos, k_rope)
             if not fused:
-                cd = shard(page_gather(cc, table, ps),
+                cd = shard(page_gather(cc, l, table, ps),
                            "batch", "seq_shard", None)
-                kd = shard(page_gather(kr, table, ps),
+                kd = shard(page_gather(kr, l, table, ps),
                            "batch", "seq_shard", None)
         else:
             cc = _cache_update(cc, ckv, pos)
@@ -570,7 +574,8 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             if fused:
                 # fused paged path: latent pages read in place (the
                 # absorbed form's V is its K, so the kernel returns the
-                # attended latent and wv_b applies outside)
+                # attended latent and wv_b applies outside); the kernel
+                # takes one layer's pools, sliced out at ``l``
                 pv = pos if per_slot else jnp.full((b,), pos)
                 axes = _tp_kernel_axes(h)
                 if axes:
@@ -586,11 +591,11 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                         in_specs=(hspec, hspec, rep3, rep3, P(None, None),
                                   P(None)),
                         out_specs=hspec, check_vma=False,
-                    )(q_lat, q_rope, cc, kr, table, pv)
+                    )(q_lat, q_rope, cc[l], kr[l], table, pv)
                 else:
                     lat = paged_mla_decode_attention(
-                        q_lat, q_rope, cc, kr, table, pv, page_size=ps,
-                        scale=scale)
+                        q_lat, q_rope, cc[l], kr[l], table, pv,
+                        page_size=ps, scale=scale)
             else:
                 scores = (jnp.einsum("bshr,btr->bhst", q_lat, cd) +
                           jnp.einsum("bshr,btr->bhst", q_rope, kd))
@@ -617,12 +622,12 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                             cfg.rope_theta)[:, :, 0, :]
         cc, kr = cache["ckv"], cache["krope"]
         if paged_leaf(pages, None):
-            table, ps = pages["table"], pages["page_size"]
-            cc = page_scatter_window(cc, table, ps, pos, ckv, n_tok)
-            kr = page_scatter_window(kr, table, ps, pos, k_rope, n_tok)
-            cd = shard(page_gather(cc, table, ps), "batch", "seq_shard",
+            table, ps, l = pages["table"], pages["page_size"], pages["layer"]
+            cc = page_scatter_window(cc, l, table, ps, pos, ckv, n_tok)
+            kr = page_scatter_window(kr, l, table, ps, pos, k_rope, n_tok)
+            cd = shard(page_gather(cc, l, table, ps), "batch", "seq_shard",
                        None)
-            kd = shard(page_gather(kr, table, ps), "batch", "seq_shard",
+            kd = shard(page_gather(kr, l, table, ps), "batch", "seq_shard",
                        None)
         else:
             cc = _cache_update_window(cc, ckv, pos, n_tok)

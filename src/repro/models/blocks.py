@@ -45,6 +45,22 @@ def block_cache_shapes(cfg, spec, batch, seq):
     return cache_fn(cfg, spec, batch, seq)
 
 
+def paged_names(spec, cache_len: int) -> frozenset:
+    """Leaf names of this pattern spec whose cache is paged: the *linear*,
+    cache_len-long attention leaves.  Bounded leaves — true SWA rings
+    (window < cache_len), the SSM conv tail and recurrent state — are O(1)
+    or O(window) per slot and stay dense rows.  In the cache-updating
+    paged modes the layer scan carries these leaves whole and the mixer
+    indexes them by layer (:func:`repro.models.lm.forward`)."""
+    if spec.kind == "ssm":
+        return frozenset()
+    if spec.attn == "mla":
+        return frozenset(("ckv", "krope"))
+    if spec.window is not None and spec.window < cache_len:
+        return frozenset()
+    return frozenset(("k", "v"))
+
+
 def block_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
                 pages=None, attn_extent=None, n_tok=None):
     """Returns (x, new_cache, aux_loss).  ``pages`` is the paged-KV

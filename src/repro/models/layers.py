@@ -59,30 +59,46 @@ def check_cache_invariant(old, new, where: str = "block"):
 
 
 # ------------------------------------------------------------------- paging
+# A paged pool is stacked over the layer scan's repeats, (n_repeats, P,
+# page_size, ...), and rides the scan's carry (repro.models.lm.forward):
+# each layer writes its new rows into it in place at its own layer index
+# and reads its pages from it.  Nothing writes a layer's pool back into a
+# fresh stacked output or copies the stacked pool.
 @scope("attn.kv_gather")
-def page_gather(pool, table, page_size):
-    """Materialise a slot-major dense view of a paged KV pool.
+def page_gather(pool, layer, table, page_size):
+    """Materialise a slot-major dense view of layer ``layer``'s pages.
 
-    pool: (P, page_size, ...) physical pages; table: (B, pages_per_slot)
-    int32 physical page ids (page 0 is the reserved garbage page, so
-    unallocated logical pages gather zeros-or-garbage that the position
-    mask must cover).  Returns (B, pages_per_slot * page_size, ...) — the
-    same dense layout a per-slot cache row would have, so the attention
-    math downstream is untouched (and bit-identical) relative to the
-    unpaged cache.
+    pool: (n_repeats, P, page_size, ...) stacked physical pages; layer:
+    traced scalar layer index; table: (B, pages_per_slot) int32 physical
+    page ids (page 0 is the reserved garbage page, so unallocated logical
+    pages gather zeros-or-garbage that the position mask must cover).
+    Returns (B, pages_per_slot * page_size, ...) — the same dense layout a
+    per-slot cache row would have, so the attention math downstream is
+    untouched (and bit-identical) relative to the unpaged cache.
+
+    The layer's pool is sliced out, then gathered.  On a TPU v5e the
+    compiler places the slice (one layer's pool) in on-chip memory, and
+    pages gather from it several times faster than straight from the
+    stacked pool in HBM, whether indexed on the layer and page axes or
+    as rows of the merged (n_repeats * P) axis.  A pool whose last
+    dimension fills no 128-lane vector is stored with its pages on the
+    lanes; its slice is where the compiler relays it out for the gather.
 
     This materialisation is the copy the fused paged-attention kernel
     eliminates: with ``pages["kernel"]`` set, decode attention walks the
     block table inside repro.kernels.paged_attention and never calls
     this — it stays as the default A/B leg and the oracle."""
     b, pps = table.shape
-    gathered = pool[table]                     # (B, pps, page_size, ...)
-    return gathered.reshape((b, pps * page_size) + pool.shape[2:])
+    gathered = jax.lax.dynamic_index_in_dim(pool, layer, 0,
+                                            keepdims=False)[table]
+    return gathered.reshape((b, pps * page_size) + pool.shape[3:])
 
 
 @scope("attn.kv_write")
-def page_scatter(pool, table, page_size, idx, update):
-    """Write one token row per slot into the paged pool.
+def page_scatter(pool, layer, table, page_size, idx, update):
+    """Write one token row per slot into layer ``layer`` of the stacked
+    paged pool (n_repeats, P, page_size, ...) — B rows scattered at
+    ``[layer, page, offset]``, in place in the carried buffer.
 
     idx: (B,) per-slot logical positions; update: (B, 1, ...) decode-step
     values.  The logical position maps through the slot's block table to
@@ -91,14 +107,15 @@ def page_scatter(pool, table, page_size, idx, update):
     never gathered into a valid (masked-in) position."""
     page = jnp.take_along_axis(table, (idx // page_size)[:, None],
                                axis=1)[:, 0]                     # (B,)
-    return pool.at[page, idx % page_size].set(
+    return pool.at[layer, page, idx % page_size].set(
         update[:, 0].astype(pool.dtype))
 
 
 @scope("attn.kv_write")
-def page_scatter_window(pool, table, page_size, pos, update, n_tok):
-    """Write a per-slot multi-token window into the paged pool (the
-    speculative-decode verify append).
+def page_scatter_window(pool, layer, table, page_size, pos, update, n_tok):
+    """Write a per-slot multi-token window into layer ``layer`` of the
+    stacked paged pool (the speculative-decode verify append), in place
+    at ``[layer, page, offset]`` as :func:`page_scatter` does.
 
     pos: (B,) first logical position per slot; update: (B, S, ...) the
     verify window's values; n_tok: (B,) valid window lengths (0 for dead
@@ -117,7 +134,7 @@ def page_scatter_window(pool, table, page_size, pos, update, n_tok):
     lp = jnp.clip(idx // page_size, 0, table.shape[1] - 1)
     page = jnp.where(valid, jnp.take_along_axis(table, lp, axis=1), 0)
     off = jnp.where(valid, idx % page_size, 0)
-    return pool.at[page, off].set(update.astype(pool.dtype))
+    return pool.at[layer, page, off].set(update.astype(pool.dtype))
 
 
 # ----------------------------------------------------------------- positions

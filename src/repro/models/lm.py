@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..sharding import shard
-from .blocks import block_apply, block_cache_shapes, block_param_shapes
+from .blocks import (block_apply, block_cache_shapes, block_param_shapes,
+                     paged_names)
 from .layers import embed_tokens, lm_logits, rms_norm, sinusoidal_pos
 
 
@@ -150,14 +151,21 @@ def forward(params, cfg, tokens, *, mode="train", pos=0, cache=None,
     unchanged in verify mode: the engine owns acceptance, so position
     bookkeeping is host-authoritative there.
 
-    pages: optional paged-KV descriptor for decode —
+    pages: optional paged-KV descriptor for decode and verify —
     ``{"table": (B, pages_per_slot) int32, "page_size": int,
     "cache_len": int, "kernel": bool}``.  Linear attention cache leaves
-    are then paged pools (see repro.models.layers.page_gather); bounded
-    leaves (SWA rings, SSM state) stay dense per-slot rows.  With
-    ``"kernel"`` set, attention walks the block table inside the fused
-    Pallas decode kernel (repro.kernels.paged_attention) instead of
-    materialising the dense gather — same tokens, no dense K/V view.
+    (``repro.models.blocks.paged_names``) are then stacked paged pools,
+    (n_repeats, num_pages, page_size, ...); bounded leaves (SWA rings,
+    SSM state) stay dense per-slot rows.  The layer scan carries the
+    pools whole and adds ``"layer"``, the repeat's index, to the
+    descriptor each mixer sees: the mixer scatters its new rows in place
+    and gathers its pages at that index (repro.models.layers.page_gather),
+    so no layer's pool is written back into a fresh stacked output and
+    the pool is not copied.  Bounded leaves are scanned per layer as
+    ``xs`` / ``ys``.  With ``"kernel"`` set, attention walks the block table
+    inside the fused Pallas decode kernel (repro.kernels.paged_attention)
+    instead of materialising the dense gather — same tokens, no dense K/V
+    view.
 
     attn_extent (prefill_chunk only): static key extent — attention reads
     only the first ``attn_extent`` cache positions (must cover
@@ -165,13 +173,16 @@ def forward(params, cfg, tokens, *, mode="train", pos=0, cache=None,
     without it each chunk pays the full cache_len extent.  want_logits
     (prefill_chunk only): False skips the LM head for non-final chunks.
 
-    Donation contract: in the cache-updating modes ("decode",
+    Donation contract: in the cache-updating modes ("decode", "verify",
     "prefill_chunk") every cache leaf comes back with exactly its input
     shape and dtype, each input leaf feeds exactly one in-place update,
     and ``pos`` stays int32 — so the serve engine's jits can pass
     ``donate_argnums`` on the cache argument and XLA aliases the whole
     pool in place (checked per block at trace time, see
-    ``repro.models.layers.check_cache_invariant``).
+    ``repro.models.layers.check_cache_invariant``).  A paged pool is a
+    loop-carried buffer updated by in-place scatters, so the donated
+    input is the buffer every layer writes: the step holds no second
+    copy of it.
     """
     dt = jnp.dtype(cfg.dtype)
     with jax.named_scope("embed"):
@@ -202,21 +213,38 @@ def forward(params, cfg, tokens, *, mode="train", pos=0, cache=None,
     with_cache = mode != "train"
     cache_in = mode in ("decode", "prefill_chunk", "verify")
     cache_blocks = cache["blocks"] if cache is not None else None
+    # paged pools ride the carry whole and each layer indexes them at its
+    # own layer index; every other cache leaf is scanned per layer
+    carried = pages is not None and mode in ("decode", "verify")
+    if carried:
+        names = [paged_names(spec, pages["cache_len"])
+                 for spec in cfg.pattern]
+        pools = tuple({k: c for k, c in cb.items() if k in n}
+                      for cb, n in zip(cache_blocks, names))
+        cache_blocks = tuple({k: c for k, c in cb.items() if k not in n}
+                             for cb, n in zip(cache_blocks, names))
+    else:
+        pools = ()
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux, pools = carry
         bp = xs[0]
         bc = xs[1] if cache_in else (None,) * len(cfg.pattern)
-        new_cs = []
+        pg = {**pages, "layer": xs[2]} if carried else pages
+        new_cs, new_pools = [], []
         for i, spec in enumerate(cfg.pattern):
+            c = {**bc[i], **pools[i]} if carried else bc[i]
             x, nc, a = block_apply(x, bp[i], cfg, spec, mode=mode, pos=pos,
-                                   cache=bc[i], cache_len=cache_len,
-                                   pages=pages, attn_extent=attn_extent,
+                                   cache=c, cache_len=cache_len,
+                                   pages=pg, attn_extent=attn_extent,
                                    n_tok=n_tok)
+            if carried:
+                new_pools.append({k: nc[k] for k in pools[i]})
+                nc = {k: v for k, v in nc.items() if k not in pools[i]}
             new_cs.append(nc)
             aux = aux + a
         ys = tuple(new_cs) if with_cache else ()
-        return (x, aux), ys
+        return (x, aux, tuple(new_pools)), ys
 
     if cfg.remat == "full":
         body = jax.checkpoint(body,
@@ -227,8 +255,12 @@ def forward(params, cfg, tokens, *, mode="train", pos=0, cache=None,
 
     xs = (params["blocks"], cache_blocks) if cache_in \
         else (params["blocks"],)
-    (x, aux), new_blocks = jax.lax.scan(body, (x, jnp.zeros((),
-                                                            jnp.float32)), xs)
+    if carried:
+        xs = xs + (jnp.arange(cfg.n_repeats),)
+    (x, aux, pools), new_blocks = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), pools), xs)
+    if carried:
+        new_blocks = tuple({**nb, **pl} for nb, pl in zip(new_blocks, pools))
 
     new_cache = None
     if with_cache:

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .models.blocks import paged_names
 from .models.lm import cache_meta, forward, init_params, init_cache
 from .models.layers import softmax_xent
 from .optim import OptHParams, adamw_init, adamw_update
@@ -167,20 +168,6 @@ def init_slot_cache(cfg, slots: int, cache_len: int, dtype):
 
 
 # ------------------------------------------------------------ paged KV cache
-def paged_names(spec, cache_len: int) -> frozenset:
-    """Leaf names of this pattern spec whose cache is paged: the *linear*,
-    cache_len-long attention leaves.  Bounded leaves — true SWA rings
-    (window < cache_len), the SSM conv tail and recurrent state — are O(1)
-    or O(window) per slot and stay dense rows."""
-    if spec.kind == "ssm":
-        return frozenset()
-    if spec.attn == "mla":
-        return frozenset(("ckv", "krope"))
-    if spec.window is not None and spec.window < cache_len:
-        return frozenset()
-    return frozenset(("k", "v"))
-
-
 def chunkable(cfg, cache_len: int) -> bool:
     """Can prefill be chunked bit-exactly for this config?  Requires every
     block to be linear-cache attention: MoE routing capacity depends on
@@ -218,7 +205,12 @@ def init_paged_slot_cache(cfg, slots: int, cache_len: int, dtype,
     Page 0 is the reserved garbage page: dead slots and unallocated
     logical pages point there, so their scatters never corrupt a live
     slot.  Bounded leaves keep the dense per-slot layout of
-    :func:`init_slot_cache`."""
+    :func:`init_slot_cache`.
+
+    The pool stays stacked over the layer repeats: the decode and verify
+    steps carry it whole through the layer scan and index it by layer
+    in their scatters and gathers (:func:`repro.models.lm.forward`), so
+    a step neither writes a layer's pool back nor copies the pool."""
     assert cache_len % page_size == 0, (cache_len, page_size)
     assert num_pages >= 2, "need at least one usable page + garbage page 0"
     dt = jnp.dtype(dtype)

@@ -784,3 +784,115 @@ def test_prefix_gather_plus_tail_chunk_matches_cold_prefill(arch, m_tokens,
     got = np.concatenate(outs, axis=1)[0]
     assert np.array_equal(got, ref[0]), (
         f"warm stream diverged (m_tokens={m_tokens})")
+
+
+# ------------------------------------------- paged pools ride the layer scan
+def _pool_shapes(cache):
+    """HLO shape strings of the stacked paged pool leaves, and their
+    bytes."""
+    leaves = [l for blk in cache["blocks"] for n, l in blk.items()
+              if n in ("k", "v", "ckv", "krope")]
+    stacked = {",".join(map(str, l.shape)) for l in leaves}
+    return stacked, sum(l.size * l.dtype.itemsize for l in leaves)
+
+
+@pytest.mark.parametrize("arch", ["minicpm3-4b", "qwen2.5-14b"])
+@pytest.mark.parametrize("step", ["decode", "verify"])
+def test_decode_step_does_not_copy_the_pool(arch, step):
+    """The donated paged decode and verify steps update the stacked pool
+    in place: the layer scan carries it whole and each layer scatters and
+    gathers at its own index, so the compiled step holds no copy, slice
+    or write-back of the stacked pool, and its temporaries stay below
+    the pool's bytes."""
+    import re
+
+    from repro.models.lm import meta_shape_structs, param_meta
+    from repro.steps import make_verify_step
+
+    cfg = get(arch).tiny(n_layers=8)
+    slots, cache_len, ps, pages = 16, 256, 8, 513
+    dt = jnp.dtype(cfg.dtype)
+    params = meta_shape_structs(param_meta(cfg), dt)
+    cache = jax.eval_shape(lambda: init_paged_slot_cache(
+        cfg, slots, cache_len, dt, ps, pages))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    table = i32(slots, cache_len // ps)
+    if step == "decode":
+        fn = make_decode_step(cfg, cache_len=cache_len, page_size=ps)
+        args = (params, cache, i32(slots, 1),
+                jax.ShapeDtypeStruct((slots,), bool), table)
+    else:
+        fn = make_verify_step(cfg, cache_len=cache_len, page_size=ps)
+        args = (params, cache, i32(slots, 3), i32(slots), i32(slots), table)
+    comp = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    stacked, pool_bytes = _pool_shapes(cache)
+    bad = []
+    for ln in comp.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     ln)
+        if m and m.group(1) in stacked and m.group(2) in (
+                "copy", "dynamic-slice", "dynamic-update-slice"):
+            bad.append(ln.strip()[:120])
+    assert not bad, bad
+    temp = comp.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes, (temp, pool_bytes)
+
+
+@pytest.mark.parametrize("arch", ["minicpm3-4b", "qwen2.5-14b",
+                                  "jamba-v0.1-52b"])
+def test_paged_rows_land_at_their_layer(arch):
+    """Three pattern repeats, scrambled page tables: two paged decode
+    ticks leave every layer's pool holding exactly the rows the dense
+    slot cache holds at that layer (and the hybrid's scanned SSM leaves
+    equal the dense ones), with distinct content per layer — a row
+    written at the wrong layer index, or read from one, would show."""
+    cfg = get(arch).tiny(n_layers=3 * len(get(arch).pattern))
+    cache_len, ps = PLEN + GEN, PAGE_SIZE
+    pps = cache_len // ps
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (SLOTS, PLEN), 0,
+                                 cfg.vocab)
+    rows, logits = jax.jit(make_prefill_step(cfg, cache_len=cache_len))(
+        params, prompts, None)
+    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    dt = jnp.dtype(cfg.dtype)
+    num_pages = SLOTS * pps + 1
+    table = np.random.default_rng(3).permutation(
+        np.arange(1, num_pages)).reshape(SLOTS, pps).astype(np.int32)
+    dense = init_slot_cache(cfg, SLOTS, cache_len, dt)
+    paged = init_paged_slot_cache(cfg, SLOTS, cache_len, dt, ps, num_pages)
+    ins_d = jax.jit(make_batched_insert_step(cfg, cache_len=cache_len))
+    ins_p = jax.jit(make_batched_insert_step(cfg, cache_len=cache_len,
+                                             page_size=ps))
+    for s in range(SLOTS):
+        dense = ins_d(dense, rows, jnp.int32(s), jnp.int32(s))
+        paged = ins_p(paged, rows, jnp.int32(s), jnp.int32(s),
+                      jnp.array(table[s]))
+    dec_d = jax.jit(make_decode_step(cfg))
+    dec_p = jax.jit(make_decode_step(cfg, cache_len=cache_len,
+                                     page_size=ps))
+    active = jnp.ones((SLOTS,), bool)
+    td = tp = toks
+    for _ in range(2):
+        td, dense = dec_d(params, dense, td, active)
+        tp, paged = dec_p(params, paged, tp, active, jnp.array(table))
+        assert np.array_equal(np.asarray(td), np.asarray(tp))
+    assert np.array_equal(np.asarray(dense["pos"]), np.asarray(paged["pos"]))
+    written = PLEN + 2
+    n_paged = 0
+    for spec, bd, bp in zip(cfg.pattern, dense["blocks"], paged["blocks"]):
+        for name, d in bd.items():
+            d, p = np.asarray(d), np.asarray(bp[name])
+            if p.shape == d.shape:               # scanned per layer
+                assert np.array_equal(p, d), name
+                continue
+            n_paged += 1
+            view = p[:, table].reshape(d.shape)  # (rep, slots, len, ...)
+            assert np.array_equal(view[:, :, :written], d[:, :, :written]), (
+                spec, name)
+            assert not np.array_equal(d[0, :, :written], d[1, :, :written])
+            assert not np.array_equal(d[1, :, :written], d[2, :, :written])
+    assert n_paged >= 2
