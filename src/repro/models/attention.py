@@ -8,7 +8,8 @@ MLA absorption, output projection), ``attn.kv_write`` (cache writes),
 
 Three execution paths, chosen by the step being lowered:
   * ``full``    — materialised scores, train-time (seq <= ~4k).
-  * ``qchunk``  — lax.scan over query chunks, forward-only prefill (32k).
+  * ``prefill`` — forward-only one-shot prefill: the tiled flash kernel on
+    a TPU, a lax.scan over query chunks elsewhere (``prefill_attention``).
   * ``decode``  — single query token against a (sequence-sharded) cache.
 
 KV caches are stored unexpanded (n_kv heads); GQA expands K/V to the query
@@ -25,7 +26,8 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from ..kernels import paged_decode_attention, paged_mla_decode_attention
+from ..kernels import (flash_attention, paged_decode_attention,
+                       paged_mla_decode_attention)
 from ..sharding import axis_size, current_mesh, resolved_axes, shard
 from .layers import (apply_rope, page_gather, page_scatter,
                      page_scatter_window, rms_norm, scope)
@@ -34,10 +36,11 @@ NEG_INF = -1e30
 
 
 def _tp_kernel_axes(*head_counts: int) -> tuple[str, ...] | None:
-    """Mesh axes for the per-shard paged-kernel dispatch, or ``None`` for
-    the single-shard call.  The kernel is head-parallel, so a tensor-
-    parallel pool (heads on the mesh, sequence replicated — TP_SERVE_RULES)
-    dispatches one kernel per model shard via ``shard_map``; the legacy
+    """Mesh axes for the per-shard kernel dispatch (paged decode, flash
+    prefill), or ``None`` for the single-shard call.  The kernels are
+    head-parallel, so a tensor-parallel layout (heads on the mesh,
+    sequence replicated — TP_SERVE_RULES) dispatches one kernel per
+    model shard via ``shard_map``; the legacy
     decode layout (seq_shard on the mesh) and non-dividing head counts
     (the constraint would drop or pad the axis) keep the plain call."""
     mesh = current_mesh()
@@ -166,6 +169,39 @@ def qchunk_attention(q, k, v, *, window=None, chunk=512, scale=None):
     _, outs = jax.lax.scan(body, 0, qs)
     dv = v.shape[-1]
     out = outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, dv)
+    return shard(out, "batch", "seq", "heads", "head_dim")
+
+
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def prefill_attention(q, k, v, *, window=None, scale=None):
+    """One-shot causal (+window) prefill attention: q (B,S,H,Dqk), k
+    (B,S,Hkv,Dqk), v (B,S,Hkv,Dv) -> (B,S,H,Dv).
+
+    On a TPU (the backend :func:`_backend` observes) the tiled flash
+    kernel runs it: scores stay in VMEM, and key blocks above the
+    diagonal are neither fetched nor multiplied.  Elsewhere
+    :func:`qchunk_attention` runs it, the formulation the CPU
+    bit-identity tests pin.  A ``pallas_call`` has no partitioning rule,
+    so under a tensor-parallel mesh (heads on it) one kernel runs per
+    head shard through ``shard_map``."""
+    if _backend() != "tpu":
+        return qchunk_attention(q, k, v, window=window, scale=scale)
+    fn = functools.partial(flash_attention, causal=True, window=window,
+                           scale=scale)
+    axes = _tp_kernel_axes(q.shape[2], k.shape[2])
+    with jax.named_scope("attn.core"):
+        if axes:
+            # heads are kv-head-major contiguous, so an even head split
+            # keeps every query head on the shard holding its kv head
+            hspec = P(None, None, axes, None)
+            out = jax.shard_map(fn, mesh=current_mesh(),
+                                in_specs=(hspec, hspec, hspec),
+                                out_specs=hspec, check_vma=False)(q, k, v)
+        else:
+            out = fn(q, k, v)
     return shard(out, "batch", "seq", "heads", "head_dim")
 
 
@@ -463,7 +499,7 @@ def gqa_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
             out = full_attention(q, kc[:, :ext], vc[:, :ext], q_off=pos)
             new_cache = {"k": kc, "v": vc}
         elif mode == "prefill":
-            out = qchunk_attention(q, k, v, window=spec.window)
+            out = prefill_attention(q, k, v, window=spec.window)
             w = spec.window
             if w is not None:
                 # the ring only needs min(window, cache_len) slots: with
@@ -704,7 +740,7 @@ def mla_apply(x, p, cfg, spec, *, mode, pos, cache=None, cache_len=None,
         q = shard(q, "batch", "seq", "heads", "head_dim")
         k = shard(k, "batch", "seq", "heads", "head_dim")
         if mode == "prefill":
-            out = qchunk_attention(q, k, vfull, scale=scale)
+            out = prefill_attention(q, k, vfull, scale=scale)
             new_cache = {
                 "ckv": shard(_pad_seq(ckv, cache_len),
                              "batch", "seq_shard", None),

@@ -77,6 +77,86 @@ def test_flash_attention_block_shape_invariance():
         _close(o, outs[0], jnp.float32)
 
 
+# Prefill shapes: (b, s, h, hkv, d, dv, window, blocks, heads per step);
+# blocks None -> ops.block_sizes chooses (one block at these lengths)
+PREFILL_CASES = {
+    "mla-40h": (1, 192, 40, 40, 96, 64, None, 64, 8),
+    "mla-40h-chosen-ragged": (1, 200, 40, 40, 96, 64, None, None, None),
+    "gqa-heads-in-group": (2, 256, 8, 2, 64, 64, None, 64, 2),
+    "gqa-groups-in-step": (1, 256, 8, 2, 64, 64, None, 64, 8),
+    "gqa-window": (1, 256, 4, 2, 32, 32, 40, 64, 2),
+    "ragged": (1, 100, 4, 4, 96, 64, None, 32, 4),
+    "ragged-window-gqa": (1, 130, 6, 2, 64, 32, 50, 32, 3),
+}
+
+
+def _prefill_inputs(case, dtype):
+    b, s, h, hkv, d, dv, window, blocks, heads = PREFILL_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (b, s, h, d), dtype)
+    k = jax.random.normal(ks[1], (b, s, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (b, s, hkv, dv), dtype)
+    return q, k, v, dict(window=window, block_q=blocks, block_k=blocks,
+                         heads=heads)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(PREFILL_CASES))
+def test_flash_attention_prefill_shapes(case, dtype):
+    """Separate value width (MLA: q/k 96, v 64), GQA with heads per step
+    inside one group or spanning groups, sliding windows and lengths off
+    the block (zero-padded and masked): the kernel matches the oracle and
+    the XLA prefill path it replaces on the TPU."""
+    from repro.models.attention import qchunk_attention
+
+    q, k, v, kw = _prefill_inputs(case, dtype)
+    out = flash_attention(q, k, v, causal=True, interpret=True, **kw)
+    assert out.shape == q.shape[:3] + v.shape[-1:]
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    _close(out, flash_attention_ref(q, k, v, causal=True,
+                                    window=kw["window"]), dtype)
+    _close(out, qchunk_attention(q, k, v, window=kw["window"]), dtype)
+
+
+@pytest.mark.parametrize("case", ["gqa-window", "ragged-window-gqa",
+                                  "mla-40h"])
+def test_flash_attention_clamped_kv_index_map(case):
+    """Clamping the K/V index map to the blocks the mask reaches changes
+    which block is resident during skipped steps, never the result."""
+    q, k, v, kw = _prefill_inputs(case, jnp.float32)
+    clamped = flash_attention(q, k, v, causal=True, interpret=True, **kw)
+    plain = flash_attention(q, k, v, causal=True, clamp_kv=False,
+                            interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(clamped), np.asarray(plain))
+
+
+@pytest.mark.parametrize("causal,sq,sk", [(False, 50, 100), (True, 128, 100)])
+def test_flash_attention_padded_keys_are_masked(causal, sq, sk):
+    """Keys zero-padded to a whole block are masked wherever a real query
+    row could reach them: without a causal mask, or when there are more
+    queries than keys."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, sq, 2, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, 2, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, 2, 64), jnp.float32)
+    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
+                          interpret=True)
+    _close(out, flash_attention_ref(q, k, v, causal=causal), jnp.float32)
+
+
+def test_flash_block_sizes_follow_the_shape():
+    from repro.kernels.flash_attention.ops import block_sizes
+
+    assert block_sizes(5120, 40, 1) == (512, 8)       # minicpm3-4b prefill
+    assert block_sizes(37, 40, 1) == (128, 20)        # short chat prompt
+    assert block_sizes(5120, 40, 5) == (512, 5)       # qwen2.5-14b, group 5
+    assert block_sizes(5120, 10, 5) == (512, 5)       # its 4-way shard
+    for seq, h, g in ((300, 6, 3), (9000, 7, 7), (1, 1, 1)):
+        blk, hb = block_sizes(seq, h, g)
+        assert blk % 128 == 0 and h % hb == 0
+        assert g % hb == 0 or hb % g == 0
+
+
 # ------------------------------------------------------------------ paged
 def _paged_table(b, cache_len, ps, pos, garbage_rest=True):
     """Per-slot block table covering each slot's ``pos``; every entry

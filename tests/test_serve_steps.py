@@ -2,6 +2,8 @@
 masked decode must reproduce the one-shot path's greedy tokens for plain
 token LMs, ``vision_patches`` and ``audio_codebooks`` configs, and the
 SWA/MoE and MLA attention families."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -896,3 +898,45 @@ def test_paged_rows_land_at_their_layer(arch):
             assert not np.array_equal(d[0, :, :written], d[1, :, :written])
             assert not np.array_equal(d[1, :, :written], d[2, :, :written])
     assert n_paged >= 2
+
+
+# ------------------------------------------ one-shot prefill on the TPU
+def _prefill_branch_cfg(name):
+    """Tiny configs for the two prefill-attention branches: MLA with
+    value heads narrower than its query/key heads, GQA with two query
+    heads per kv head, and the same GQA under a sliding window shorter
+    than the prompt."""
+    if name == "mla":
+        return get("minicpm3-4b").tiny(v_head_dim=8)
+    cfg = get("qwen2.5-14b").tiny(n_kv_heads=2)
+    if name == "gqa-window":
+        cfg = cfg.replace(pattern=(dataclasses.replace(cfg.pattern[0],
+                                                       window=16),))
+    return cfg
+
+
+@pytest.mark.parametrize("name", ["mla", "gqa", "gqa-window"])
+def test_prefill_kernel_branch_matches_xla_branch(name, monkeypatch):
+    """On a TPU one-shot prefill attention runs in the flash kernel, and
+    elsewhere in ``qchunk_attention``.  Taken here in interpret mode, the
+    kernel branch gives the XLA branch's logits and caches at a prompt
+    length off the kernel's block (zero-padded and masked)."""
+    from repro.models import attention
+
+    cfg = _prefill_branch_cfg(name)
+    cache_len, plen = 48, 37
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (2, plen), 0,
+                                 cfg.vocab)
+    want_cache, want = jax.jit(make_prefill_step(
+        cfg, cache_len=cache_len))(params, prompts, None)
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
+    step = make_prefill_step(cfg, cache_len=cache_len)
+    assert "pallas_call" in str(jax.make_jaxpr(step)(params, prompts, None))
+    got_cache, got = jax.jit(step)(params, prompts, None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    for g, w in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)

@@ -218,3 +218,42 @@ print("TP_SCOPES_OK")
     out = _run(_PRELUDE + body)
     assert "TP_SCOPES_OK" in out.stdout, (out.stdout[-1500:],
                                           out.stderr[-3000:])
+
+
+@pytest.mark.slow
+def test_tp_prefill_kernel_branch_matches_xla_branch():
+    """Tensor-parallel one-shot prefill on the TPU branch (interpret mode
+    here): a ``pallas_call`` has no partitioning rule, so one flash
+    kernel runs per head shard through ``shard_map``.  Its logits and
+    caches match the single-device XLA branch, for GQA with two query
+    heads per kv head on every shard and for MLA."""
+    body = r"""
+from repro.models import attention
+from repro.steps import make_prefill_step
+
+mesh = make_mesh((1, 4), ("data", "model"))
+for arch, kw in (("qwen2.5-14b", dict(n_heads=8, n_kv_heads=4)),
+                 ("minicpm3-4b", dict(v_head_dim=8))):
+    cfg = get(arch).tiny(**kw)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 37), 0,
+                                 cfg.vocab)
+    want_cache, want = jax.jit(make_prefill_step(cfg, cache_len=48))(
+        params, prompts, None)
+    attention._backend = lambda: "tpu"
+    step = make_prefill_step(cfg, mesh, cache_len=48, tp=True)
+    jaxpr = str(jax.make_jaxpr(step)(params, prompts, None))
+    assert "shard_map" in jaxpr and "pallas_call" in jaxpr, arch
+    got_cache, got = jax.jit(step)(params, prompts, None)
+    attention._backend = jax.default_backend
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    for g, w in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+    print("PREFILL_OK", arch)
+print("TP_PREFILL_OK")
+"""
+    out = _run(_PRELUDE + body)
+    assert "TP_PREFILL_OK" in out.stdout, (out.stdout[-1500:],
+                                           out.stderr[-3000:])

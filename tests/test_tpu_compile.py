@@ -7,7 +7,9 @@ in interpret mode on the CPU, cannot catch it.  These compile the paged
 decode kernels at the widths the chip serves: GQA at qwen2.5-14b's
 (8 kv heads, group 5, head_dim 128) and at one tensor-parallel shard's
 (2 kv heads), and absorbed MLA at minicpm3-4b's (40 heads, latent 256,
-rope 32) — bf16 pools, page_size 16.
+rope 32) — bf16 pools, page_size 16.  The prefill flash kernel compiles
+at minicpm3-4b's prefill (40 heads, q/k 96, v 64, 5,120 tokens), at a
+ragged chat length, and at one qwen2.5-14b tensor-parallel shard's.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.paged_attention import ops
 
 SLOTS, PAGE, PAGES_PER_SLOT = 8, 16, 32
@@ -83,4 +86,23 @@ def test_paged_kernel_compiles_for_v5e(name, one_chip):
                jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)]
     compiled = jax.jit(
         lambda *a: fn(*a, interpret=False, **static)).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (seq, heads, kv heads, q/k width, v width, window)
+FLASH = {
+    "mla-minicpm3-4b-5120": (5120, 40, 40, 96, 64, None),
+    "mla-minicpm3-4b-ragged": (1000, 40, 40, 96, 64, None),
+    "gqa-qwen2.5-14b-tp-shard": (2048, 10, 2, 128, 128, None),
+    "gqa-window": (3000, 32, 8, 128, 128, 1024),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLASH))
+def test_flash_kernel_compiles_for_v5e(name, one_chip):
+    s, h, hkv, d, dv, window = FLASH[name]
+    shapes = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+              for shape in ((1, s, h, d), (1, s, hkv, d), (1, s, hkv, dv))]
+    compiled = jax.jit(lambda q, k, v: flash_ops.flash_attention(
+        q, k, v, window=window, interpret=False)).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
