@@ -1,6 +1,6 @@
 """Serving load benchmark: Poisson arrivals against the continuous-batching
-engine (paged KV + batched/chunked prefill; umt on/off; dense legacy) and
-the static one-shot batch path.
+engine (paged KV + batched/chunked prefill; umt on/off) and the static
+one-shot batch path.
 
 Requests arrive with exponential inter-arrival gaps at a configurable
 offered load (req/s) and identical prompts/generation budgets; every mode
@@ -19,24 +19,19 @@ Modes:
     monitored block, a blocked core is backfilled (the paper's point, at
     the serving layer);
   * engine_base  — same engine, baseline runtime (blocked = idle core);
-  * engine_dense — UMT engine with the seed's dense per-slot cache
-    (page_size=None): the paging A/B;
   * oneshot      — static batching: collect up to `slots` queued requests,
     prefill the batch, decode it to completion, repeat (pre-engine path).
 
-Beyond the load sweep, three targeted phases (ISSUE 3/4 acceptance):
+Beyond the load sweep, targeted phases:
 
-  * equal-memory slot capacity — at the dense layout's KV byte budget,
-    the paged engine must sustain strictly more concurrent slots (short
-    requests reserve only the pages they can touch, not cache_len);
+  * equal-memory slot capacity — at a one-full-cache-per-slot KV byte
+    budget, the paged engine must sustain strictly more concurrent slots
+    (short requests reserve only the pages they can touch, not
+    cache_len);
   * chunked-prefill tick jitter — on a long+short prompt mix, chunked
     prefill (bounded cache-append calls, one continuation task per
     chunk) must cut the p99 decode-tick interval vs unchunked
     (sync_ticks=True so intervals measure real compute cadence);
-  * buffer-donation A/B — dense and paged, at >= 2 loads, donation on
-    vs off: tokens/s and p50/p99 tick per leg, identical greedy tokens,
-    and a direct aliasing probe asserting the donated decode reuses the
-    cache buffers in place (the per-tick full-pool copy is gone);
   * scheduler-policy A/B (ISSUE 5) — on-demand paging + preemption-by-
     eviction vs worst-case reservation at equal KV memory: strictly more
     live slots (hard-asserted), then an eviction storm on a budget two
@@ -67,7 +62,7 @@ Beyond the load sweep, three targeted phases (ISSUE 3/4 acceptance):
     ``TP_SERVE,...`` PASS line; auto-skipped on one visible device
     (CI forces 4 host devices via XLA_FLAGS).
 
-``--phases load,donation,kernel,equal_mem,policy,prefix,spec,tp,jitter``
+``--phases load,kernel,equal_mem,policy,prefix,spec,tp,jitter``
 selects a subset (default all; ``--skip-phases`` = load only).
 
   python -m benchmarks.serve [--loads 32,256] [--requests 32] [--slots 4]
@@ -282,11 +277,12 @@ def run_oneshot(cfg, params, prefill, serve_step, prompts, gaps, *, gens,
 def bench_equal_memory_slots(cfg, params, prefill, serve_step, *, slots,
                              cache_len, page_size, prompt_len, gen, cores,
                              n_req) -> ServeResult:
-    """At the dense layout's KV token budget (slots * cache_len), run the
-    paged engine with a doubled slot pool and short requests: because
-    each request reserves only ceil((prompt+gen-1)/page_size) pages
-    instead of a full cache_len row, strictly more slots fit — the seed's
-    dense cache cannot exceed ``slots`` concurrent requests at this
+    """At a KV token budget of one full cache per slot (slots *
+    cache_len), run the paged engine with a doubled slot pool and short
+    requests: because each request reserves only
+    ceil((prompt+gen-1)/page_size) pages instead of a full cache_len
+    row, strictly more slots fit — one page per slot (``page_size ==
+    cache_len``) cannot exceed ``slots`` concurrent requests at this
     memory no matter what arrives."""
     prompts, patches = _prompts(cfg, n_req, prompt_len, seed=5)
     prompts = np.asarray(prompts)
@@ -295,7 +291,7 @@ def bench_equal_memory_slots(cfg, params, prefill, serve_step, *, slots,
     ref = np.asarray(greedy_oneshot(
         prefill, serve_step, params, jnp.asarray(prompts),
         None if patches is None else jnp.asarray(patches), gen))
-    budget_pages = slots * cache_len // page_size      # dense-equivalent
+    budget_pages = slots * cache_len // page_size   # a full cache per slot
     steps = make_jit_steps(cfg, cache_len=cache_len, page_size=page_size)
     res, toks = run_engine(
         cfg, params, steps, prompts, np.zeros(n_req), gens=gens,
@@ -308,9 +304,9 @@ def bench_equal_memory_slots(cfg, params, prefill, serve_step, *, slots,
     ok = res.max_live > slots
     print(res.row(), flush=True)
     print(f"  -> equal KV memory ({budget_pages} pages x {page_size} tok "
-          f"= dense {slots} slots): paged sustained max_live="
+          f"= {slots} full-cache slots): paged sustained max_live="
           f"{res.max_live} slots — "
-          f"{'PASS (strictly more than dense)' if ok else 'FAIL'}",
+          f"{'PASS (strictly more)' if ok else 'FAIL'}",
           flush=True)
     return res
 
@@ -420,133 +416,6 @@ def bench_chunked_tick_jitter(cfg, params, *, prompt_len, long_factor, gen,
     print(f"  -> long-prompt burst p99 tick (median of {repeats}): "
           f"unchunked {meds[None]:.1f}ms vs chunked "
           f"{meds[chunk_size]:.1f}ms — {verdict}", flush=True)
-    return out
-
-
-def _donation_alias_probe(cfg, params, steps, *, slots, cache_len):
-    """Direct proof the per-tick full-pool copy is gone: run one donated
-    decode and assert the biggest cache leaf comes back in the *same*
-    device buffer (XLA input/output aliasing).  Deterministic — asserted
-    hard, unlike the timing-noise throughput lines."""
-    from repro.steps import init_paged_slot_cache, init_slot_cache
-
-    dt = jnp.dtype(cfg.dtype)
-    paged = steps["page_size"] is not None
-    if paged:
-        pps = cache_len // steps["page_size"]
-        cache = init_paged_slot_cache(cfg, slots, cache_len, dt,
-                                      steps["page_size"], slots * pps + 1)
-        table = jnp.zeros((slots, pps), jnp.int32)
-    else:
-        cache = init_slot_cache(cfg, slots, cache_len, dt)
-    extra = ((cfg.n_codebooks,) if cfg.frontend == "audio_codebooks"
-             else ())
-    toks = jnp.zeros((slots, 1) + extra, jnp.int32)
-    active = jnp.ones((slots,), bool)
-    leaves = jax.tree.leaves(cache)
-    nbytes = [x.nbytes for x in leaves]
-    ptrs = [x.unsafe_buffer_pointer() for x in leaves]
-    args = (params, cache, toks, active) + ((table,) if paged else ())
-    _, out = steps["decode"](*args)
-    out_ptrs = {x.unsafe_buffer_pointer() for x in jax.tree.leaves(out)}
-    aliased = sum(1 for p in ptrs if p in out_ptrs)
-    big_ok = ptrs[int(np.argmax(nbytes))] in out_ptrs
-    layout = "paged" if paged else "dense"
-    print(f"  donation probe [{layout}]: {aliased}/{len(leaves)} cache "
-          f"leaves aliased in place, biggest leaf reused: {big_ok} -> "
-          f"per-tick full-pool copy "
-          f"{'ELIMINATED' if big_ok else 'STILL PRESENT'}", flush=True)
-    assert big_ok, "donated decode did not alias the big cache leaf"
-
-
-def bench_donation_ab(cfg, params, prompts, patches, gens, *, loads, slots,
-                      cache_len, page_size, cores, seed, repeats=3,
-                      steps_on=None) -> list[ServeResult]:
-    """ISSUE 4 acceptance phase: single-owner KV state with buffer
-    donation, A/B'd against the copying legacy path.
-
-    For dense and paged layouts at >= 2 offered loads, the same arrival
-    trace runs with donation on and off (``sync_ticks=True`` so tick
-    quantiles measure real compute cadence); legs are interleaved
-    ``repeats`` times and medians reported (this container schedules
-    40-100 ms stalls onto bare jit loops).  Greedy tokens must be
-    identical across all legs; donation-on must be no slower
-    (informational PASS/FAIL on shared runners); the aliasing probe
-    above is the hard, deterministic check that the copy is gone."""
-    loads = list(loads) if len(loads) >= 2 else \
-        list(loads) + [4 * loads[-1]]
-    legs = {}
-    steps_on = steps_on or {}
-    for layout, ps in (("paged", page_size), ("dense", None)):
-        for donate in (True, False):
-            # donate=True dicts are the load sweep's own steps when the
-            # caller passes them (steps are meant to compile once per
-            # process); only the donate=False legs are new compiles
-            st = steps_on.get(layout) if donate else None
-            if st is None:
-                st = make_jit_steps(cfg, cache_len=cache_len,
-                                    page_size=ps, donate=donate)
-                warm_engine_shapes(cfg, params, st, prompts, patches,
-                                   slots=slots, cache_len=cache_len,
-                                   cores=cores)
-            legs[(layout, donate)] = st
-        _donation_alias_probe(cfg, params, legs[(layout, True)],
-                              slots=slots, cache_len=cache_len)
-
-    out = []
-    for load in loads:
-        gaps = np.random.default_rng(seed).exponential(
-            1.0 / load, len(prompts))
-        runs = {k: [] for k in legs}
-        for _ in range(repeats):
-            for key, st in legs.items():      # interleaved A/B
-                layout, donate = key
-                res, toks = run_engine(
-                    cfg, params, st, prompts, gaps, gens=gens,
-                    slots=slots, cache_len=cache_len, umt=True,
-                    cores=cores, patches=patches,
-                    name=f"serve_donate_{'on' if donate else 'off'}"
-                         f"_{layout}",
-                    page_size=st["page_size"], sync_ticks=True)
-                res.load = load
-                runs[key].append((res, toks))
-        ref = runs[("paged", True)][-1][1]
-        for key, rs in runs.items():
-            for _, toks in rs:
-                for i, (a, b) in enumerate(zip(ref, toks)):
-                    assert np.array_equal(a, b), (
-                        f"donation A/B token mismatch: {key} @ load "
-                        f"{load}, request {i}")
-        def _med(vals):
-            xs = sorted(v for v in vals if v is not None)
-            return xs[len(xs) // 2] if xs else float("nan")
-
-        for layout in ("paged", "dense"):
-            med = {}
-            for donate in (True, False):
-                rs = [r for r, _ in runs[(layout, donate)]]
-                # per-metric medians across the interleaved repeats: one
-                # stalled run must not leak its latency/occupancy into a
-                # row whose tokens_s is a median — every noisy field of
-                # the reported row is the median of its own samples
-                r = rs[-1]
-                r.tokens_s = _med(x.tokens_s for x in rs)
-                r.wall_s = _med(x.wall_s for x in rs)
-                r.occupancy = _med(x.occupancy for x in rs)
-                r.p50_s = _med(x.p50_s for x in rs)
-                r.p99_s = _med(x.p99_s for x in rs)
-                r.p99_tick_ms = _med(x.p99_tick_ms for x in rs)
-                med[donate] = r
-                out.append(r)
-                print(r.row(), flush=True)
-            ratio = med[True].tokens_s / med[False].tokens_s
-            ok = ratio >= 0.95
-            print(f"  -> donation A/B [{layout}] load={load:g} (median "
-                  f"of {repeats}): on/off tokens_s = {ratio:.2f}x, p99 "
-                  f"tick {med[True].p99_tick_ms:.1f} vs "
-                  f"{med[False].p99_tick_ms:.1f} ms — "
-                  f"{'PASS (donation-on no slower)' if ok else 'FAIL'}",
-                  flush=True)
     return out
 
 
@@ -772,7 +641,7 @@ def bench_prefix_reuse(cfg, params, *, slots, prompt_len, gen, cores,
     warm_engine_shapes(cfg, params, steps, prompts, None, slots=slots,
                        cache_len=cache_len, cores=cores,
                        prefill_chunk=chunk)
-    # equal KV memory on both legs: the dense-equivalent pool plus one
+    # equal KV memory on both legs: a full cache per slot plus one
     # slot-equivalent of headroom so trie capital (the warm request's
     # pages idling at refcount 0) never fights live slots for pages
     pps = cache_len // ps
@@ -971,7 +840,7 @@ def bench_spec_decode(cfg, params, serve_step, *, slots, page_size,
     return out
 
 
-PHASES = ("load", "donation", "kernel", "equal_mem", "policy", "prefix",
+PHASES = ("load", "kernel", "equal_mem", "policy", "prefix",
           "spec", "tp", "jitter")
 
 
@@ -1133,7 +1002,6 @@ def main(argv=None) -> list[ServeResult]:
     cache_len = _cache_len(cfg, args.prompt_len, args.gen)
     page_size = args.page_size or auto_page_size(cache_len)
     steps = make_jit_steps(cfg, cache_len=cache_len, page_size=page_size)
-    steps_dense = make_jit_steps(cfg, cache_len=cache_len, page_size=None)
     prefill = steps["prefill"]
     serve_step = jax.jit(make_serve_step(cfg))
     # frontend-correct shapes (audio codebook dim, vision patches)
@@ -1147,18 +1015,17 @@ def main(argv=None) -> list[ServeResult]:
 
     # warm every shape (oneshot batch prefill + serve step, and — via
     # throwaway engine legs — the engine's bucketed batched prefills,
-    # paged/dense insert + masked decode and the small eager ops) so no
-    # timed leg pays XLA compile — only when a timed phase will run
-    # (capacity-asserted phases like tp warm themselves or don't care)
-    if phases & {"load", "donation", "kernel", "equal_mem", "policy"}:
+    # insert + masked decode and the small eager ops) so no timed leg
+    # pays XLA compile — only when a timed phase will run (capacity-
+    # asserted phases like tp warm themselves or don't care)
+    if phases & {"load", "kernel", "equal_mem", "policy"}:
         wp = None if patches is None else jnp.asarray(patches[:args.slots])
         cache, logits = prefill(params, jnp.asarray(prompts[:args.slots]),
                                 wp)
         serve_step(params, cache, jnp.argmax(logits, -1).astype(jnp.int32))
-        for st in (steps, steps_dense):
-            warm_engine_shapes(cfg, params, st, prompts, patches,
-                               slots=args.slots, cache_len=cache_len,
-                               cores=args.cores)
+        warm_engine_shapes(cfg, params, steps, prompts, patches,
+                           slots=args.slots, cache_len=cache_len,
+                           cores=args.cores)
 
     results: list[ServeResult] = []
     burst_ratio = None
@@ -1169,9 +1036,7 @@ def main(argv=None) -> list[ServeResult]:
         legs = [("serve_engine_umt", dict(umt=True, steps=steps,
                                           page_size=page_size)),
                 ("serve_engine_base", dict(umt=False, steps=steps,
-                                           page_size=page_size)),
-                ("serve_engine_dense", dict(umt=True, steps=steps_dense,
-                                            page_size=None))]
+                                           page_size=page_size))]
         for name, kw in legs:
             res, toks = run_engine(
                 cfg, params, kw["steps"], prompts, gaps, gens=gens,
@@ -1199,13 +1064,11 @@ def main(argv=None) -> list[ServeResult]:
                     f"@ load {load}, request {i}")
         eng, base = runs["serve_engine_umt"][0], runs["serve_oneshot"][0]
         ub = runs["serve_engine_base"][0]
-        dn = runs["serve_engine_dense"][0]
         burst_ratio = eng.tokens_s / base.tokens_s
         print(f"  -> load={load:g}: engine/oneshot tokens_s = "
               f"{burst_ratio:.2f}x, "
               f"p99 {eng.p99_s * 1e3:.0f}ms vs {base.p99_s * 1e3:.0f}ms; "
-              f"umt/base = {eng.tokens_s / ub.tokens_s:.2f}x; "
-              f"paged/dense = {eng.tokens_s / dn.tokens_s:.2f}x",
+              f"umt/base = {eng.tokens_s / ub.tokens_s:.2f}x",
               flush=True)
     if burst_ratio is not None:
         ok = burst_ratio >= 1 / 1.2
@@ -1213,16 +1076,6 @@ def main(argv=None) -> list[ServeResult]:
               f"{burst_ratio:.2f}x of one-shot tokens/s — "
               f"{'PASS (within 1.2x)' if ok else 'FAIL (worse than 1.2x)'}",
               flush=True)
-
-    if "donation" in phases:
-        # phase: donation A/B — the memcpy win of single-owner KV state
-        # (dense and paged, >= 2 loads, aliasing probe asserted)
-        results.extend(bench_donation_ab(
-            cfg, params, prompts, patches, gens, loads=loads,
-            slots=args.slots, cache_len=cache_len, page_size=page_size,
-            cores=args.cores, seed=args.seed,
-            repeats=1 if args.smoke else 3,
-            steps_on={"paged": steps, "dense": steps_dense}))
 
     if "kernel" in phases:
         # phase: fused paged-attention kernel A/B — in-kernel block-table

@@ -18,15 +18,15 @@ The full engine flag surface (``python -m repro.launch.serve``) — every
 knob is a layout/scheduling change, never a tokens change (greedy output
 is bit-identical across all of them, fuzz-tested):
 
-  * ``--page-size N`` / ``--pages N`` — paged KV cache (0 = auto size,
-    < 0 = the dense per-slot layout).  Paging is the substrate for the
-    three flags below; on dense they are rejected or inert.
+  * ``--page-size N`` / ``--pages N`` — paged KV cache geometry (0 =
+    auto size; ``N`` = the cache length gives each slot one page).
+    Paging is the substrate for the three flags below.
   * ``--policy reserve|ondemand`` — worst-case page reservation at
-    admission vs on-demand growth with preemption-by-eviction (paged
-    only; ``ondemand`` admits more but may evict + bit-exactly restore).
+    admission vs on-demand growth with preemption-by-eviction
+    (``ondemand`` admits more but may evict + bit-exactly restore).
   * ``--prefix-cache auto|on|off`` — radix-trie reuse of shared prompt
-    prefixes over refcounted pages (paged + chunk-exact configs; new
-    requests link cached pages and prefill only their tail).
+    prefixes over refcounted pages (chunk-exact configs; new requests
+    link cached pages and prefill only their tail).
   * ``--paged-kernel`` — decode attention via the fused Pallas kernel
     that walks the block table in-kernel (paged GQA/MLA only; off-TPU it
     runs interpret-mode, a correctness harness not a speed claim).
@@ -42,9 +42,8 @@ is bit-identical across all of them, fuzz-tested):
     stats line reports ``"tp": true``.  Off-accelerator, force devices
     with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
   * ``--chunk N`` — Sarathi-style chunked prefill (bounds decode-tick
-    jitter under long prompts); ``--no-donate`` — copying legacy cache
-    path (A/B leg); ``--no-umt`` — baseline runtime where a blocked
-    core idles (the paper's A/B).
+    jitter under long prompts); ``--no-umt`` — baseline runtime where a
+    blocked core idles (the paper's A/B).
 """
 import argparse
 

@@ -126,16 +126,13 @@ def serve_engine(cfg, params, mesh, args):
     prompts, patches = _prompts(cfg, args.requests, args.prompt_len)
     prompts = np.asarray(prompts)
 
-    page_size = ("auto" if args.page_size == 0
-                 else None if args.page_size < 0 else args.page_size)
     t0 = time.time()
     with ServeEngine(cfg, params, slots=args.batch, cache_len=cache_len,
                      mesh=mesh, umt=not args.no_umt, n_cores=args.cores,
-                     page_size=page_size,
+                     page_size=args.page_size or "auto",
                      num_pages=args.pages if args.pages > 0 else None,
                      prefill_chunk=args.chunk if args.chunk > 0
                      else None,
-                     donate=not args.no_donate,
                      paged_kernel=args.paged_kernel,
                      policy=args.policy,
                      prefix_cache=args.prefix_cache,
@@ -165,7 +162,6 @@ def serve_engine(cfg, params, mesh, args):
         "umt": not args.no_umt,
         "page_size": stats["page_size"],
         "tp": stats["tp"],
-        "donate": stats["donate"],
         "paged_kernel": stats["paged_kernel"],
         "policy": stats["policy"],
         "kv_versions": stats["kv_version"],
@@ -220,11 +216,11 @@ def serve(argv=None):
     ap.add_argument("--cores", type=int, default=None,
                     help="engine: runtime core count")
     ap.add_argument("--page-size", type=int, default=0,
-                    help="engine: KV page size (0 = auto, <0 = dense "
-                         "per-slot cache, no paging)")
+                    help="engine: KV page size, a divisor of the cache "
+                         "length (0 = auto)")
     ap.add_argument("--pages", type=int, default=0,
                     help="engine: KV page-pool size incl. garbage page "
-                         "(0 = dense-equivalent capacity)")
+                         "(0 = one full cache per slot)")
     ap.add_argument("--chunk", type=int, default=0,
                     help="engine: chunked prefill — prompts longer than "
                          "this prefill as cache-append chunks (0 = off)")
@@ -232,15 +228,11 @@ def serve(argv=None):
                     help="engine: decode attention through the fused "
                          "paged-attention Pallas kernel (reads KV pages "
                          "in place; default is the gather+dense leg)")
-    ap.add_argument("--no-donate", action="store_true",
-                    help="engine: disable buffer donation on the "
-                         "decode/insert/chunk cache argument (the "
-                         "copying legacy path, kept for A/B)")
     ap.add_argument("--policy", choices=("reserve", "ondemand"),
                     default="reserve",
                     help="engine: scheduler policy — worst-case page "
                          "reservation at admission, or on-demand paging "
-                         "with preemption-by-eviction (paged only)")
+                         "with preemption-by-eviction")
     ap.add_argument("--spec", choices=("off", "ngram"), default="off",
                     help="engine: speculative decoding — draft k tokens "
                          "per slot (n-gram prompt lookup, no second "
@@ -262,10 +254,12 @@ def serve(argv=None):
                     default="auto",
                     help="engine: shared-prefix KV reuse (radix cache "
                          "over refcounted pages).  auto enables it on "
-                         "paged + chunk-exact configs; off is the A/B "
+                         "chunk-exact configs; off is the A/B "
                          "leg; on fails loudly if the config cannot be "
                          "bit-exact")
     args = ap.parse_args(argv)
+    if args.page_size < 0:
+        ap.error("--page-size must be 0 (auto) or a positive page size")
     if args.requests <= 0:
         args.requests = args.batch
 

@@ -38,17 +38,16 @@ serve path for any arrival order and slot schedule (tested).
 Single-owner KV state & buffer donation
 ---------------------------------------
 The cache pytree has exactly one owner — :class:`repro.serve.kvstate.
-KVState`, held by the engine — and the decode/insert/chunk jits *donate*
-it (``donate_argnums`` on the cache argument, the default): XLA aliases
-every cache leaf in place, so a decode tick updates the KV pool without
-materialising a full copy (previously the dominant hot-path memcpy).
-Every rebind of the live version goes through ``KVState.commit``, whose
-versioned pinning keeps any buffer a dispatched-but-pending computation
-still reads alive (this backend can recycle such buffers — see
+KVState`, held by the engine — and every cache-updating jit (decode,
+verify, insert, chunk) *donates* it (``donate_argnums`` on the cache
+argument): XLA aliases every cache leaf in place, so a decode tick
+updates the KV pool without materialising a copy.  Every rebind of the
+live version goes through ``KVState.commit``.  Versioned pinning keeps
+any other buffer a dispatched-but-pending computation still reads alive
+(this backend can recycle such buffers — see
 ``examples/repro_buffer_lifetime.py``) and is exclusive with donation: a
 donated version is consumed by the computation that produced its
-successor and is never pinned.  ``donate=False`` keeps the copying
-legacy path as the benchmark A/B leg.
+successor and is never pinned.
 
 Mechanism/policy split
 ----------------------
@@ -67,16 +66,18 @@ of ``page_size`` token slots allocated from a free list
 request finishes (including early ``eos_id``/``stop`` stops), addressed
 through per-slot block tables.  KV memory is bounded by live tokens
 rather than ``slots * cache_len``, so at equal memory the pool runs
-strictly more concurrent slots than the dense layout
-(``page_size=None``, kept for A/B benchmarks).  The default policy
-reserves the worst case at admission, which *blocks* on pool exhaustion,
-deadlock-free; ``policy="ondemand"`` reserves only the prefill extent
-and grows a slot's block table as decode crosses page boundaries — at
-equal memory it sustains strictly more live slots, and exhaustion
-mid-decode is unblocked by preemption: the policy's victim is evicted
-and restored later by replaying prefill over prompt + generated tokens
-(recompute-on-restore, bit-exact — tested).  Page reuse across slots can
-never corrupt: dead slots' tables point at the reserved garbage page 0.
+strictly more concurrent slots than one page per slot
+(``page_size == cache_len``) would.  Bounded leaves (SWA rings, SSM
+conv/state) keep one row per slot inside the same cache.  The default
+policy reserves the worst case at admission, which *blocks* on pool
+exhaustion, deadlock-free; ``policy="ondemand"`` reserves only the
+prefill extent and grows a slot's block table as decode crosses page
+boundaries — at equal memory it sustains strictly more live slots, and
+exhaustion mid-decode is unblocked by preemption: the policy's victim is
+evicted and restored later by replaying prefill over prompt + generated
+tokens (recompute-on-restore, bit-exact — tested).  Page reuse across
+slots can never corrupt: dead slots' tables point at the reserved
+garbage page 0.
 
 Shared-prefix KV reuse (radix prefix cache)
 -------------------------------------------

@@ -30,16 +30,14 @@ stays bit-identical to the never-evicted run (tested across the fuzz
 grid).
 
 The cache pytree has a **single owner** — :class:`repro.serve.kvstate.
-KVState` — and the decode/insert/chunk jits **donate** it
-(``donate_argnums`` on the cache argument, the default): XLA aliases
-every cache leaf in place, so a decode tick or an insert no longer
-materialises a full copy of the KV pool (the dominant hot-path memcpy
-before this).  ``donate=False`` keeps the copying legacy path as the
-benchmark A/B leg (``benchmarks/serve.py`` measures both).  Rebinding
-the live version goes through :meth:`KVState.commit`, whose versioned
-pinning replaces the old ad-hoc ``_retain`` list — and is exclusive
-with donation: a donated version is consumed by the computation that
-produced its successor, so it is never pinned (asserted, tested).
+KVState` — and every cache-updating jit (decode, verify, insert, chunk)
+**donates** it (``donate_argnums`` on the cache argument): XLA aliases
+every cache leaf in place, so a decode tick or an insert never
+materialises a copy of the KV pool.  Rebinding the live version goes
+through :meth:`KVState.commit`; the versioned pinning of everything
+else a pending dispatch reads is exclusive with donation: a donated
+version is consumed by the computation that produced its successor, so
+it is never pinned (asserted, tested).
 
 Prefill is **batched** and **chunked**:
 
@@ -82,11 +80,10 @@ import numpy as np
 from ..core import UMTRuntime, io
 from ..sharding import logical_sharding
 from ..steps import (TP_SERVE_RULES, chunkable, init_cache,
-                     init_paged_slot_cache, init_slot_cache,
-                     make_batched_insert_step, make_decode_step,
-                     make_prefill_chunk_step, make_prefill_step,
-                     make_prefix_gather_step, make_serve_step,
-                     make_verify_step, speculatable)
+                     init_paged_slot_cache, make_batched_insert_step,
+                     make_decode_step, make_prefill_chunk_step,
+                     make_prefill_step, make_prefix_gather_step,
+                     make_serve_step, make_verify_step, speculatable)
 from .kvstate import KVState, alias_safe, cache_tree_shardings
 from .pager import GARBAGE_PAGE
 from .policy import SchedulerPolicy, SlotView, make_policy
@@ -115,9 +112,9 @@ def auto_page_size(cache_len: int, cap: int = 8) -> int:
                if cache_len % d == 0)
 
 
-def _tp_shardings(cfg, mesh, cache_len: int, page_size: int | None):
+def _tp_shardings(cfg, mesh, cache_len: int, page_size: int):
     """Output-sharding trees for the tensor-parallel serve jits: the
-    slot pool / paged pool (``pool``), a prefill row cache (``row``) and
+    paged slot pool (``pool``), a prefill row cache (``row``) and
     a fully-replicated leaf (``rep`` — tokens and logits, which the
     model-axis all-reduce already materialises on every device).
 
@@ -130,13 +127,8 @@ def _tp_shardings(cfg, mesh, cache_len: int, page_size: int | None):
     only the head/conv dims (taken from ``cfg``) decide the strict
     per-leaf resolution."""
     dt = jnp.dtype(cfg.dtype)
-    if page_size is None:
-        pool = jax.eval_shape(
-            lambda: init_slot_cache(cfg, 1, cache_len, dt))
-    else:
-        pool = jax.eval_shape(
-            lambda: init_paged_slot_cache(cfg, 1, cache_len, dt,
-                                          page_size, 2))
+    pool = jax.eval_shape(
+        lambda: init_paged_slot_cache(cfg, 1, cache_len, dt, page_size, 2))
     rows = jax.eval_shape(lambda: init_cache(cfg, 1, cache_len, dt))
     return {"rep": NamedSharding(mesh, P()),
             "pool": cache_tree_shardings(pool, mesh),
@@ -156,22 +148,20 @@ def param_shardings(cfg, mesh):
 
 
 def make_jit_steps(cfg, mesh=None, cache_len: int = 64, *,
-                   page_size: int | None = None, chunk: bool | None = None,
-                   donate: bool = True, paged_kernel: bool = False,
-                   tp: bool = False):
+                   page_size: int | str = "auto", chunk: bool | None = None,
+                   paged_kernel: bool = False, tp: bool = False):
     """The engine's jitted steps, built once — pass as ``jit_steps`` to
     several ``ServeEngine`` instances (benchmark A/B legs) so XLA compiles
     each step a single time per process.  Returns a dict carrying the
     cache geometry it was built for (the engine cross-checks it).
-    ``page_size=None`` builds the dense (pre-paging) steps.
+    ``page_size="auto"`` (default) is :func:`auto_page_size` of
+    ``cache_len``, the engine's own rule.
 
-    ``donate=True`` (default) puts ``donate_argnums`` on each step's
-    cache argument: insert arg 0 (the pool — never the shared prefill
-    rows), decode arg 1, chunk arg 1.  The producing computation then
-    aliases every cache leaf in place (alias safety is asserted per leaf
-    by the first engine built on the dict), eliminating the per-tick
-    full-pool copy.  ``donate=False`` keeps the copying legacy path as
-    the benchmark A/B leg.
+    Every cache-updating step donates its cache argument: insert arg 0
+    (the pool — never the shared prefill rows), decode and verify arg 1,
+    chunk arg 1.  The producing computation then aliases every cache
+    leaf in place (alias safety is asserted per leaf by the first engine
+    built on the dict), so no step copies the pool.
 
     ``chunk=None`` (default) builds the chunked-prefill step whenever the
     config can chunk bit-exactly (``repro.steps.chunkable``) — jit is
@@ -193,9 +183,8 @@ def make_jit_steps(cfg, mesh=None, cache_len: int = 64, *,
     per-leaf shardings ``KVState`` commits its pool with — so donation
     keeps aliasing every sharded leaf in place, now per shard.  Greedy
     tokens stay bit-identical to the single-device engine (tested)."""
-    if paged_kernel and page_size is None:
-        raise ValueError("paged_kernel=True needs a paged cache "
-                         "(page_size set)")
+    if page_size == "auto":
+        page_size = auto_page_size(cache_len)
     if tp and mesh is None:
         raise ValueError("tp=True needs a (data, model) mesh")
     if chunk is None:
@@ -212,15 +201,14 @@ def make_jit_steps(cfg, mesh=None, cache_len: int = 64, *,
 
     ins = _jit(make_batched_insert_step(
         cfg, mesh, cache_len=cache_len, page_size=page_size, tp=tp),
-        pool_sh, donate_argnums=(0,) if donate else ())
+        pool_sh, donate_argnums=(0,))
     dec = _jit(make_decode_step(
         cfg, mesh, cache_len=cache_len, page_size=page_size,
         paged_kernel=paged_kernel, tp=tp),
-        (rep, pool_sh), donate_argnums=(1,) if donate else ())
+        (rep, pool_sh), donate_argnums=(1,))
     return {
         "cache_len": cache_len,
         "page_size": page_size,
-        "donate": donate,
         "paged_kernel": paged_kernel,
         "tp": tp,
         "prefill": _jit(make_prefill_step(cfg, mesh, cache_len=cache_len,
@@ -234,7 +222,7 @@ def make_jit_steps(cfg, mesh=None, cache_len: int = 64, *,
         "chunk": (_jit(make_prefill_chunk_step(cfg, mesh, cache_len,
                                                tp=tp),
                        (row_sh, rep),
-                       donate_argnums=(1,) if donate else (),
+                       donate_argnums=(1,),
                        static_argnames=("attn_extent", "want_logits"))
                   if chunk else None),
         # prefix-cache hit path (pure read of the pool — never donated):
@@ -242,15 +230,13 @@ def make_jit_steps(cfg, mesh=None, cache_len: int = 64, *,
         # cache that seeds the tail chunk prefill
         "gather": (_jit(make_prefix_gather_step(
             cfg, mesh, cache_len=cache_len, page_size=page_size, tp=tp),
-            row_sh)
-            if page_size is not None and chunkable(cfg, cache_len)
-            else None),
+            row_sh) if chunkable(cfg, cache_len) else None),
         # speculative-decode verify (draft-and-verify multi-token decode,
         # see ServeEngine ``spec=``) — jit is lazy, so an unused verify
         # step costs nothing; None where the config cannot be bit-exact
         "verify": (_jit(make_verify_step(
             cfg, mesh, cache_len=cache_len, page_size=page_size, tp=tp),
-            (rep, pool_sh), donate_argnums=(1,) if donate else ())
+            (rep, pool_sh), donate_argnums=(1,))
             if speculatable(cfg, cache_len) else None),
     }
 
@@ -270,13 +256,14 @@ class ServeEngine:
     cache_len : int
         Logical per-slot cache length; every request needs
         ``prompt_len (+ n_patches) + max_new_tokens <= cache_len``.
-    page_size : int | "auto" | None
-        KV page size.  "auto" (default) picks the largest divisor of
-        ``cache_len`` <= 8; ``None`` keeps the dense per-slot reservation
-        (the pre-paging layout, kept for A/B benchmarks).
+    page_size : int | "auto"
+        KV page size: a positive divisor of ``cache_len``, or "auto"
+        (default), the largest divisor of ``cache_len`` <= 8
+        (:func:`auto_page_size`).  ``page_size == cache_len`` gives each
+        slot one page.
     num_pages : int, optional
         Physical pages including the reserved garbage page 0.  Default is
-        dense-equivalent capacity: ``slots * cache_len / page_size + 1``.
+        one full cache per slot: ``slots * cache_len / page_size + 1``.
         A smaller pool admits fewer concurrent requests (admission blocks
         on the free list); a larger one admits more ``slots`` at the same
         per-request footprint.
@@ -285,17 +272,12 @@ class ServeEngine:
         chunks of this many tokens, one continuation task per chunk.
         Requires a chunk-exact config (``repro.steps.chunkable``) —
         raises ``ValueError`` otherwise.
-    donate : bool, optional
-        Buffer donation on the decode/insert/chunk cache argument
-        (default True): the cache is updated in place instead of copied
-        per tick.  Must match ``jit_steps`` when both are given;
-        ``donate=False`` is the measured A/B leg.
     paged_kernel : bool, optional
         Decode attention reads KV pages in place through the fused
         paged-attention Pallas kernel (the per-tick dense ``page_gather``
         copy never materialises).  Default False keeps the gather+dense
-        leg — the A/B baseline and bit-exactness oracle.  Requires a
-        paged engine; must match ``jit_steps`` when both are given.
+        leg — the A/B baseline and bit-exactness oracle.  Must match
+        ``jit_steps`` when both are given.
     tp : bool | None, optional
         Tensor-parallel serving: shard the decode/prefill/verify jits
         over ``mesh``'s model axis — every cache leaf with a head dim is
@@ -313,21 +295,21 @@ class ServeEngine:
     policy : SchedulerPolicy | str | None, optional
         The decision layer (see :mod:`repro.serve.policy`): None/"reserve"
         keeps worst-case page reservation at admission; "ondemand" turns
-        on on-demand paging with preemption-by-eviction (paged engines
-        only).  Any ``SchedulerPolicy`` instance plugs in custom
-        decisions without touching the mechanism here.
+        on on-demand paging with preemption-by-eviction.  Any
+        ``SchedulerPolicy`` instance plugs in custom decisions without
+        touching the mechanism here.
     prefix_cache : bool | "on" | "off" | "auto" | None, optional
         Shared-prefix KV reuse (SGLang-style radix cache over the
         refcounted page pool): admission matches a prompt's longest
         cached prefix, points the slot's block table at the shared
         pages, and prefills only the uncached tail (copy-on-write fork
         at the divergence page).  Default ``None``/"auto" turns it on
-        exactly when it can be bit-exact — a paged engine on a
-        chunk-exact config (``repro.steps.chunkable``); non-qualifying
-        configs (dense cache, MoE, SSM/SSD, short SWA rings) bypass it
-        transparently.  "on" raises on a non-qualifying engine; "off"
-        disables it (the benchmark A/B leg).  Requests with ``patches``
-        never match (the trie keys on token ids alone).
+        exactly when it can be bit-exact — a chunk-exact config
+        (``repro.steps.chunkable``); non-qualifying configs (MoE,
+        SSM/SSD, short SWA rings) bypass it transparently.  "on" raises
+        on a non-qualifying engine; "off" disables it (the benchmark A/B
+        leg).  Requests with ``patches`` never match (the trie keys on
+        token ids alone).
     spec : str | None, optional
         Speculative decoding (draft-and-verify multi-token decode).
         ``None``/"off" keeps tick-by-tick decode (the A/B leg); "ngram"
@@ -365,11 +347,11 @@ class ServeEngine:
                  mesh=None, rt: UMTRuntime | None = None, umt: bool = True,
                  n_cores: int | None = None, response_sink=None,
                  idle_wait: float = 0.05, jit_steps=None,
-                 page_size: int | str | None = "auto",
+                 page_size: int | str = "auto",
                  num_pages: int | None = None,
                  prefill_chunk: int | None = None,
                  max_prefill_batch: int | None = None,
-                 sync_ticks: bool = False, donate: bool | None = None,
+                 sync_ticks: bool = False,
                  paged_kernel: bool | None = None,
                  tp: bool | None = None, policy=None,
                  prefix_cache: bool | str | None = None,
@@ -377,6 +359,15 @@ class ServeEngine:
         self.cfg = cfg
         self.slots = slots
         self.cache_len = cache_len
+        if page_size == "auto":
+            page_size = (jit_steps["page_size"] if jit_steps is not None
+                         else auto_page_size(cache_len))
+        if not isinstance(page_size, (int, np.integer)) or page_size < 1 \
+                or cache_len % page_size:
+            raise ValueError(
+                f"page_size={page_size!r}: pick 'auto' or a positive "
+                f"divisor of cache_len={cache_len}")
+        self.page_size = int(page_size)
         self.mesh = mesh
         self.response_sink = response_sink
         self.idle_wait = idle_wait
@@ -398,15 +389,8 @@ class ServeEngine:
         if jit_steps is not None:
             assert jit_steps["cache_len"] == cache_len, (
                 "jit_steps were built for a different cache_len")
-            if page_size == "auto":
-                page_size = jit_steps["page_size"]
             assert jit_steps["page_size"] == page_size, (
                 "jit_steps were built for a different page_size")
-            steps_donate = jit_steps.get("donate", False)
-            assert donate is None or donate == steps_donate, (
-                "jit_steps were built for donate="
-                f"{steps_donate}, engine asked for donate={donate}")
-            donate = steps_donate
             steps_pk = jit_steps.get("paged_kernel", False)
             assert paged_kernel is None or paged_kernel == steps_pk, (
                 "jit_steps were built for paged_kernel="
@@ -417,15 +401,7 @@ class ServeEngine:
                 f"jit_steps were built for tp={steps_tp}, "
                 f"engine asked for tp={tp}")
             tp = steps_tp
-        elif page_size == "auto":
-            page_size = auto_page_size(cache_len)
-        self.page_size: int | None = page_size
-        self.paged = page_size is not None
-        self.donate = True if donate is None else donate
         self.paged_kernel = bool(paged_kernel)
-        if self.paged_kernel and not self.paged:
-            raise ValueError("paged_kernel=True needs a paged engine "
-                             "(page_size is None here)")
         # tensor-parallel serving auto-enables exactly when the mesh has
         # a model axis to shard over; a 1x1 host mesh (or no mesh) keeps
         # the single-device layout bit-for-bit
@@ -447,10 +423,6 @@ class ServeEngine:
         self._tp_serial = self.tp and jax.default_backend() == "cpu"
         self._dev_lock = threading.Lock()
         self.policy = make_policy(policy)
-        if self.policy.on_demand and not self.paged:
-            raise ValueError(
-                f"policy {self.policy.name!r} is on-demand paging — it "
-                "needs a paged engine (page_size is None here)")
         # hot-path guard: only build per-tick SlotView snapshots for
         # policies that actually override the unforced-preemption hook
         self._policy_may_evict = (type(self.policy).maybe_evict
@@ -469,13 +441,12 @@ class ServeEngine:
         self.queue = RequestQueue()
         if jit_steps is None:
             jit_steps = make_jit_steps(cfg, mesh, cache_len,
-                                       page_size=page_size,
-                                       donate=self.donate,
+                                       page_size=self.page_size,
                                        paged_kernel=self.paged_kernel,
                                        tp=self.tp)
         # fallback jits (an external jit_steps dict may omit entries)
         # must build with the same tp/out_shardings as make_jit_steps's
-        tp_sh = (_tp_shardings(cfg, mesh, cache_len, page_size)
+        tp_sh = (_tp_shardings(cfg, mesh, cache_len, self.page_size)
                  if self.tp else None)
         rep_sh = tp_sh["rep"] if tp_sh else None
         pool_sh = tp_sh["pool"] if tp_sh else None
@@ -501,8 +472,7 @@ class ServeEngine:
         if self._restore_prefill and self.chunk is None:
             self.chunk = _fb_jit(
                 make_prefill_chunk_step(cfg, mesh, cache_len, tp=self.tp),
-                (row_sh, rep_sh),
-                donate_argnums=(1,) if self.donate else (),
+                (row_sh, rep_sh), donate_argnums=(1,),
                 static_argnames=("attn_extent", "want_logits"))
         # speculative decoding: spec mode resolves to a drafter (a policy
         # decision) + the verify jit; both shapes (S=1 and S=spec_k+1)
@@ -522,9 +492,8 @@ class ServeEngine:
             if self.verify is None:
                 self.verify = _fb_jit(
                     make_verify_step(cfg, mesh, cache_len=cache_len,
-                                     page_size=page_size, tp=self.tp),
-                    (rep_sh, pool_sh),
-                    donate_argnums=(1,) if self.donate else ())
+                                     page_size=self.page_size, tp=self.tp),
+                    (rep_sh, pool_sh), donate_argnums=(1,))
             self.drafter = self.policy.spec_drafter(self, self.spec_mode)
         # chunk width for prefill-replay restores when the engine has no
         # steady-state prefill_chunk of its own: chunk-step shapes are
@@ -537,14 +506,14 @@ class ServeEngine:
             1 << ((cache_len - 1).bit_length() // 2))
 
         # shared-prefix KV reuse: qualifies exactly when the hit path can
-        # be bit-exact — paged pool (shareable pages) + chunk-exact
-        # prefill (the tail chunks reproduce the cold logits)
-        can_prefix = self.paged and self._restore_prefill
+        # be bit-exact — chunk-exact prefill (the tail chunks reproduce
+        # the cold logits)
+        can_prefix = self._restore_prefill
         if prefix_cache in (True, "on"):
             if not can_prefix:
                 raise ValueError(
-                    f"{cfg.name}: prefix_cache='on' needs a paged engine "
-                    "on a chunk-exact config (repro.steps.chunkable) — "
+                    f"{cfg.name}: prefix_cache='on' needs a chunk-exact "
+                    "config (repro.steps.chunkable) — "
                     "the hit path gathers shared pages into a row cache "
                     "and chunk-prefills the tail bit-exactly")
             self._use_prefix = True
@@ -565,10 +534,11 @@ class ServeEngine:
             self._params_ready.set()
 
         dt = jnp.dtype(cfg.dtype)
-        # single owner of the cache pytree (and, paged, of the block
-        # tables + page free-list): every rebind goes through kv.commit,
-        # every buffer a pending dispatch may read is pinned in kv
-        self.kv = KVState(cfg, slots, cache_len, dt, page_size=page_size,
+        # single owner of the cache pytree, the block tables and the page
+        # free-list: every rebind goes through kv.commit, every buffer a
+        # pending dispatch may read is pinned in kv
+        self.kv = KVState(cfg, slots, cache_len, dt,
+                          page_size=self.page_size,
                           num_pages=num_pages, mesh=mesh, tp=self.tp)
         self.pager = self.kv.pager
         self.pages_per_slot = self.kv.pages_per_slot
@@ -577,13 +547,14 @@ class ServeEngine:
         # donating dispatch of the same version (decode tick, insert) —
         # FIFO device execution then guarantees the gather reads the
         # buffers before the donating computation recycles them
-        self.prefix = (PrefixCache(self.pager, page_size)
+        self.prefix = (PrefixCache(self.pager, self.page_size)
                        if self._use_prefix else None)
         self.gather = None
         if self.prefix is not None:
             self.gather = jit_steps.get("gather") or _fb_jit(
                 make_prefix_gather_step(cfg, mesh, cache_len=cache_len,
-                                        page_size=page_size, tp=self.tp),
+                                        page_size=self.page_size,
+                                        tp=self.tp),
                 row_sh)
         self._pool_lock = threading.Lock()
         extra = ((cfg.n_codebooks,) if cfg.frontend == "audio_codebooks"
@@ -663,7 +634,7 @@ class ServeEngine:
         # no compile): every cache leaf must come out of each donating
         # step with its input shape/dtype, or XLA could not alias the
         # donated buffer and would silently keep the full-pool copy
-        if self.donate and not jit_steps.get("_alias_ok"):
+        if not jit_steps.get("_alias_ok"):
             self._assert_alias_safe()
             jit_steps["_alias_ok"] = True
 
@@ -675,23 +646,14 @@ class ServeEngine:
                                 jnp.dtype(cfg.param_dtype))
         kv, i32 = self.kv, jnp.int32
         scalar = jax.ShapeDtypeStruct((), i32)
-        if self.paged:
-            _, out_c = jax.eval_shape(self.decode, ps, kv.cache,
-                                      self._tokens, self._active_dev,
-                                      kv.table_dev)
-        else:
-            _, out_c = jax.eval_shape(self.decode, ps, kv.cache,
-                                      self._tokens, self._active_dev)
+        _, out_c = jax.eval_shape(self.decode, ps, kv.cache, self._tokens,
+                                  self._active_dev, kv.table_dev)
         alias_safe(kv.cache, out_c, "decode")
         rows = meta_shape_structs(cache_meta(cfg, 1, self.cache_len),
                                   jnp.dtype(cfg.dtype))
-        if self.paged:
-            trow = jax.ShapeDtypeStruct((self.pages_per_slot,), i32)
-            out_c = jax.eval_shape(self.insert, kv.cache, rows, scalar,
-                                   scalar, trow)
-        else:
-            out_c = jax.eval_shape(self.insert, kv.cache, rows, scalar,
-                                   scalar)
+        trow = jax.ShapeDtypeStruct((self.pages_per_slot,), i32)
+        out_c = jax.eval_shape(self.insert, kv.cache, rows, scalar, scalar,
+                               trow)
         alias_safe(kv.cache, out_c, "insert")
         if self.chunk is not None:
             tok = jax.ShapeDtypeStruct(
@@ -824,13 +786,12 @@ class ServeEngine:
                 f"request {req.rid}: prompt {req.total_len} + "
                 f"{ticks + 1} tokens to go exceeds cache_len "
                 f"{self.cache_len}")
-        if self.paged:
-            need = self.pager.pages_for(req.total_len + ticks)
-            if need > self.pager.capacity:
-                return ValueError(
-                    f"request {req.rid}: needs {need} KV pages but the "
-                    f"pool only has {self.pager.capacity} — it can never "
-                    "be admitted")
+        need = self.pager.pages_for(req.total_len + ticks)
+        if need > self.pager.capacity:
+            return ValueError(
+                f"request {req.rid}: needs {need} KV pages but the "
+                f"pool only has {self.pager.capacity} — it can never "
+                "be admitted")
         if req.needs_host_tokens and \
                 self.cfg.frontend == "audio_codebooks":
             return ValueError(
@@ -1104,8 +1065,8 @@ class ServeEngine:
             # exactly like one long prefill — the bounded gap (plus the
             # task boundary, a scheduling point like any other) is where
             # decode ticks interleave.  ``old_rows`` stays referenced
-            # until this sync, so the chunk chain (donated or copied)
-            # never drops a version a pending dispatch still reads.
+            # until this sync, so the chunk chain never drops a version
+            # a pending dispatch still reads.
             with self._span("prefill.sync", rids):
                 jax.block_until_ready(rows_cache["pos"])
             del old_rows, chunk_toks, off_dev
@@ -1330,7 +1291,7 @@ class ServeEngine:
         """Admit prefilled rows into free slots, strictly head-first
         (restores re-enter at the head, everything else FIFO — keeps a
         large request from being starved by smaller ones slipping past
-        it).  Paged: the head reserves the pages the *policy* asks for —
+        it).  The head reserves the pages the *policy* asks for —
         the worst case (default) or just the prefill extent (on-demand).
         If the pool cannot cover them, admission *blocks* (the row stays
         queued; nothing is written) until a free — completion or
@@ -1352,45 +1313,42 @@ class ServeEngine:
         """Admit the queued row ``entry`` into one of the ``free`` slots;
         False when its admission blocks on free pages."""
         req, rows_cache, row, t0 = entry
-        ids = None
         shared: list[int] = []
-        if self.paged:
-            # a prefix hit already holds its matched pages — only
-            # the uncached remainder is allocated (never zero: at
-            # least the last prompt position was recomputed)
-            if req.prefix_hold is not None:
-                shared = list(req.prefix_hold.pages)
+        # a prefix hit already holds its matched pages — only the
+        # uncached remainder is allocated (never zero: at least the last
+        # prompt position was recomputed)
+        if req.prefix_hold is not None:
+            shared = list(req.prefix_hold.pages)
+        need = self.pager.pages_for(
+            self.policy.admission_tokens(self, req)) - len(shared)
+        ids = self._alloc_pages(need)
+        if ids is None and shared:
+            # a blocked head must hold nothing — the deadlock-freedom
+            # argument (a lone live slot can always grow; every eviction
+            # strictly frees) breaks if blocked admissions pin pages.
+            # Dropping the hold is always sound: the gathered row cache
+            # holds the complete prefix content, so the admission falls
+            # back to cold-shaped (all pages fresh) — the prefill compute
+            # stayed saved, only page dedup is lost.  The dropped pages
+            # revert to refcount-0 cached: this very retry's reclaim may
+            # harvest them.
+            self._drop_prefix_hold(req)
+            shared = []
             need = self.pager.pages_for(
-                self.policy.admission_tokens(self, req)) - len(shared)
+                self.policy.admission_tokens(self, req))
             ids = self._alloc_pages(need)
-            if ids is None and shared:
-                # a blocked head must hold nothing — the
-                # deadlock-freedom argument (a lone live slot can
-                # always grow; every eviction strictly frees) breaks
-                # if blocked admissions pin pages.  Dropping the
-                # hold is always sound: the gathered row cache holds
-                # the complete prefix content, so the admission
-                # falls back to cold-shaped (all pages fresh) — the
-                # prefill compute stayed saved, only page dedup is
-                # lost.  The dropped pages revert to refcount-0
-                # cached: this very retry's reclaim may harvest them.
-                self._drop_prefix_hold(req)
-                shared = []
-                need = self.pager.pages_for(
-                    self.policy.admission_tokens(self, req))
-                ids = self._alloc_pages(need)
-            if ids is None and not self._active.any() \
-                    and self._drop_queued_holds():
-                # nothing live will ever free a page, so what the
-                # head lacks was held by rows queued behind it, which
-                # only their own admission would release: they fall
-                # back to cold-shaped too (sound as above) — retry
-                return True
-            if ids is None:         # admission blocked on free pages
-                if self._blocked_head != req.rid:
-                    self._blocked_head = req.rid
-                    self.stats_admission_blocks += 1
-                return False
+        if ids is None and not self._active.any() \
+                and self._drop_queued_holds():
+            # nothing live will ever free a page, so what the head lacks
+            # was held by rows queued behind it, which only their own
+            # admission would release: they fall back to cold-shaped too
+            # (sound as above) — retry
+            return True
+        if ids is None:             # admission blocked on free pages
+            if self._blocked_head != req.rid:
+                self._blocked_head = req.rid
+                self.stats_admission_blocks += 1
+            return False
         self._blocked_head = None
         with self._lock:
             # dequeue this row, not the head: a prefill worker may
@@ -1407,30 +1365,20 @@ class ServeEngine:
         # dispatch temporaries the pending insert reads whose Python
         # refs drop when this admission returns: pin until a sync
         kv.pin(rows_cache, t0, row_dev, slot_dev)
-        if self.paged:
-            req.pages = shared + ids
-            req.prefix_hold = None  # holds adopted as the slot's own
-            table_row = kv.bind_slot_pages(s, req.pages,
-                                           n_shared=len(shared))
-            kv.pin(table_row)
-            if kv.debug_validate:
-                for pid in ids:     # fresh pages must be private
-                    assert self.pager.refcount(pid) == 1 \
-                        and not self.pager.is_cached(pid), (
-                        f"freshly allocated page {pid} is shared")
-            with self._pool_lock:
-                new_cache = self._dispatch(self.insert, kv.cache,
-                                           rows_cache, row_dev,
-                                           slot_dev, table_row)
-                # donated: the displaced version was consumed by the
-                # insert (never pinned); copied: commit pins it
-                kv.commit(new_cache, donated=self.donate)
-        else:
-            with self._pool_lock:
-                new_cache = self._dispatch(self.insert, kv.cache,
-                                           rows_cache, row_dev,
-                                           slot_dev)
-                kv.commit(new_cache, donated=self.donate)
+        req.pages = shared + ids
+        req.prefix_hold = None      # holds adopted as the slot's own
+        table_row = kv.bind_slot_pages(s, req.pages, n_shared=len(shared))
+        kv.pin(table_row)
+        if kv.debug_validate:
+            for pid in ids:         # fresh pages must be private
+                assert self.pager.refcount(pid) == 1 \
+                    and not self.pager.is_cached(pid), (
+                    f"freshly allocated page {pid} is shared")
+        with self._pool_lock:
+            new_cache = self._dispatch(self.insert, kv.cache, rows_cache,
+                                       row_dev, slot_dev, table_row)
+            # the displaced version was donated to the insert: never pinned
+            kv.commit(new_cache)
         if req.t_admit is None:
             req.t_admit = time.monotonic()
             if req.t_submit is not None:    # queued through submit()
@@ -1592,7 +1540,7 @@ class ServeEngine:
             self._rebind_active()
 
     def _release_slot(self, s: int):
-        """Free a slot and, when paged, its pages — immediately, so the
+        """Free a slot and its pages — immediately, so the
         very next admission can reuse them; the slot's table rows are
         re-pointed at the garbage page so the dead slot's frozen-pos
         cache writes land nowhere.  ``release`` (not ``free``): a page
@@ -1601,10 +1549,9 @@ class ServeEngine:
         req = self._slot_req[s]
         self._active[s] = False
         self._slot_req[s] = None
-        if self.paged and req.pages is not None:
-            self.kv.release_slot_pages(s)
-            self.pager.release(req.pages)
-            req.pages = None
+        self.kv.release_slot_pages(s)
+        self.pager.release(req.pages)
+        req.pages = None
 
     def _tick(self):
         kv = self.kv
@@ -1617,10 +1564,8 @@ class ServeEngine:
                 if v is not None:
                     self._evict_slot(int(v))
                     self._rebind_active()
-                    if self.paged:
-                        kv.sync_table()
-            if self.paged:
-                self._page_faults()
+                    kv.sync_table()
+            self._page_faults()
         if not self._active.any():
             return                      # everything evicted: no tick
         if kv.debug_validate and self.prefix is not None:
@@ -1637,15 +1582,10 @@ class ServeEngine:
                     f"slot {int(s)} would decode-write shared/cached "
                     f"page {pid}")
         with self._span("tick.dispatch"), self._pool_lock:
-            if self.paged:
-                new_tokens, new_cache = self._dispatch(
-                    self.decode, self._params, kv.cache, self._tokens,
-                    self._active_dev, kv.table_dev)
-            else:
-                new_tokens, new_cache = self._dispatch(
-                    self.decode, self._params, kv.cache, self._tokens,
-                    self._active_dev)
-            kv.commit(new_cache, donated=self.donate)
+            new_tokens, new_cache = self._dispatch(
+                self.decode, self._params, kv.cache, self._tokens,
+                self._active_dev, kv.table_dev)
+            kv.commit(new_cache)
         self.stats_decode_dispatches += 1
         self._rebind_tokens(new_tokens)
         self._slot_pos[self._active] += 1   # each live slot wrote one pos
@@ -1693,8 +1633,7 @@ class ServeEngine:
                 freed = True
         if freed:
             self._rebind_active()
-            if self.paged:
-                self.kv.sync_table()
+            self.kv.sync_table()
         # freed: a finish forced the chain; sync_ticks / host_toks: this
         # tick's sync did.  Otherwise flush only past the depth cap.
         synced = freed or self.sync_ticks or host_toks is not None
@@ -1767,15 +1706,10 @@ class ServeEngine:
         pos_dev = kv.to_dev(self._slot_pos.astype(np.int32))
         n_dev = kv.to_dev(n_tok)
         with self._span("tick.dispatch"), self._pool_lock:
-            if self.paged:
-                nxt, new_cache = self._dispatch(
-                    self.verify, self._params, kv.cache, toks_dev,
-                    pos_dev, n_dev, kv.table_dev)
-            else:
-                nxt, new_cache = self._dispatch(
-                    self.verify, self._params, kv.cache, toks_dev,
-                    pos_dev, n_dev)
-            kv.commit(new_cache, donated=self.donate)
+            nxt, new_cache = self._dispatch(
+                self.verify, self._params, kv.cache, toks_dev, pos_dev,
+                n_dev, kv.table_dev)
+            kv.commit(new_cache)
         self.stats_decode_dispatches += 1
         host_nxt = np.asarray(nxt)      # forces the dispatch chain
         now = time.monotonic()
@@ -1832,8 +1766,7 @@ class ServeEngine:
                 freed = True
         if freed:
             self._rebind_active()
-            if self.paged:
-                self.kv.sync_table()
+            self.kv.sync_table()
         # the host_nxt sync above proved the whole chain executed
         self.kv.flush(synced=True)
 
@@ -1846,8 +1779,7 @@ class ServeEngine:
             if v is not None:
                 self._evict_slot(int(v))
                 self._rebind_active()
-                if self.paged:
-                    self.kv.sync_table()
+                self.kv.sync_table()
         # draft before the fault pass: on-demand growth must cover the
         # whole verify window, not just the next position — a window can
         # cross several page boundaries at once
@@ -1857,14 +1789,12 @@ class ServeEngine:
             d = self._spec_window(self._slot_req[s])
             if d:
                 drafts[s] = d
-        if self.paged:
-            ahead = np.zeros((self.slots,), np.int64)
-            for s, d in drafts.items():
-                ahead[s] = len(d)
-            self._page_faults(ahead=ahead)
-            # the fault pass may have evicted a drafted slot
-            drafts = {s: d for s, d in drafts.items() if self._active[s]}
-        return drafts
+        ahead = np.zeros((self.slots,), np.int64)
+        for s, d in drafts.items():
+            ahead[s] = len(d)
+        self._page_faults(ahead=ahead)
+        # the fault pass may have evicted a drafted slot
+        return {s: d for s, d in drafts.items() if self._active[s]}
 
     def _drained(self) -> bool:
         with self._lock:
@@ -1941,7 +1871,6 @@ class ServeEngine:
             "prefix_tokens_saved": self.stats_prefix_tokens_saved,
             "cow_forks": self.stats_cow_forks,
             "policy": self.policy.name,
-            "donate": self.donate,
             "paged_kernel": self.paged_kernel,
             "tp": self.tp,
             "p50_latency_s": percentile(lats, 0.50),

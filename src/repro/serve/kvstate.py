@@ -6,10 +6,10 @@ Ownership model
 ---------------
 Exactly one live version of the KV cache pytree exists at any time and
 :class:`KVState` is its owner.  Every jitted step that rewrites the
-cache (decode tick, slot insert) consumes the current version and
-produces the next, and the rebind goes through :meth:`KVState.commit` —
-nothing else ever holds the live tree.  That single-owner rule is what
-makes **buffer donation** a correctness-preserving optimisation: with
+cache (decode tick, verify, slot insert) donates the current version
+and produces the next, and the rebind goes through
+:meth:`KVState.commit` — nothing else ever holds the live tree.  That
+single-owner rule is what makes **buffer donation** sound: with
 ``donate_argnums`` on the cache argument, XLA aliases the donated
 input's device buffers into the output (verified per leaf on this
 backend), so a decode tick updates the KV pool *in place* instead of
@@ -30,11 +30,11 @@ discipline explicitly, replacing the engine's ad-hoc ``_retain`` list:
 
 * :meth:`pin` — pin a *displaced* version (or a dispatch temporary) the
   moment it stops being engine state;
-* :meth:`commit` — rebind the live cache to the next version, pinning
-  the displaced one exactly when it was **not** donated.  A donated
-  version's lifetime is owned by the computation that consumed it, so
-  pinning it would hold a dead husk — the two mechanisms must never
-  overlap (asserted when ``debug_validate`` is on, and tested);
+* :meth:`commit` — rebind the live cache to the next version.  The
+  displaced version was donated: its lifetime is owned by the
+  computation that consumed it, so it is never pinned — pinning it
+  would hold a dead husk.  Donation and pinning must never overlap
+  (asserted when ``debug_validate`` is on, and tested);
 * :meth:`flush` — drop every pin at a proven sync point, or pay one
   bounded ``block_until_ready`` when the pin list hits its cap (an
   unbounded list pins whole cache versions: a leak with allocator
@@ -56,8 +56,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.layers import check_cache_invariant
 from ..sharding import logical_sharding
-from ..steps import (TP_SERVE_RULES, init_paged_slot_cache, init_slot_cache,
-                     serve_cache_axes)
+from ..steps import TP_SERVE_RULES, init_paged_slot_cache, serve_cache_axes
 from .pager import GARBAGE_PAGE, PagePool
 
 # The engine-init guard and the per-block trace-time guard are the SAME
@@ -78,7 +77,7 @@ def _no_deleted_leaves(objs, where: str):
 
 def cache_tree_shardings(cache, mesh):
     """Per-leaf ``NamedSharding`` tree for any cache with the serve leaf
-    names — the slot pool, a paged pool, or a prefill row cache (same
+    names — the paged slot pool or a prefill row cache (same
     leaf names and ranks throughout).  Resolution is strict: a mesh axis
     that does not divide the leaf dim is dropped (small head counts
     replicate, never pad — pjit argument shardings must divide evenly).
@@ -95,23 +94,22 @@ def cache_tree_shardings(cache, mesh):
 
 
 class KVState:
-    """Single owner of one slot pool's KV cache (dense or paged).
+    """Single owner of one slot pool's paged KV cache.
 
-    Parameters mirror the engine's cache geometry.  With ``page_size``
-    set the linear attention leaves are paged pools; ``KVState`` then
-    also owns the block table (host copy + device mirror, garbage page
-    re-pointing) and the :class:`PagePool` free-list (``num_pages``
-    defaults to dense-equivalent capacity + the garbage page).
+    Parameters mirror the engine's cache geometry.  The linear attention
+    leaves are paged pools; ``KVState`` also owns the block table (host
+    copy + device mirror, garbage page re-pointing) and the
+    :class:`PagePool` free-list (``num_pages`` defaults to one full
+    cache per slot + the garbage page).
     """
 
     def __init__(self, cfg, slots: int, cache_len: int, dtype, *,
-                 page_size: int | None = None, num_pages: int | None = None,
+                 page_size: int, num_pages: int | None = None,
                  pin_max: int = 64, mesh=None, tp: bool = False):
         self.cfg = cfg
         self.slots = slots
         self.cache_len = cache_len
         self.page_size = page_size
-        self.paged = page_size is not None
         # tensor-parallel serving: every cache leaf gets a per-leaf
         # NamedSharding (heads on the model axis, strict resolution — a
         # head count the axis cannot divide replicates, never pads: pjit
@@ -124,37 +122,26 @@ class KVState:
             self.rep = NamedSharding(mesh, P())
         else:
             self.rep = None
-        if self.paged:
-            assert cache_len % page_size == 0, (
-                f"page_size {page_size} must divide cache_len {cache_len}")
-            self.pages_per_slot = cache_len // page_size
-            if num_pages is None:
-                num_pages = slots * self.pages_per_slot + 1
-            self.pager = PagePool(num_pages, page_size)
-            self.cache = init_paged_slot_cache(cfg, slots, cache_len, dtype,
-                                               page_size, num_pages)
-            self._table = np.zeros((slots, self.pages_per_slot), np.int32)
-        else:
-            self.pages_per_slot = 0
-            self.pager = None
-            self.cache = init_slot_cache(cfg, slots, cache_len, dtype)
-            self._table = None
+        assert cache_len % page_size == 0, (
+            f"page_size {page_size} must divide cache_len {cache_len}")
+        self.pages_per_slot = cache_len // page_size
+        if num_pages is None:
+            num_pages = slots * self.pages_per_slot + 1
+        self.pager = PagePool(num_pages, page_size)
+        self.cache = init_paged_slot_cache(cfg, slots, cache_len, dtype,
+                                           page_size, num_pages)
+        self._table = np.zeros((slots, self.pages_per_slot), np.int32)
         self.shardings = self.cache_shardings(self.cache)
         if self.tp:
             self.cache = jax.device_put(self.cache, self.shardings)
-        if self.paged:
-            # device mirrors are always a copy (jnp.array, or a committed
-            # device_put of one under tp): asarray may alias the numpy
-            # buffer, which async dispatch could read *after* a later
-            # host-side mutation
-            self.table_dev = self.to_dev(self._table)
-        else:
-            self.table_dev = None
+        # device mirrors are always a copy (jnp.array, or a committed
+        # device_put of one under tp): asarray may alias the numpy
+        # buffer, which async dispatch could read *after* a later
+        # host-side mutation
+        self.table_dev = self.to_dev(self._table)
         self._pins: list = []
         self.pin_max = pin_max
         self.version = 0
-        self.donated_commits = 0
-        self.copied_commits = 0
         self.pin_syncs = 0            # forced drains from a full pin list
         self.debug_validate = False   # tests: scan pins for dead buffers
 
@@ -175,19 +162,10 @@ class KVState:
         return jnp.array(x)
 
     # ------------------------------------------------------------ ownership
-    def commit(self, new_cache, *, donated: bool) -> None:
-        """Rebind the live cache to ``new_cache``.
-
-        ``donated=False``: the displaced version may still be read by
-        dispatched-but-pending computations — pin it until a sync point.
-        ``donated=True``: the displaced version was consumed by the jit
-        call that produced ``new_cache`` (its buffers now belong to that
-        execution), so it must **not** be pinned."""
-        if not donated:
-            self._pins.append(self.cache)
-            self.copied_commits += 1
-        else:
-            self.donated_commits += 1
+    def commit(self, new_cache) -> None:
+        """Rebind the live cache to ``new_cache``.  The displaced version
+        was donated to the jit call that produced ``new_cache`` (its
+        buffers now belong to that execution), so it is **not** pinned."""
         self.cache = new_cache
         self.version += 1
         if self.debug_validate:
@@ -242,7 +220,6 @@ class KVState:
         donation-safety rule made mechanical: a donated step never
         aliases a shared page it writes, because the write path never
         sees a shared page id."""
-        assert self.paged
         assert 0 <= n_shared <= len(ids)
         self._table[slot, :] = GARBAGE_PAGE
         self._table[slot, :len(ids)] = ids
@@ -264,7 +241,6 @@ class KVState:
         unlike :meth:`bind_slot_pages` (admission needs the device row
         immediately) the caller batches one :meth:`sync_table` per tick
         over every slot grown that tick."""
-        assert self.paged
         assert 0 <= base and base + len(ids) <= self.pages_per_slot, (
             f"slot {slot}: grow [{base}, {base + len(ids)}) exceeds "
             f"{self.pages_per_slot} logical pages")
@@ -277,14 +253,12 @@ class KVState:
         the dead slot's frozen-pos cache writes land nowhere.  Host-side
         only — the caller refreshes the device mirror once per batch of
         releases (:meth:`sync_table`)."""
-        assert self.paged
         self._table[slot, :] = GARBAGE_PAGE
 
     def sync_table(self) -> None:
         """Refresh the device block table from the host copy; the
         displaced mirror is an argument of pending decode dispatches,
         so it is pinned, not dropped."""
-        assert self.paged
         self.pin(self.table_dev)
         self.table_dev = self.to_dev(self._table)
 
@@ -292,17 +266,12 @@ class KVState:
     def stats(self) -> dict:
         out = {
             "kv_version": self.version,
-            "kv_donated_commits": self.donated_commits,
-            "kv_copied_commits": self.copied_commits,
             "kv_pins": len(self._pins),
             "kv_pin_syncs": self.pin_syncs,
         }
-        if self.pager is not None:
-            out.update(self.pager.stats())
+        out.update(self.pager.stats())
         return out
 
     def __repr__(self):
-        layout = (f"paged(ps={self.page_size})" if self.paged else "dense")
-        return (f"<KVState v{self.version} {layout} slots={self.slots} "
-                f"pins={len(self._pins)} donated={self.donated_commits} "
-                f"copied={self.copied_commits}>")
+        return (f"<KVState v{self.version} paged(ps={self.page_size}) "
+                f"slots={self.slots} pins={len(self._pins)}>")

@@ -54,7 +54,7 @@ class SlotView:
     """Read-only snapshot of one live slot for policy decisions.
 
     ``admit_seq`` orders slots by admission time (higher = younger);
-    ``pages_held`` is the physical pages currently bound (0 when dense);
+    ``pages_held`` is the physical pages currently bound;
     ``next_pos`` is the cache position the next decode tick will write;
     ``emitted``/``budget`` are tokens generated so far / ``max_new``.
     """

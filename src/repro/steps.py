@@ -25,11 +25,11 @@ DECODE_RULES = {"heads": ()}  # decode shards cache-seq, not heads
 # read them without a gather.
 TP_SERVE_RULES = {"seq_shard": (), "batch": ()}
 
-# Serve-cache leaf name -> logical axes (dense slot pools, paged pools
-# and batch=1/bpad prefill row caches all share leaf names and ranks, so
-# one table covers every cache the engine moves between steps).  Leaves
-# without a head-like dim (MLA latents, pos) resolve to fully replicated
-# under TP_SERVE_RULES.
+# Serve-cache leaf name -> logical axes (paged slot pools and batch=1/bpad
+# prefill row caches all share leaf names and ranks, so one table covers
+# every cache the engine moves between steps).  Leaves without a
+# head-like dim (MLA latents, pos) resolve to fully replicated under
+# TP_SERVE_RULES.
 SERVE_CACHE_AXES: dict[str, tuple[str | None, ...]] = {
     "k": (None, "batch", "seq_shard", "kv_heads", None),
     "v": (None, "batch", "seq_shard", "kv_heads", None),
@@ -158,16 +158,6 @@ def greedy_oneshot(prefill, serve_step, params, prompts, patches, gen):
 
 
 # ------------------------------------------------- continuous-batching steps
-def init_slot_cache(cfg, slots: int, cache_len: int, dtype):
-    """Batched KV cache shared by a pool of ``slots`` serve slots: same
-    leaves as :func:`init_cache` but ``pos`` is a (slots,) vector — every
-    slot decodes at its own depth (continuous batching)."""
-    cache = init_cache(cfg, slots, cache_len, jnp.dtype(dtype))
-    cache["pos"] = jnp.zeros((slots,), jnp.int32)
-    return cache
-
-
-# ------------------------------------------------------------ paged KV cache
 def chunkable(cfg, cache_len: int) -> bool:
     """Can prefill be chunked bit-exactly for this config?  Requires every
     block to be linear-cache attention: MoE routing capacity depends on
@@ -204,8 +194,9 @@ def init_paged_slot_cache(cfg, slots: int, cache_len: int, dtype,
     block table (held by the caller, see repro.serve.pager.PagePool).
     Page 0 is the reserved garbage page: dead slots and unallocated
     logical pages point there, so their scatters never corrupt a live
-    slot.  Bounded leaves keep the dense per-slot layout of
-    :func:`init_slot_cache`.
+    slot.  Bounded leaves (SWA rings, SSM conv/state) keep one row per
+    slot, as :func:`init_cache` lays them out for ``slots`` rows; ``pos``
+    is a (slots,) vector — every slot decodes at its own depth.
 
     The pool stays stacked over the layer repeats: the decode and verify
     steps carry it whole through the layer scan and index it by layer
@@ -230,49 +221,21 @@ def init_paged_slot_cache(cfg, slots: int, cache_len: int, dtype,
     return {"pos": jnp.zeros((slots,), jnp.int32), "blocks": tuple(blocks)}
 
 
-def make_insert_step(cfg, mesh=None, *, tp=False):
-    """Scatter one prefilled request (a batch=1 cache from
-    ``make_prefill_step`` with the pool's ``cache_len``) into slot ``slot``
-    of the shared batched cache, replacing every leaf row — so whatever a
-    dead slot wrote there while it was idle is erased.
-
-    (cache, row_cache, slot) -> cache with slot ``slot`` replaced.
-    ``slot`` may be a traced scalar: one jit covers every slot.
-    """
-    rules = TP_SERVE_RULES if tp else DECODE_RULES
-
-    def insert_step(cache, row_cache, slot):
-        with sharding_ctx(mesh, rules):
-            def put(c, r):
-                start = (0, slot) + (0,) * (c.ndim - 2)
-                return jax.lax.dynamic_update_slice(c, r.astype(c.dtype),
-                                                    start)
-
-            blocks = jax.tree.map(put, cache["blocks"], row_cache["blocks"])
-            pos = cache["pos"].at[slot].set(
-                row_cache["pos"].astype(jnp.int32))
-            return {"pos": pos, "blocks": blocks}
-
-    return insert_step
-
-
 def make_batched_insert_step(cfg, mesh=None, *, cache_len: int,
-                             page_size: int | None = None, tp=False):
+                             page_size: int, tp=False):
     """Insert row ``row`` of a *batched* prefill output into slot ``slot``
-    of the shared cache (dense or paged).
+    of the shared paged cache:
 
-    Dense (``page_size is None``):
-        (cache, rows_cache, row, slot) -> cache
-    Paged:
         (cache, rows_cache, row, slot, table_row) -> cache
-        ``table_row``: (pages_per_slot,) physical page ids for the slot;
-        unreserved logical pages point at garbage page 0 (their scatters
-        collide there and are never read valid).  Under on-demand paging
-        the garbage tail is later re-pointed at real pages as the slot's
-        ``pos`` grows past a boundary (``KVState.grow_slot_pages``) —
-        sound precisely because the tail positions were never written
-        anywhere else: the decode scatter fills each page at the moment
-        its position range first becomes live.
+
+    ``table_row``: (pages_per_slot,) physical page ids for the slot;
+    unreserved logical pages point at garbage page 0 (their scatters
+    collide there and are never read valid).  Under on-demand paging the
+    garbage tail is later re-pointed at real pages as the slot's ``pos``
+    grows past a boundary (``KVState.grow_slot_pages``) — sound precisely
+    because the tail positions were never written anywhere else: the
+    decode scatter fills each page at the moment its position range
+    first becomes live.  Bounded leaves are written at row ``slot``.
 
     ``rows_cache`` is a dense (B, cache_len) prefill/chunk cache; ``row``
     and ``slot`` may be traced scalars, so one jit covers every
@@ -284,13 +247,12 @@ def make_batched_insert_step(cfg, mesh=None, *, cache_len: int,
     feeds one insert per row, so the same version is read repeatedly."""
     rules = TP_SERVE_RULES if tp else DECODE_RULES
 
-    def insert_step(cache, rows_cache, row, slot, table_row=None):
+    def insert_step(cache, rows_cache, row, slot, table_row):
         with sharding_ctx(mesh, rules):
             new_blocks = []
             for spec, cb, rb in zip(cfg.pattern, cache["blocks"],
                                     rows_cache["blocks"]):
-                paged = (paged_names(spec, cache_len)
-                         if page_size is not None else frozenset())
+                paged = paged_names(spec, cache_len)
                 leaves = {}
                 for name, c in cb.items():
                     r = jax.lax.dynamic_slice_in_dim(rb[name], row, 1,
@@ -370,11 +332,10 @@ def make_prefix_gather_step(cfg, mesh=None, *, cache_len: int,
     return gather_step
 
 
-def make_decode_step(cfg, mesh=None, *, cache_len: int | None = None,
-                     page_size: int | None = None,
+def make_decode_step(cfg, mesh=None, *, cache_len: int, page_size: int,
                      paged_kernel: bool = False, tp=False):
     """Masked continuous-batching decode over the slot pool:
-    (params, cache, tokens, active[, table]) -> (next_tokens, cache).
+    (params, cache, tokens, active, table) -> (next_tokens, cache).
 
     ``cache["pos"]`` is (slots,) per-slot positions; ``active`` is a
     (slots,) bool mask.  Dead slots emit token 0 and do not advance
@@ -382,18 +343,18 @@ def make_decode_step(cfg, mesh=None, *, cache_len: int | None = None,
     independent, MoE capacity is per-row) but can never corrupt a live
     slot's sampling, and an insert replaces their whole row anyway.
 
-    With ``page_size`` set the linear attention leaves of ``cache`` are
-    paged pools and the extra ``table`` argument carries the
-    (slots, pages_per_slot) block table; dead slots' tables point at
-    garbage page 0, so their (frozen-``pos``) cache writes land there.
-    The table is a per-tick *argument*, not captured state, which is
-    what lets the engine grow a live slot's row between ticks (on-demand
-    paging) or re-point an evicted slot's row at garbage without
-    recompiling — the jit sees the same shape either way.
+    The linear attention leaves of ``cache`` are paged pools and
+    ``table`` carries the (slots, pages_per_slot) block table; dead
+    slots' tables point at garbage page 0, so their (frozen-``pos``)
+    cache writes land there.  The table is a per-tick *argument*, not
+    captured state, which is what lets the engine grow a live slot's row
+    between ticks (on-demand paging) or re-point an evicted slot's row
+    at garbage without recompiling — the jit sees the same shape either
+    way.
 
-    ``paged_kernel=True`` (paged only) routes the paged attention leaves
-    through the fused Pallas kernel: the block table is walked in-kernel
-    and K/V pages are read in place instead of materialising the dense
+    ``paged_kernel=True`` routes the paged attention leaves through the
+    fused Pallas kernel: the block table is walked in-kernel and K/V
+    pages are read in place instead of materialising the dense
     ``page_gather`` view every tick.  Greedy tokens are identical; the
     default-off dense-gather leg stays the A/B baseline and oracle.
 
@@ -402,19 +363,14 @@ def make_decode_step(cfg, mesh=None, *, cache_len: int | None = None,
     so XLA aliases the whole pool in place and a tick stops copying it.
     Tokens/active/table are *not* donated: the engine keeps reading
     them (token streams, host mirrors) after the dispatch."""
-    paged = page_size is not None
-    if paged:
-        assert cache_len is not None and cache_len % page_size == 0
-    assert not (paged_kernel and not paged), \
-        "paged_kernel needs a paged cache (page_size set)"
+    assert cache_len % page_size == 0, (cache_len, page_size)
     rules = TP_SERVE_RULES if tp else DECODE_RULES
 
-    def decode_step(params, cache, tokens, active, table=None):
+    def decode_step(params, cache, tokens, active, table):
         with sharding_ctx(mesh, rules):
             pc = cast_tree(params, cfg.dtype)
-            pages = ({"table": table, "page_size": page_size,
-                      "cache_len": cache_len, "kernel": paged_kernel}
-                     if paged else None)
+            pages = {"table": table, "page_size": page_size,
+                     "cache_len": cache_len, "kernel": paged_kernel}
             out = forward(pc, cfg, tokens, mode="decode", pos=cache["pos"],
                           cache=cache, pages=pages)
             nxt = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
@@ -425,18 +381,14 @@ def make_decode_step(cfg, mesh=None, *, cache_len: int | None = None,
                                          cache["pos"])
             return nxt, new_cache
 
-    if not paged:
-        def decode_step_dense(params, cache, tokens, active):
-            return decode_step(params, cache, tokens, active)
-        return decode_step_dense
     return decode_step
 
 
-def make_verify_step(cfg, mesh=None, *, cache_len: int | None = None,
-                     page_size: int | None = None, tp=False):
-    """Draft-verify speculative decode over the slot pool:
+def make_verify_step(cfg, mesh=None, *, cache_len: int, page_size: int,
+                     tp=False):
+    """Draft-verify speculative decode over the paged slot pool:
 
-        (params, cache, tokens, pos, n_tok[, table]) -> (argmax, cache)
+        (params, cache, tokens, pos, n_tok, table) -> (argmax, cache)
 
     ``tokens`` is the (slots, S) verify window per slot — the last
     committed token followed by up to S-1 drafted tokens, right-padded;
@@ -471,31 +423,24 @@ def make_verify_step(cfg, mesh=None, *, cache_len: int | None = None,
     Donation: safe to jit with ``donate_argnums=(1,)`` — the same
     shape/dtype-preserving cache append as decode (trace-time checked).
     """
-    paged = page_size is not None
-    if paged:
-        assert cache_len is not None and cache_len % page_size == 0
-    assert cache_len is None or speculatable(cfg, cache_len), (
+    assert cache_len % page_size == 0, (cache_len, page_size)
+    assert speculatable(cfg, cache_len), (
         f"{cfg.name}: speculative decoding needs a chunk-exact config "
         "(no MoE, no SSM, no SWA ring shorter than cache_len) and a "
         "scalar greedy-token frontend")
     rules = TP_SERVE_RULES if tp else DECODE_RULES
 
-    def verify_step(params, cache, tokens, pos, n_tok, table=None):
+    def verify_step(params, cache, tokens, pos, n_tok, table):
         with sharding_ctx(mesh, rules):
             pc = cast_tree(params, cfg.dtype)
-            pages = ({"table": table, "page_size": page_size,
-                      "cache_len": cache_len, "kernel": False}
-                     if paged else None)
+            pages = {"table": table, "page_size": page_size,
+                     "cache_len": cache_len, "kernel": False}
             out = forward(pc, cfg, tokens, mode="verify", pos=pos,
                           cache=cache, cache_len=cache_len, pages=pages,
                           n_tok=n_tok)
             nxt = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
             return nxt, out["cache"]
 
-    if not paged:
-        def verify_step_dense(params, cache, tokens, pos, n_tok):
-            return verify_step(params, cache, tokens, pos, n_tok)
-        return verify_step_dense
     return verify_step
 
 
@@ -541,9 +486,9 @@ def make_prefill_chunk_step(cfg, mesh=None, cache_len: int | None = None, *,
 
 
 __all__ = ["init_train_state", "make_train_step", "make_prefill_step",
-           "make_serve_step", "make_insert_step", "make_decode_step",
+           "make_serve_step", "make_decode_step",
            "make_batched_insert_step", "make_prefill_chunk_step",
            "make_prefix_gather_step", "make_verify_step",
-           "init_slot_cache", "init_paged_slot_cache", "paged_names",
+           "init_paged_slot_cache", "paged_names",
            "chunkable", "speculatable", "greedy_oneshot", "cast_tree",
            "init_cache", "OptHParams", "TP_SERVE_RULES", "serve_cache_axes"]

@@ -88,3 +88,14 @@ def test_init_serve_params_is_born_sharded_and_equals_unsharded_init():
                        capture_output=True, text=True, env=env, cwd=root,
                        timeout=600)
     assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-3000:]
+
+
+def test_negative_page_size_is_an_argument_error(capsys):
+    """``--page-size`` takes 0 (auto) or a page size; a negative value
+    is refused by the argument parser before anything is built."""
+    from repro.launch.serve import serve
+
+    with pytest.raises(SystemExit) as e:
+        serve(["--page-size", "-1"])
+    assert e.value.code == 2
+    assert "--page-size" in capsys.readouterr().err

@@ -399,20 +399,61 @@ def test_batched_prefill_coalesces_bursts(built):
 
 @pytest.mark.slow
 def test_dense_legacy_engine_still_exact(built):
-    """page_size=None keeps the seed's dense per-slot reservation (the
-    benchmark A/B leg) — same tokens, no pager."""
+    """page_size == cache_len gives each slot one page: the one-shot
+    tokens, one page per admitted request, every page back at the
+    end."""
     from repro.serve import make_jit_steps
 
     b = built
-    dense = make_jit_steps(b["cfg"], cache_len=CACHE_LEN, page_size=None)
+    one = make_jit_steps(b["cfg"], cache_len=CACHE_LEN,
+                         page_size=CACHE_LEN)
     reqs = [Request(i, b["prompts"][i], max_new_tokens=4)
             for i in range(5)]
-    stats, pager = _run_engine(b, reqs, page_size=None, jit_steps=dense)
+    stats, pager = _run_engine(b, reqs, page_size=CACHE_LEN,
+                               jit_steps=one)
     for r in reqs:
         assert np.array_equal(np.asarray(r.wait(), np.int32),
                               b["ref"][r.rid, :4])
-    assert pager is None
-    assert stats["page_size"] is None
+    assert stats["page_size"] == CACHE_LEN
+    assert pager.capacity == 3 and pager.live_refs == 0
+
+
+@pytest.mark.parametrize("page_size", [None, 0, -7, 5])
+def test_engine_page_size_must_be_a_positive_divisor(page_size):
+    """Every engine is paged: no page size, a non-positive one or one
+    that does not divide cache_len is refused before anything is
+    built."""
+    from repro.configs import get
+    from repro.serve import ServeEngine
+
+    with pytest.raises(ValueError, match="positive divisor"):
+        ServeEngine(get("qwen2.5-14b").tiny(), {}, cache_len=CACHE_LEN,
+                    page_size=page_size)
+
+
+@pytest.mark.slow
+def test_default_jit_steps_are_paged_at_the_engine_page_size(built):
+    """``make_jit_steps`` with default arguments holds the engine's own
+    layout rule: an engine handed them pages at ``auto_page_size`` and
+    serves the one-shot tokens."""
+    from repro.serve import ServeEngine, auto_page_size, make_jit_steps
+
+    b = built
+    steps = make_jit_steps(b["cfg"], cache_len=CACHE_LEN)
+    assert steps["page_size"] == auto_page_size(CACHE_LEN)
+    reqs = [Request(i, b["prompts"][i], max_new_tokens=3)
+            for i in range(3)]
+    with ServeEngine(b["cfg"], b["params"], slots=3, cache_len=CACHE_LEN,
+                     jit_steps=steps) as eng:
+        for r in reqs:
+            eng.submit(r)
+        eng.close()
+        eng.join()
+        assert eng.page_size == auto_page_size(CACHE_LEN)
+        assert eng.kv.pages_per_slot == CACHE_LEN // eng.page_size
+    for r in reqs:
+        assert np.array_equal(np.asarray(r.wait(), np.int32),
+                              b["ref"][r.rid, :3])
 
 
 @pytest.mark.slow
@@ -432,27 +473,6 @@ def test_oversized_request_fails_loudly(built):
                           b["ref"][1, :2])
     assert pager.live_refs == 0
     assert pager.used_pages == pager.cached_pages
-
-
-@pytest.mark.slow
-def test_engine_donation_off_leg_still_exact(built):
-    """donate=False is the copying legacy path (benchmark A/B leg): same
-    tokens, every commit pins the displaced cache version, nothing is
-    donated."""
-    from repro.serve import make_jit_steps
-
-    b = built
-    steps = make_jit_steps(b["cfg"], cache_len=CACHE_LEN,
-                           page_size=PAGE_SIZE, donate=False)
-    reqs = [Request(i, b["prompts"][i], max_new_tokens=4)
-            for i in range(5)]
-    stats, pager = _run_engine(b, reqs, jit_steps=steps)
-    for r in reqs:
-        assert np.array_equal(np.asarray(r.wait(), np.int32),
-                              b["ref"][r.rid, :4])
-    assert stats["donate"] is False
-    assert stats["kv_donated_commits"] == 0
-    assert stats["kv_copied_commits"] == stats["kv_version"] > 0
 
 
 @pytest.mark.slow
@@ -480,9 +500,7 @@ def test_donated_version_never_pinned(built):
     for r in reqs:
         assert np.array_equal(np.asarray(r.wait(), np.int32),
                               b["ref"][r.rid])
-    assert stats["donate"] is True
-    assert stats["kv_donated_commits"] == stats["kv_version"] > 0
-    assert stats["kv_copied_commits"] == 0
+    assert stats["kv_version"] > 0
 
 
 @pytest.mark.slow

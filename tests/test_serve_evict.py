@@ -3,14 +3,14 @@
 * evict -> restore greedy tokens are **bit-identical** to the
   never-evicted run — organically (on-demand paging into a tight pool)
   across all five frontends plus the SSM hybrid, and under forced fuzz
-  evictions over the dense x paged, donation on x off grid;
+  evictions over the page-size x paged-kernel grid;
 * the donation/pinning invariant holds under evict (no pinned leaf is a
   donated husk, and no freed page is read by a pending dispatch);
 * on-demand allocation never deadlocks while the policy can always name
   one evictable victim (severe-pressure drain test with a watchdog);
 * shared-prefix KV reuse serves bit-exact: a warm trie turns admissions
   into prefix hits (gather + tail chunks over shared pages) whose greedy
-  tokens equal the cold one-shot run across the arch and donation x
+  tokens equal the cold one-shot run across the arch and page-size x
   paged-kernel grids, an evicted slot's pages are re-hit by its own
   restore, and the page_size=1 degenerate trie still saves the prefix.
 
@@ -58,8 +58,9 @@ def test_ondemand_victim_is_youngest():
 
 
 def test_ondemand_policy_requires_paged_engine():
+    """Every engine is paged: asking for no page size is refused."""
     cfg = get("qwen2.5-14b").tiny()
-    with pytest.raises(ValueError, match="on-demand"):
+    with pytest.raises(ValueError, match="page_size"):
         ServeEngine(cfg, {}, slots=2, cache_len=8, page_size=None,
                     policy="ondemand")
 
@@ -144,11 +145,10 @@ def _run(b, policy, *, num_pages=None, jit_steps=None, page_size="use",
         assert len(got) <= r.max_new
         if not r.stopped:
             assert len(got) == r.max_new
-    if pager is not None:
-        assert pager.live_refs == 0, "page refs leaked across evictions"
-        assert pager.used_pages == pager.cached_pages, (
-            "pages leaked across evictions (allocated but neither held "
-            "nor trie-cached)")
+    assert pager.live_refs == 0, "page refs leaked across evictions"
+    assert pager.used_pages == pager.cached_pages, (
+        "pages leaked across evictions (allocated but neither held "
+        "nor trie-cached)")
     return stats
 
 
@@ -176,8 +176,9 @@ def test_evict_restore_bit_exact_under_pressure(arch, built):
 
 class FuzzEvictPolicy(SchedulerPolicy):
     """Forced-preemption fuzz: every ``period`` ticks, evict a random
-    live slot — exercises evict/restore on engines (dense included)
-    whose allocator would never preempt on its own."""
+    live slot — exercises evict/restore on engines (worst-case
+    reservation included) whose allocator would never preempt on its
+    own."""
 
     def __init__(self, seed, period=3, max_evictions=4):
         self.rng = np.random.default_rng(seed)
@@ -199,34 +200,37 @@ class OnDemandFuzzEvict(FuzzEvictPolicy, OnDemandPolicy):
     on_demand = True
 
 
+def _grid_page_size(b, pages):
+    """The page-size axis of the engine grids: one token per page, the
+    small pages the fuzz grid runs, or one page per slot."""
+    return {"token": 1, "small": b["ps"],
+            "one_page": b["cache_len"]}[pages]
+
+
 @pytest.mark.slow
-@pytest.mark.parametrize("layout,donate", [("dense", True),
-                                           ("dense", False),
-                                           ("paged", True),
-                                           ("paged", False),
-                                           ("kernel", True),
-                                           ("kernel", False)])
-def test_evict_grid_dense_paged_donation(layout, donate, built):
-    """(a) across the grid: forced fuzz evictions on dense x paged x
-    fused-paged-kernel, donation on x off — including an eos request
-    whose stop fired *before* an eviction could re-check it (restore
-    must not re-emit or re-stop).  Tokens bit-exact (the kernel rows
-    therefore bit-identical to the gather rows), (b) the pinning
-    invariant holds."""
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("pages", ["token", "small", "one_page"])
+def test_evict_grid_dense_paged_donation(pages, kernel, built):
+    """(a) across the grid: forced fuzz evictions on page size {1, small,
+    cache_len} x gather/fused-paged-kernel decode — including an eos
+    request whose stop fired *before* an eviction could re-check it
+    (restore must not re-emit or re-stop).  One page per slot runs under
+    worst-case reservation, the others on demand.  Tokens bit-exact (the
+    kernel rows therefore bit-identical to the gather rows), (b) the
+    pinning invariant holds."""
     b = _build("qwen2.5-14b", built)
-    ps = b["ps"] if layout != "dense" else None
-    steps = (b["steps"] if layout == "paged" and donate else
+    ps = _grid_page_size(b, pages)
+    steps = (b["steps"] if ps == b["ps"] and not kernel else
              make_jit_steps(b["cfg"], cache_len=b["cache_len"],
-                            page_size=ps, donate=donate,
-                            paged_kernel=layout == "kernel"))
-    policy = (OnDemandFuzzEvict(seed=7) if layout != "dense"
+                            page_size=ps, paged_kernel=kernel))
+    policy = (OnDemandFuzzEvict(seed=7) if pages != "one_page"
               else FuzzEvictPolicy(seed=7))
     eos = [None] * N_REQ
     eos[0] = int(b["ref"][0, 2])      # stops at its 3rd emitted token
     stats = _run(b, policy, jit_steps=steps, page_size=ps, eos=eos)
     assert stats["evictions"] > 0
-    assert stats["donate"] is donate
-    assert stats["paged_kernel"] is (layout == "kernel")
+    assert stats["page_size"] == ps
+    assert stats["paged_kernel"] is kernel
 
 
 @pytest.mark.slow
@@ -446,20 +450,18 @@ def test_spec_decode_bit_exact_under_eviction_churn(arch, built):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout,donate", [("dense", True),
-                                           ("paged", False),
-                                           ("kernel", True)])
-def test_spec_decode_grid_layout_donation(layout, donate, built):
-    """Spec decode across the layout/donation grid (verify always reads
+@pytest.mark.parametrize("layout", ["one_page", "small", "kernel"])
+def test_spec_decode_grid_layout_donation(layout, built):
+    """Spec decode across the layout grid — one page per slot, small
+    pages, small pages with kernel-built decode (verify always reads
     through the gather+dense path — the fused kernel leg checks the
-    engine tolerates kernel-built steps while speculating), with an eos
-    request in the mix and forced evictions.  Tokens bit-exact."""
+    engine tolerates kernel-built steps while speculating) — with an
+    eos request in the mix and forced evictions.  Tokens bit-exact."""
     b = _spec_data(_build("qwen2.5-14b", built))
-    ps = b["ps"] if layout != "dense" else None
+    ps = _grid_page_size(b, "small" if layout == "kernel" else layout)
     steps = make_jit_steps(b["cfg"], cache_len=b["cache_len"],
-                           page_size=ps, donate=donate,
-                           paged_kernel=layout == "kernel")
-    policy = (OnDemandFuzzEvict(seed=7) if layout != "dense"
+                           page_size=ps, paged_kernel=layout == "kernel")
+    policy = (OnDemandFuzzEvict(seed=7) if layout != "one_page"
               else FuzzEvictPolicy(seed=7))
     eos = [None] * N_REQ
     eos[0] = int(b["ref"][0, 2])
@@ -467,7 +469,7 @@ def test_spec_decode_grid_layout_donation(layout, donate, built):
                  spec="ngram")
     assert stats["evictions"] > 0
     assert stats["spec_drafted"] > 0
-    assert stats["donate"] is donate
+    assert stats["page_size"] == ps
 
 
 class OracleDrafter:
@@ -614,8 +616,7 @@ def _run_prefix(b, *, policy=None, num_pages=None, jit_steps=None,
                       policy=policy, prefix_cache=prefix_cache,
                       spec=spec, spec_k=spec_k)
     eng.kv.debug_validate = True
-    if eng.pager is not None:
-        eng.pager.debug_validate = True
+    eng.pager.debug_validate = True
     eng.start()
     eng.submit(reqs[0])
     reqs[0].wait(timeout=120)
@@ -658,21 +659,23 @@ def test_prefix_hit_bit_exact_across_archs(arch, built):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("donate,kernel", [(True, False), (False, False),
-                                           (True, True), (False, True)])
-def test_prefix_hit_grid_donation_paged_kernel(donate, kernel, built):
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("pages", ["token", "small"])
+def test_prefix_hit_grid_donation_paged_kernel(pages, kernel, built):
     """Shared pages read identically through the gather+dense decode
-    and the fused paged-attention kernel, donation on x off — the
-    garbage-masked insert and COW fork keep donated writes off shared
-    pages on every leg."""
+    and the fused paged-attention kernel, at two page sizes that each
+    leave a complete shared page — the garbage-masked insert and COW
+    fork keep the donating steps' writes off shared pages on every
+    leg."""
     b = _build("qwen2.5-14b", built)
+    ps = _grid_page_size(b, pages)
+    assert ps <= N_SHARED
     steps = make_jit_steps(b["cfg"], cache_len=b["cache_len"],
-                           page_size=b["ps"], donate=donate,
-                           paged_kernel=kernel)
-    stats = _run_prefix(b, jit_steps=steps)
+                           page_size=ps, paged_kernel=kernel)
+    stats = _run_prefix(b, jit_steps=steps, page_size=ps)
     assert stats["prefix_hits"] >= 1
     assert stats["prefix_tokens_saved"] > 0
-    assert stats["donate"] is donate
+    assert stats["page_size"] == ps
     assert stats["paged_kernel"] is kernel
 
 

@@ -15,9 +15,9 @@ CFG = get("qwen2.5-14b").tiny()
 SLOTS, CACHE_LEN, PS = 3, 12, 4
 
 
-def _kv(paged=True, **kw):
+def _kv(**kw):
     return KVState(CFG, SLOTS, CACHE_LEN, jnp.dtype(CFG.dtype),
-                   page_size=PS if paged else None, **kw)
+                   page_size=PS, **kw)
 
 
 _touch = jax.jit(lambda c: jax.tree.map(lambda x: x * 1, c))
@@ -26,24 +26,12 @@ _touch_don = jax.jit(lambda c: jax.tree.map(lambda x: x * 1, c),
 
 
 # ------------------------------------------------------------- ownership
-def test_copied_commit_pins_displaced_version():
-    kv = _kv()
-    v0 = kv.cache
-    kv.commit(_touch(v0), donated=False)
-    assert kv.version == 1 and kv.copied_commits == 1
-    assert kv.pins == 1                  # v0 pinned for pending readers
-    assert kv.cache is not v0
-    kv.assert_no_deleted_pins()          # copied versions stay alive
-    kv.flush(synced=True)
-    assert kv.pins == 0
-
-
 def test_donated_commit_never_pins_the_consumed_version():
     kv = _kv()
     kv.debug_validate = True
     v0 = kv.cache
-    kv.commit(_touch_don(v0), donated=True)
-    assert kv.version == 1 and kv.donated_commits == 1
+    kv.commit(_touch_don(v0))
+    assert kv.version == 1
     assert kv.pins == 0                  # v0 was consumed, not pinned
     # the donated version really is dead — single ownership, not style
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(v0))
@@ -53,21 +41,8 @@ def test_donated_commit_never_pins_the_consumed_version():
         kv.assert_no_deleted_pins()
 
 
-def test_donated_chain_stays_bit_exact_with_copy_chain():
-    """The same update chain, donated vs copied, lands on identical
-    leaves — donation changes buffer ownership, never values."""
-    bump = lambda c: jax.tree.map(lambda x: x + jnp.ones((), x.dtype), c)
-    j, jd = jax.jit(bump), jax.jit(bump, donate_argnums=(0,))
-    a, b = _kv(paged=False), _kv(paged=False)
-    for _ in range(4):
-        a.commit(j(a.cache), donated=False)
-        b.commit(jd(b.cache), donated=True)
-    for x, y in zip(jax.tree.leaves(a.cache), jax.tree.leaves(b.cache)):
-        assert np.array_equal(np.asarray(x), np.asarray(y))
-
-
 def test_flush_cap_forces_one_sync_then_clears():
-    kv = _kv(paged=False, pin_max=4)
+    kv = _kv(pin_max=4)
     for _ in range(4):
         kv.pin(jnp.zeros((2,)))
         kv.flush(synced=False)
@@ -134,21 +109,15 @@ def test_grow_slot_pages_multi_page_in_one_call():
         kv.grow_slot_pages(2, kv.pager.alloc(1), base=3)
 
 
-def test_dense_kvstate_has_no_pager_or_table():
-    kv = _kv(paged=False)
-    assert kv.pager is None and kv.table_dev is None
-    assert not kv.paged and kv.pages_per_slot == 0
-
-
 # ------------------------------------------------------------ alias_safe
 def test_alias_safe_accepts_shape_dtype_preserving_step():
-    kv = _kv(paged=False)
+    kv = _kv()
     out = jax.eval_shape(_touch, kv.cache)
     alias_safe(kv.cache, out, "touch")
 
 
 def test_alias_safe_rejects_dtype_or_shape_drift():
-    kv = _kv(paged=False)
+    kv = _kv()
     promoted = jax.eval_shape(
         jax.jit(lambda c: jax.tree.map(
             lambda x: x.astype(jnp.float32) * 1.0, c)), kv.cache)
@@ -158,7 +127,8 @@ def test_alias_safe_rejects_dtype_or_shape_drift():
 
 def test_stats_report_versions_and_pool():
     kv = _kv()
-    kv.commit(_touch(kv.cache), donated=False)
+    kv.commit(_touch_don(kv.cache))
     st = kv.stats()
-    assert st["kv_version"] == 1 and st["kv_copied_commits"] == 1
+    assert st["kv_version"] == 1
+    assert kv.pins == 0 and st["kv_pins"] == 0
     assert st["pages_capacity"] == kv.pager.capacity

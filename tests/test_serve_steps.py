@@ -13,8 +13,7 @@ from repro.configs import get
 from repro.models.lm import init_cache, init_params
 from repro.serve import PagePool
 from repro.steps import (chunkable, greedy_oneshot, init_paged_slot_cache,
-                         init_slot_cache, make_batched_insert_step,
-                         make_decode_step, make_insert_step,
+                         make_batched_insert_step, make_decode_step,
                          make_prefill_chunk_step, make_prefill_step,
                          make_serve_step)
 
@@ -25,11 +24,13 @@ pytestmark = pytest.mark.slow
 # plain GQA, SWA+MoE, MLA, vision frontend, audio frontend
 ARCHS = ["qwen2.5-14b", "mixtral-8x7b", "minicpm3-4b", "internvl2-2b",
          "musicgen-large"]
-# + attn/SSM/MoE hybrid for the paged fuzz (SSM state stays dense while
-# the attention layer's K/V leaves page)
+# + attn/SSM/MoE hybrid for the paged fuzz (SSM state keeps a row per
+# slot while the attention layer's K/V leaves page)
 FUZZ_ARCHS = ARCHS + ["jamba-v0.1-52b"]
 SLOTS, PLEN, GEN = 3, 8, 4
 PAGE_SIZE = 4
+# one page per slot (page_size == cache_len): slot s owns page s + 1
+SLOT_TABLE = np.arange(1, SLOTS + 1, dtype=np.int32).reshape(SLOTS, 1)
 
 
 @pytest.fixture(scope="module")
@@ -58,10 +59,14 @@ def _build(arch, built):
             patches=patches,
             prefill=jax.jit(make_prefill_step(cfg, cache_len=cache_len)),
             serve=jax.jit(make_serve_step(cfg)),
-            insert=jax.jit(make_insert_step(cfg)),
-            decode=jax.jit(make_decode_step(cfg)),
-            insert_dense=jax.jit(make_batched_insert_step(
-                cfg, cache_len=cache_len, page_size=None)),
+            # one page per slot: the pool the engine holds at
+            # page_size == cache_len
+            insert=jax.jit(make_batched_insert_step(
+                cfg, cache_len=cache_len, page_size=cache_len)),
+            decode=jax.jit(make_decode_step(
+                cfg, cache_len=cache_len, page_size=cache_len)),
+            slot_table=jnp.asarray(SLOT_TABLE),
+            slot_rows=[jnp.asarray(r) for r in SLOT_TABLE],
             insert_paged=jax.jit(make_batched_insert_step(
                 cfg, cache_len=cache_len, page_size=PAGE_SIZE)),
             decode_paged=jax.jit(make_decode_step(
@@ -78,14 +83,15 @@ def _build(arch, built):
             # donated legs: the steps consume the cache version they
             # rewrite (donate_argnums on the cache arg), exactly like
             # the engine's default fast path
-            insert_dense_don=jax.jit(make_batched_insert_step(
-                cfg, cache_len=cache_len, page_size=None),
+            insert_don=jax.jit(make_batched_insert_step(
+                cfg, cache_len=cache_len, page_size=cache_len),
                 donate_argnums=(0,)),
             insert_paged_don=jax.jit(make_batched_insert_step(
                 cfg, cache_len=cache_len, page_size=PAGE_SIZE),
                 donate_argnums=(0,)),
-            decode_don=jax.jit(make_decode_step(cfg),
-                               donate_argnums=(1,)),
+            decode_don=jax.jit(make_decode_step(
+                cfg, cache_len=cache_len, page_size=cache_len),
+                donate_argnums=(1,)),
             decode_paged_don=jax.jit(make_decode_step(
                 cfg, cache_len=cache_len, page_size=PAGE_SIZE),
                 donate_argnums=(1,)),
@@ -106,6 +112,26 @@ def _oneshot_reference(b):
                                      b["prompts"], b["patches"], GEN))
 
 
+def _slot_pool(b):
+    """An empty one-page-per-slot pool (``SLOT_TABLE``)."""
+    cfg = b["cfg"]
+    return init_paged_slot_cache(cfg, SLOTS, b["cache_len"],
+                                 jnp.dtype(cfg.dtype), b["cache_len"],
+                                 SLOTS + 1)
+
+
+def _insert(b, pool, rows, row, slot):
+    """Row ``row`` of ``rows`` into ``slot`` of a one-page-per-slot
+    pool."""
+    return b["insert"](pool, rows, jnp.int32(row), jnp.int32(slot),
+                       b["slot_rows"][slot])
+
+
+def _decode(b, pool, toks, active):
+    """One masked decode tick over a one-page-per-slot pool."""
+    return b["decode"](b["params"], pool, toks, active, b["slot_table"])
+
+
 def _row_prefill(b, i):
     patches = b["patches"]
     rc, rl = b["prefill"](b["params"], b["prompts"][i:i + 1],
@@ -121,19 +147,19 @@ def test_scrambled_insert_matches_oneshot(arch, built):
     cfg = b["cfg"]
     ref = _oneshot_reference(b)
 
-    pool = init_slot_cache(cfg, SLOTS, b["cache_len"], jnp.dtype(cfg.dtype))
+    pool = _slot_pool(b)
     extra = ((cfg.n_codebooks,) if cfg.frontend == "audio_codebooks"
              else ())
     toks = jnp.zeros((SLOTS, 1) + extra, jnp.int32)
     outs = {}
     for r in (2, 0, 1):                       # arrival != slot-id order
         rc, t0 = _row_prefill(b, r)
-        pool = b["insert"](pool, rc, jnp.int32(r))
+        pool = _insert(b, pool, rc, 0, r)
         toks = toks.at[r].set(t0[0])
         outs[r] = [np.asarray(t0)]
     active = jnp.ones((SLOTS,), bool)
     for _ in range(GEN - 1):
-        toks, pool = b["decode"](b["params"], pool, toks, active)
+        toks, pool = _decode(b, pool, toks, active)
         # force per tick, never accumulate lazy slices of rebound
         # arrays: this backend can recycle a buffer whose last Python
         # reference drops while a pending computation still reads it
@@ -157,7 +183,7 @@ def test_evict_and_reuse_slot_mid_decode(arch, built):
     cfg = b["cfg"]
     ref = _oneshot_reference(b)
 
-    pool = init_slot_cache(cfg, SLOTS, b["cache_len"], jnp.dtype(cfg.dtype))
+    pool = _slot_pool(b)
     extra = ((cfg.n_codebooks,) if cfg.frontend == "audio_codebooks"
              else ())
     toks = jnp.zeros((SLOTS, 1) + extra, jnp.int32)
@@ -171,22 +197,21 @@ def test_evict_and_reuse_slot_mid_decode(arch, built):
 
     # A = request 0 into slot 1; decodes 2 ticks alone
     rc, t0 = _row_prefill(b, 0)
-    pool = b["insert"](pool, rc, jnp.int32(1))
+    pool = _insert(b, pool, rc, 0, 1)
     toks = toks.at[1].set(t0[0])
     active[1] = True
     out_a = [np.asarray(t0)]
     for _ in range(2):
-        toks, pool = b["decode"](b["params"], pool, toks,
-                                 jnp.array(active))
+        toks, pool = _decode(b, pool, toks, jnp.array(active))
         out_a.append(np.asarray(toks)[1:2])
 
     # B = request 2 arrives into dead slot 0 while A keeps decoding
     rc, t0 = _row_prefill(b, 2)
-    pool = b["insert"](pool, rc, jnp.int32(0))
+    pool = _insert(b, pool, rc, 0, 0)
     toks = toks.at[0].set(t0[0])
     active[0] = True
     out_b = [np.asarray(t0)]
-    toks, pool = b["decode"](b["params"], pool, toks, jnp.array(active))
+    toks, pool = _decode(b, pool, toks, jnp.array(active))
     host = np.asarray(toks)
     out_a.append(host[1:2])
     out_b.append(host[0:1])
@@ -194,13 +219,12 @@ def test_evict_and_reuse_slot_mid_decode(arch, built):
     # A done (GEN tokens collected): evict, reuse its slot for request 1
     active[1] = False
     rc, t0 = _row_prefill(b, 1)
-    pool = b["insert"](pool, rc, jnp.int32(1))
+    pool = _insert(b, pool, rc, 0, 1)
     toks = toks.at[1].set(t0[0])
     active[1] = True
     out_c = [np.asarray(t0)]
     for _ in range(GEN - 1):
-        toks, pool = b["decode"](b["params"], pool, toks,
-                                 jnp.array(active))
+        toks, pool = _decode(b, pool, toks, jnp.array(active))
         host = np.asarray(toks)
         if len(out_b) < GEN:
             out_b.append(host[0:1])
@@ -219,21 +243,20 @@ def test_evict_and_reuse_slot_mid_decode(arch, built):
 def test_masked_decode_freezes_dead_slot_pos():
     """Dead slots emit token 0 and their pos does not advance."""
     b = _build("qwen2.5-14b", {})
-    cfg = b["cfg"]
-    pool = init_slot_cache(cfg, SLOTS, b["cache_len"], jnp.dtype(cfg.dtype))
+    pool = _slot_pool(b)
     rc, t0 = _row_prefill(b, 0)
-    pool = b["insert"](pool, rc, jnp.int32(2))
+    pool = _insert(b, pool, rc, 0, 2)
     toks = jnp.zeros((SLOTS, 1), jnp.int32).at[2].set(t0[0])
     active = jnp.asarray([False, False, True])
     pos0 = np.asarray(pool["pos"])
-    toks, pool = b["decode"](b["params"], pool, toks, active)
+    toks, pool = _decode(b, pool, toks, active)
     pos1 = np.asarray(pool["pos"])
     assert pos1[2] == pos0[2] + 1
     assert pos1[0] == pos0[0] and pos1[1] == pos0[1]
     assert int(toks[0, 0]) == 0 and int(toks[1, 0]) == 0
 
 
-# ------------------------------------------------- dense/paged schedule fuzz
+# ------------------------------------------------------ paged schedule fuzz
 def _chunked_prefill_rows(b, chunk, chunk_fn=None):
     """Cache-append chunked prefill of the whole prompt batch (ragged last
     chunk; vision patches ride the first chunk; extent buckets + LM head
@@ -274,17 +297,16 @@ def _run_schedule(b, seed, page_size, insert, decode, n_req=6,
                   chunk=None, chunk_fn=None, check_alias=False):
     """Seeded schedule generator: requests (prompt rows reused mod SLOTS,
     fuzzed budgets) arrive in a random order into random free slots and
-    decode ticks interleave randomly with inserts.  Paged
-    (``page_size`` set): pages come from a deliberately tight PagePool
-    (admission blocks on exhaustion) and are freed the tick a request
-    completes.  Dense (``page_size=None``): same schedule on the per-slot
-    layout.  Works with donated or copying jits — the cache is rebound
-    on every step, so the single-owner discipline holds either way.
+    decode ticks interleave randomly with inserts.  Pages come from a
+    deliberately tight PagePool (admission blocks on exhaustion) and are
+    freed the tick a request completes; ``page_size == cache_len`` runs
+    the same schedule on one page per slot.  Works with donated or
+    copying jits — the cache is rebound on every step, so the
+    single-owner discipline holds either way.
     Every request's greedy stream must equal its one-shot row prefix,
     bit for bit; ``check_alias`` additionally asserts the donated decode
     really reused the big cache leaf's buffer (the eliminated copy)."""
     cfg = b["cfg"]
-    paged = page_size is not None
     ref = _oneshot_reference(b)
     rng = np.random.default_rng(seed)
     cache_len = b["cache_len"]
@@ -296,19 +318,14 @@ def _run_schedule(b, seed, page_size, insert, decode, n_req=6,
         rc, logits = b["prefill"](b["params"], b["prompts"], b["patches"])
         rows_cache, t0 = rc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    if paged:
-        pps = cache_len // page_size
-        # tight pool: enough for ~2 of 3 slots -> admission must block
-        pool_pages = 2 * pps + 2
-        pager = PagePool(pool_pages, page_size)
-        cache = init_paged_slot_cache(cfg, SLOTS, cache_len,
-                                      jnp.dtype(cfg.dtype), page_size,
-                                      pool_pages)
-        table = np.zeros((SLOTS, pps), np.int32)
-    else:
-        pager = table = None
-        cache = init_slot_cache(cfg, SLOTS, cache_len,
-                                jnp.dtype(cfg.dtype))
+    pps = cache_len // page_size
+    # tight pool: enough for ~2 of 3 slots -> admission must block
+    pool_pages = 2 * pps + 2
+    pager = PagePool(pool_pages, page_size)
+    cache = init_paged_slot_cache(cfg, SLOTS, cache_len,
+                                  jnp.dtype(cfg.dtype), page_size,
+                                  pool_pages)
+    table = np.zeros((SLOTS, pps), np.int32)
     extra = ((cfg.n_codebooks,) if cfg.frontend == "audio_codebooks"
              else ())
     toks = jnp.zeros((SLOTS, 1) + extra, jnp.int32)
@@ -331,9 +348,8 @@ def _run_schedule(b, seed, page_size, insert, decode, n_req=6,
     def free_slot_of(r, s):
         active[s] = False
         del live[s]
-        if paged:
-            table[s, :] = 0
-            pager.free(pages_of.pop(r))
+        table[s, :] = 0
+        pager.free(pages_of.pop(r))
 
     for _ in range(10_000):
         if not waiting and not live:
@@ -345,26 +361,20 @@ def _run_schedule(b, seed, page_size, insert, decode, n_req=6,
         if want_insert:
             i = int(waiting[0])
             row = i % SLOTS
-            ids = None
-            if paged:
-                ids = pager.reserve(PLEN + npatch + int(gens[i]) - 1)
-            if paged and ids is None:
+            ids = pager.reserve(PLEN + npatch + int(gens[i]) - 1)
+            if ids is None:
                 blocked_allocs += 1     # admission blocks; tick instead
             else:
                 waiting.pop(0)
                 s = int(rng.choice(free))
                 pins.append((cache, toks))
-                if paged:
-                    pages_of[i] = ids
-                    table[s, :] = 0
-                    table[s, :len(ids)] = ids
-                    trow = jnp.array(table[s])
-                    pins.append(trow)
-                    cache = insert(cache, rows_cache, jnp.int32(row),
-                                   jnp.int32(s), trow)
-                else:
-                    cache = insert(cache, rows_cache, jnp.int32(row),
-                                   jnp.int32(s))
+                pages_of[i] = ids
+                table[s, :] = 0
+                table[s, :len(ids)] = ids
+                trow = jnp.array(table[s])
+                pins.append(trow)
+                cache = insert(cache, rows_cache, jnp.int32(row),
+                               jnp.int32(s), trow)
                 toks = toks.at[s].set(t0[row])
                 outs[i] = [np.asarray(t0[row])]
                 active[s] = True
@@ -378,9 +388,8 @@ def _run_schedule(b, seed, page_size, insert, decode, n_req=6,
                 big = max(leaves, key=lambda x: x.nbytes)
                 big_ptr = big.unsafe_buffer_pointer()
             pins.append((cache, toks))
-            args = (b["params"], cache, toks, jnp.array(active))
-            if paged:
-                args = args + (jnp.array(table),)
+            args = (b["params"], cache, toks, jnp.array(active),
+                    jnp.array(table))
             pins.append(args[3:])
             toks, cache = decode(*args)
             if not alias_checked:
@@ -397,8 +406,7 @@ def _run_schedule(b, seed, page_size, insert, decode, n_req=6,
     assert not waiting and not live, "schedule deadlocked"
     jax.block_until_ready(toks)
     pins.clear()                    # chain drained: nothing pending
-    if paged:
-        assert pager.used_pages == 0, "pages leaked"
+    assert pager.used_pages == 0, "pages leaked"
     for i in range(n_req):
         got = np.concatenate(outs[i], axis=0)
         want = ref[i % SLOTS, :gens[i]]
@@ -422,17 +430,17 @@ def test_paged_schedule_fuzz_matches_oneshot(arch, seed, built):
 
 
 @pytest.mark.parametrize("arch", FUZZ_ARCHS)
-@pytest.mark.parametrize("layout,donate", [("dense", False),
-                                           ("dense", True),
+@pytest.mark.parametrize("layout,donate", [("one_page", False),
+                                           ("one_page", True),
                                            ("paged", True)])
 def test_schedule_fuzz_donation_grid_matches_oneshot(arch, layout, donate,
                                                      built):
-    """Donation on x off, dense x paged (the paged x off cell is the
-    fuzz above), across plain/SWA+MoE/MLA/vision/audio frontends and the
-    SSM hybrid: donated steps consume the cache version they rewrite —
-    fuzzed schedules stay bit-identical to the one-shot rows, and (spot
-    check) the donated decode really reuses the big cache leaf's
-    buffer in place."""
+    """Donation on x off, one page per slot x small pages (the paged x
+    off cell is the fuzz above), across plain/SWA+MoE/MLA/vision/audio
+    frontends and the SSM hybrid: donated steps consume the cache
+    version they rewrite — fuzzed schedules stay bit-identical to the
+    one-shot rows, and (spot check) the donated decode really reuses the
+    big cache leaf's buffer in place."""
     b = _build(arch, built)
     suffix = "_don" if donate else ""
     if layout == "paged":
@@ -440,9 +448,8 @@ def test_schedule_fuzz_donation_grid_matches_oneshot(arch, layout, donate,
             b["decode_paged" + suffix]
         ps = PAGE_SIZE
     else:
-        insert, decode = b["insert_dense" + suffix], \
-            b["decode" + suffix]
-        ps = None
+        insert, decode = b["insert" + suffix], b["decode" + suffix]
+        ps = b["cache_len"]
     chunk = chunk_fn = None
     if b["chunk"] is not None:
         chunk, chunk_fn = 3, (b["chunk_don"] if donate else b["chunk"])
@@ -564,23 +571,17 @@ def test_chunked_prefill_rows_match_oneshot_prefill(arch, built):
     ref = _oneshot_reference(b)
     for chunk in (1, 3, PLEN):
         rows, t0 = _chunked_prefill_rows(b, chunk)
-        pool = init_slot_cache(b["cfg"], SLOTS, b["cache_len"],
-                               jnp.dtype(b["cfg"].dtype))
+        pool = _slot_pool(b)
         extra = ((b["cfg"].n_codebooks,)
                  if b["cfg"].frontend == "audio_codebooks" else ())
         toks = jnp.zeros((SLOTS, 1) + extra, jnp.int32)
-        outs = []
         for r in range(SLOTS):
-            pool = b["insert"](pool, {"pos": rows["pos"],
-                                      "blocks": jax.tree.map(
-                                          lambda x, rr=r: x[:, rr:rr + 1],
-                                          rows["blocks"])},
-                               jnp.int32(r))
+            pool = _insert(b, pool, rows, r, r)
             toks = toks.at[r].set(t0[r])
         outs = [t0]
         act = jnp.ones((SLOTS,), bool)
         for _ in range(GEN - 1):
-            toks, pool = b["decode"](b["params"], pool, toks, act)
+            toks, pool = _decode(b, pool, toks, act)
             outs.append(toks)
         got = np.asarray(jnp.concatenate(outs, axis=1))
         assert np.array_equal(got, ref), f"chunk={chunk}"
@@ -593,7 +594,7 @@ def test_chunked_prefill_rows_match_oneshot_prefill(arch, built):
 SPEC_STEP_ARCHS = ["qwen2.5-14b", "minicpm3-4b", "internvl2-2b"]
 
 
-def _verify_jit(b, page_size=None, donate=False):
+def _verify_jit(b, page_size, donate=False):
     from repro.steps import make_verify_step
 
     key = f"verify_{page_size}_{donate}"
@@ -615,7 +616,7 @@ def test_verify_step_requires_speculatable():
         cfg = get(arch).tiny()
         assert not speculatable(cfg, 16)
         with pytest.raises(AssertionError):
-            make_verify_step(cfg, cache_len=16)
+            make_verify_step(cfg, cache_len=16, page_size=4)
     assert speculatable(get("qwen2.5-14b").tiny(), 16)
 
 
@@ -628,20 +629,21 @@ def test_verify_s1_ticks_equal_decode_ticks(arch, built):
     b = _build(arch, built)
     cfg = b["cfg"]
     ref = _oneshot_reference(b)
-    verify = _verify_jit(b)
-    pool = init_slot_cache(cfg, SLOTS, b["cache_len"], jnp.dtype(cfg.dtype))
+    verify = _verify_jit(b, b["cache_len"])
+    pool = _slot_pool(b)
     pos = np.zeros((SLOTS,), np.int32)
     toks = np.zeros((SLOTS, 1), np.int32)
     for r in range(SLOTS):
         rc, t0 = _row_prefill(b, r)
-        pool = b["insert"](pool, rc, jnp.int32(r))
+        pool = _insert(b, pool, rc, 0, r)
         pos[r] = int(np.asarray(rc["pos"]).reshape(-1)[0])
         toks[r, 0] = int(np.asarray(t0)[0, 0])
     outs = [toks.copy()]
     n_tok = np.ones((SLOTS,), np.int32)
     for _ in range(GEN - 1):
         nxt, pool = verify(b["params"], pool, jnp.array(toks),
-                           jnp.array(pos), jnp.array(n_tok))
+                           jnp.array(pos), jnp.array(n_tok),
+                           b["slot_table"])
         toks = np.asarray(nxt)[:, :1].astype(np.int32)
         outs.append(toks.copy())
         pos += 1
@@ -650,8 +652,8 @@ def test_verify_s1_ticks_equal_decode_ticks(arch, built):
 
 
 @pytest.mark.parametrize("arch", SPEC_STEP_ARCHS)
-@pytest.mark.parametrize("layout,donate", [("dense", False),
-                                           ("dense", True),
+@pytest.mark.parametrize("layout,donate", [("one_page", False),
+                                           ("one_page", True),
                                            ("paged", False)])
 def test_verify_window_scores_the_decode_stream(arch, layout, donate,
                                                 built):
@@ -662,8 +664,9 @@ def test_verify_window_scores_the_decode_stream(arch, layout, donate,
     its stale cache writes are overwritten by the next window
     (rollback-for-free) — asserted by re-running the perfect window on
     the same cache afterwards.  Dead slots stay masked (n_tok=0); the
-    paged leg uses page_size=1 so the window crosses a page boundary at
-    every position."""
+    one_page leg holds each slot in one page, the paged leg uses
+    page_size=1 so the window crosses a page boundary at every
+    position."""
     b = _build(arch, built)
     cfg = b["cfg"]
     ref = _oneshot_reference(b)
@@ -678,19 +681,17 @@ def test_verify_window_scores_the_decode_stream(arch, layout, donate,
                                      jnp.dtype(cfg.dtype), ps, num_pages)
         table = np.zeros((SLOTS, b["cache_len"]), np.int32)
     else:
-        verify = _verify_jit(b, donate=donate)
-        pool = init_slot_cache(cfg, SLOTS, b["cache_len"],
-                               jnp.dtype(cfg.dtype))
-        table = None
+        verify = _verify_jit(b, b["cache_len"], donate=donate)
+        insert = b["insert"]
+        pool = _slot_pool(b)
+        table = SLOT_TABLE.copy()
     rc, t0 = _row_prefill(b, 0)
     p0 = int(np.asarray(rc["pos"]).reshape(-1)[0])
     if layout == "paged":
         # bind every page the window can write (p0 .. p0+k), 1 token each
         table[0, :p0 + k + 1] = np.arange(1, p0 + k + 2)
-        pool = insert(pool, rc, jnp.int32(0), jnp.int32(0),
-                      jnp.array(table[0]))
-    else:
-        pool = b["insert"](pool, rc, jnp.int32(0))
+    pool = insert(pool, rc, jnp.int32(0), jnp.int32(0),
+                  jnp.array(table[0]))
     pos = jnp.array(np.array([p0, 0, 0], np.int32))
     n_tok = jnp.array(np.array([k + 1, 0, 0], np.int32))
 
@@ -700,11 +701,10 @@ def test_verify_window_scores_the_decode_stream(arch, layout, donate,
         toks[0, 1:] = draft
         return jnp.array(toks)
 
+    table_dev = jnp.array(table)
+
     def dispatch(toks, cache):
-        args = (b["params"], cache, toks, pos, n_tok)
-        if layout == "paged":
-            args = args + (jnp.array(table),)
-        return verify(*args)
+        return verify(b["params"], cache, toks, pos, n_tok, table_dev)
 
     # perfect drafts: the stream's own next tokens — every lane agrees
     nxt, pool = dispatch(window(ref[0, 1:1 + k]), pool)
@@ -847,10 +847,11 @@ def test_decode_step_does_not_copy_the_pool(arch, step):
                                   "jamba-v0.1-52b"])
 def test_paged_rows_land_at_their_layer(arch):
     """Three pattern repeats, scrambled page tables: two paged decode
-    ticks leave every layer's pool holding exactly the rows the dense
-    slot cache holds at that layer (and the hybrid's scanned SSM leaves
-    equal the dense ones), with distinct content per layer — a row
-    written at the wrong layer index, or read from one, would show."""
+    ticks leave every layer's pool holding exactly the rows the one-shot
+    serve step (``greedy_oneshot``'s path) holds at that layer after the
+    same two ticks (and the hybrid's scanned SSM leaves equal its ones),
+    with distinct content per layer — a row written at the wrong layer
+    index, or read from one, would show."""
     cfg = get(arch).tiny(n_layers=3 * len(get(arch).pattern))
     cache_len, ps = PLEN + GEN, PAGE_SIZE
     pps = cache_len // ps
@@ -864,28 +865,27 @@ def test_paged_rows_land_at_their_layer(arch):
     num_pages = SLOTS * pps + 1
     table = np.random.default_rng(3).permutation(
         np.arange(1, num_pages)).reshape(SLOTS, pps).astype(np.int32)
-    dense = init_slot_cache(cfg, SLOTS, cache_len, dt)
     paged = init_paged_slot_cache(cfg, SLOTS, cache_len, dt, ps, num_pages)
-    ins_d = jax.jit(make_batched_insert_step(cfg, cache_len=cache_len))
     ins_p = jax.jit(make_batched_insert_step(cfg, cache_len=cache_len,
                                              page_size=ps))
     for s in range(SLOTS):
-        dense = ins_d(dense, rows, jnp.int32(s), jnp.int32(s))
         paged = ins_p(paged, rows, jnp.int32(s), jnp.int32(s),
                       jnp.array(table[s]))
-    dec_d = jax.jit(make_decode_step(cfg))
+    serve = jax.jit(make_serve_step(cfg))
     dec_p = jax.jit(make_decode_step(cfg, cache_len=cache_len,
                                      page_size=ps))
     active = jnp.ones((SLOTS,), bool)
-    td = tp = toks
+    ref, tr = rows, toks
+    tp = toks
     for _ in range(2):
-        td, dense = dec_d(params, dense, td, active)
+        tr, ref = serve(params, ref, tr)
         tp, paged = dec_p(params, paged, tp, active, jnp.array(table))
-        assert np.array_equal(np.asarray(td), np.asarray(tp))
-    assert np.array_equal(np.asarray(dense["pos"]), np.asarray(paged["pos"]))
+        assert np.array_equal(np.asarray(tr), np.asarray(tp))
     written = PLEN + 2
+    assert int(ref["pos"]) == written
+    assert np.array_equal(np.asarray(paged["pos"]), np.full(SLOTS, written))
     n_paged = 0
-    for spec, bd, bp in zip(cfg.pattern, dense["blocks"], paged["blocks"]):
+    for spec, bd, bp in zip(cfg.pattern, ref["blocks"], paged["blocks"]):
         for name, d in bd.items():
             d, p = np.asarray(d), np.asarray(bp[name])
             if p.shape == d.shape:               # scanned per layer

@@ -79,44 +79,28 @@ def run_leg(cfg, params, prompts, ref, mesh, steps, seed, **kw):
                                   "mamba2-780m"])
 def test_tp_engine_bit_identical_to_single_device(arch):
     """Sharded engine greedy tokens == single-device one-shot rows, per
-    request, across the donation x policy grid on a (1, 4) mesh.  GQA
-    shards KV heads, MLA replicates its latents, SSM shards state/conv
-    channels — all three must come out bit-identical, not just close."""
-    if arch == "mamba2-780m":
-        # pure-SSM: no paged leaves, so dense cache + reserve only
-        body = r"""
-cfg, params, prompts = build("mamba2-780m")
-ref = reference(cfg, params, prompts, None)
-mesh = make_mesh((1, 4), ("data", "model"))
-for seed, donate in ((0, True), (1, False)):
-    steps = make_jit_steps(cfg, mesh, cache_len=CACHE_LEN,
-                           donate=donate, tp=True)
-    run_leg(cfg, params, prompts, ref, mesh, steps, seed,
-            page_size=None, donate=donate, policy="reserve")
-print("TP_GRID_OK")
-"""
-    else:
-        extra = ""
-        if arch == "qwen2.5-14b":
-            extra = r"""
+    request, across the policy grid on a (1, 4) mesh.  GQA shards KV
+    heads, MLA replicates its latents, SSM shards state/conv channels
+    (pure SSM pages no leaf: its per-slot rows ride the paged cache) —
+    all three must come out bit-identical, not just close."""
+    extra = ""
+    if arch == "qwen2.5-14b":
+        extra = r"""
 # GQA also exercises the shard_map'd paged-attention kernel leg
 steps = make_jit_steps(cfg, mesh, cache_len=CACHE_LEN, page_size=PAGE,
                        chunk=True, paged_kernel=True, tp=True)
 run_leg(cfg, params, prompts, ref, mesh, steps, 7, page_size=PAGE,
         paged_kernel=True, policy="reserve")
 """
-        body = (r"""
+    body = (r"""
 cfg, params, prompts = build("%s")
 ref = reference(cfg, params, prompts, PAGE)
 mesh = make_mesh((1, 4), ("data", "model"))
-for donate in (True, False):
-    steps = make_jit_steps(cfg, mesh, cache_len=CACHE_LEN,
-                           page_size=PAGE, chunk=True, donate=donate,
-                           tp=True)
-    for seed, policy in enumerate(("reserve", "ondemand")):
-        run_leg(cfg, params, prompts, ref, mesh, steps,
-                10 * donate + seed, page_size=PAGE, donate=donate,
-                policy=policy)
+steps = make_jit_steps(cfg, mesh, cache_len=CACHE_LEN, page_size=PAGE,
+                       tp=True)
+for seed, policy in ((10, "reserve"), (11, "ondemand")):
+    run_leg(cfg, params, prompts, ref, mesh, steps, seed, page_size=PAGE,
+            policy=policy)
 """ % arch) + extra + "\nprint(\"TP_GRID_OK\")\n"
     out = _run(_PRELUDE + body)
     assert "TP_GRID_OK" in out.stdout, (out.stdout[-1500:],
@@ -176,7 +160,7 @@ with ServeEngine(cfg, params, slots=3, cache_len=CACHE_LEN, mesh=mesh,
     assert ptrs <= new_ptrs, (
         "donated sharded decode did not alias the pool shards — "
         "out_shardings no longer match the committed input shardings")
-    kv.commit(new_cache, donated=True)
+    kv.commit(new_cache)
     print("ALIAS_OK")
 print("TP_SYS_OK")
 """
